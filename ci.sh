@@ -76,14 +76,14 @@ assert fused_d < plain_d, (
 print(f"fusion parity OK; dispatches {plain_d} -> {fused_d}")
 EOF
 
-echo "== kernel-backend parity + default flip (no-conf session selects pallas, =xla oracle bit-identical) =="
+echo "== kernel-backend parity (explicit pallas bit-identical to the no-conf xla default) =="
 timeout 300 python - <<'EOF'
 # the XLA composed-array-op paths are the Pallas kernels' correctness
 # oracle (the sql.fusion.enabled pattern): one real q6-class query —
 # dict-encoded parquet scan -> filter -> grouped aggregate — runs under
-# an explicit kernel.backend=xla session AND a session with NO backend
-# conf at all (the PR 14 default-flip gate: the process default must
-# resolve to pallas on its own) and must be BIT-IDENTICAL.  On CPU the
+# a session with NO backend conf at all (the default must resolve to
+# xla, the path the TPU compiler accepts) AND an explicit
+# kernel.backend=pallas session and must be BIT-IDENTICAL.  On CPU the
 # Pallas kernels execute under interpret=True (real kernel bodies, not
 # a skip), and the registry must show actual pallas selections: a
 # silently-all-fallback run would make this gate vacuous.
@@ -119,20 +119,20 @@ def run(backend):
            .sort("k")).collect()
     return out, view.delta()["counters"]
 
-xla_t, _ = run("xla")
-pal_t, d = run(None)          # NO backend conf: the flipped default
-assert kbk.default_backend() == "pallas", (
+xla_t, _ = run(None)          # NO backend conf: the xla default
+assert kbk.default_backend() == "xla", (
     f"fresh no-conf session resolved {kbk.default_backend()!r}, "
-    "expected the flipped 'pallas' default")
+    "expected the 'xla' default")
+pal_t, d = run("pallas")
 assert xla_t.equals(pal_t), (
-    "default (pallas) diverges from the =xla oracle:\n"
+    "pallas diverges from the default xla oracle:\n"
     f"xla={xla_t.to_pydict()}\npallas={pal_t.to_pydict()}")
 hits = d.get("kernel.backend.pallas.hits", 0)
 assert hits > 0, f"no pallas kernel selected — gate is vacuous: {d}"
 agg_pallas = d.get("kernel.dispatches.agg_update.pallas", 0)
 assert agg_pallas > 0, f"aggregate never dispatched on pallas: {d}"
 fams = {k for k in d if k.startswith("kernel.backend.pallas.hits.")}
-print(f"kernel default-flip parity OK: bit-identical, {int(hits)} "
+print(f"kernel backend parity OK: bit-identical, {int(hits)} "
       f"pallas selections across {len(fams)} families, "
       f"{int(agg_pallas)} pallas agg dispatches")
 EOF
@@ -1060,18 +1060,13 @@ timeout 560 python - <<'EOF'
 # precompile service BEFORE serving, then re-runs the probe and must
 # report ZERO fresh compiles on /compiles — persistent reloads only,
 # every one of them paid off the serving path by the replay thread.
-# Donation is disabled for the probe: donating kernels are barred from
-# the persistent cache by design (jax 0.4.37 reload mis-applies the
-# aliasing table) and would legitimately compile fresh.
 import json, os, subprocess, sys, tempfile
 
 work = tempfile.mkdtemp(prefix="warm_gate_")
 env = dict(os.environ)
 env.update({"JAX_PLATFORMS": "cpu",
             "PYTHONPATH": os.getcwd(),   # probes run from temp files
-            "SPARK_RAPIDS_TPU_CPU_COMPILE_CACHE": "1",
-            "SPARK_RAPIDS_TPU_COMPILE_CACHE":
-                os.path.join(work, "xla")})
+            "JAX_COMPILATION_CACHE_DIR": os.path.join(work, "xla")})
 corpus = os.path.join(work, "corpus.jsonl")
 
 COMMON = r'''
@@ -1378,7 +1373,6 @@ timeout 420 python - <<'EOF'
 # handshake, and serves with ZERO fresh kernel compiles.
 import json, os, tempfile, threading, time, urllib.request
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ["SPARK_RAPIDS_TPU_CPU_COMPILE_CACHE"] = "1"
 import pyarrow as pa, pyarrow.parquet as papq
 from spark_rapids_tpu import TpuSparkSession
 from spark_rapids_tpu.fleet.replica import FleetManager

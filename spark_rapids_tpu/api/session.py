@@ -63,7 +63,7 @@ class TpuSparkSession:
                     os.path.join(corpus_dir,
                                  f"corpus-{os.getpid()}.jsonl"))
         import spark_rapids_tpu as _pkg
-        _pkg._enable_compile_cache(  # accelerator backends only
+        _pkg._enable_compile_cache(
             self._fleet_store.compile_cache_dir()
             if self._fleet_store is not None else None)
         from spark_rapids_tpu.mem import spill

@@ -67,16 +67,11 @@ def resolve_oocore(conf_obj) -> Optional[dict]:
     if budget == 0:
         # admission-machinery derivation: one admitted query's fair
         # share of the scheduler budget (sched/service.py's own
-        # default chain: explicit conf > HBM pool > 8 GiB)
+        # default chain: explicit conf > the device manager's pool)
         base = int(conf_obj.get(cfg.SCHED_MEMORY_BUDGET) or 0)
         if base <= 0:
-            try:
-                from spark_rapids_tpu.mem.device import TpuDeviceManager
-                base = int(TpuDeviceManager.get().hbm_budget)
-            except Exception:
-                base = 0
-        if base <= 0:
-            base = 8 << 30
+            from spark_rapids_tpu.mem.device import TpuDeviceManager
+            base = int(TpuDeviceManager.get().hbm_budget)
         budget = max(1, base // max(1, int(conf_obj.get(
             cfg.SCHED_MAX_CONCURRENT))))
     return {

@@ -248,8 +248,8 @@ def _pad_np(a: np.ndarray, cap: int, fill=0) -> np.ndarray:
 
 def _upload_runs(runs: RunTable, packed: bytes):
     """Bucket + upload a run table as TWO device arrays (one [rcap, 5]
-    int64 run matrix + the packed byte buffer) — minimizing host->device
-    transfers, which dominate scan cost on remote/tunneled devices."""
+    int64 run matrix + the packed byte buffer) — minimizing the number
+    of host->device transfers."""
     r = max(len(runs.counts), 1)
     rcap = bucket_rows(r, 8)
     ends = np.cumsum(np.asarray(runs.counts + [0], dtype=np.int64))[:r]

@@ -2,8 +2,8 @@
 
 The per-column decode path (io/device_parquet.py) issues ~5 device
 dispatches and ~4 uploads per column per row group — hundreds per query.
-On any runtime that's dispatch overhead; on a tunneled/remote device it
-dominates the whole query.  This module is the TPU-first answer to the
+That is dispatch overhead on any runtime (its size on the attached chip
+is not measured).  This module is the TPU-first answer to the
 reference's one-kernel-per-buffer decode (`Table.readParquet`,
 reference: GpuParquetScan.scala:1022 — one libcudf call decodes every
 column of the assembled buffer):
@@ -242,8 +242,7 @@ def assemble(plans: List[List[Optional[ChunkPlan]]],
 
     # -- kernel-2 deferral candidates (decided before specs build) ----
     defer_cols: set = set()
-    if pushed_filter is not None and backend == kb.PALLAS and \
-            kb.pallas_available():
+    if pushed_filter is not None and backend == kb.PALLAS:
         from spark_rapids_tpu.expr import ir as _ir
         ref_names = {scan_names[b.ordinal] for b in _ir.collect(
             pushed_filter, lambda e: isinstance(e, _ir.BoundReference))}

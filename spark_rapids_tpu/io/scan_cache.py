@@ -3,9 +3,8 @@
 The CPU half of the device parquet scan — footer parse, Thrift
 page-header walks, RLE run-boundary tables (``ChunkPlan``) — is pure
 O(pages+runs) host work that the engine redoes from scratch on every
-``collect()``.  On the bench chip that host prep dominates the engine
-end-to-end wall (BENCH_r05: 3.98 s host prep vs 149 ms device
-pipeline).  This cache is the host-side sibling of
+``collect()`` (its share of the end-to-end wall on the attached chip
+is not measured).  This cache is the host-side sibling of
 ``exec/kernel_cache.py`` and the analog of the reference's footer
 cache (reference: GpuParquetScan caches parsed footers per file so the
 multi-file reader clips row groups without re-reading the tail):

@@ -18,12 +18,16 @@ argument (arXiv:2607.04489) applied to the three measured walls:
 Selection contract (the ``sql.fusion.enabled`` pattern end to end):
 
   * ``spark.rapids.tpu.kernel.backend`` picks ``xla`` (default, the
-    existing composed-array-op paths) or ``pallas``.
-  * The choice is PER CALL SITE with per-kernel fallback: a shape or
-    dtype a Pallas kernel doesn't cover silently takes the XLA path for
-    THAT kernel only — never the whole query (GPU-join-on-Hadoop,
-    arXiv:1904.11201: fallback cliffs dominate when the fast path isn't
-    universally applicable and degradation is coarse-grained).
+    composed-array-op paths — every program of which the TPU compiler
+    accepts) or ``pallas``.
+  * The choice is PER CALL SITE and STATIC: a shape or dtype a Pallas
+    kernel doesn't cover is deselected before dispatch and takes the
+    XLA path for THAT kernel only — never the whole query
+    (GPU-join-on-Hadoop, arXiv:1904.11201: fallback cliffs dominate
+    when the fast path isn't universally applicable and degradation is
+    coarse-grained).  A kernel that WAS selected and that the compiler
+    then refuses raises: nothing reroutes at run time, and a missing
+    Pallas extension is an error when ``pallas`` was asked for.
   * Every selection is observable: ``kernel.backend.pallas.hits`` and
     ``kernel.backend.pallas.fallbacks`` (plus reason- and family-tagged
     variants ``...fallbacks.<family>.<reason>``) in the metrics
@@ -38,11 +42,11 @@ TRACING a cached kernel (the aggregate's segmented reductions) count
 once per compile — the per-dispatch ground truth is always
 ``kernel.dispatches.<family>.<backend>``.
 
-Interpret mode: Pallas kernels run under ``interpret=True`` whenever
-the active jax backend is not a real TPU (``kernel.pallas.interpret``
-= auto), so CPU CI (`JAX_PLATFORMS=cpu`) executes the REAL kernel
-bodies and the parity gates exercise actual kernel semantics, not a
-skip.
+Interpret mode: Pallas kernels run under ``interpret=True`` when the
+active jax backend is not a TPU (``kernel.pallas.interpret`` = auto),
+so CPU CI (`JAX_PLATFORMS=cpu`) executes the REAL kernel bodies and
+the parity gates exercise actual kernel semantics, not a skip.  On a
+TPU ``auto`` always compiles; a backend probe that raises propagates.
 """
 
 from __future__ import annotations
@@ -55,10 +59,9 @@ XLA = "xla"
 PALLAS = "pallas"
 
 _lock = threading.Lock()
-_default_backend = PALLAS
+_default_backend = XLA
 _interpret_mode = "auto"        # auto | true | false
 _tile_bytes = 4 << 20           # kernel.pallas.tileBytes default
-_pallas_available: Optional[bool] = None
 # memoized resolution of interpret='auto' (the active-jax-backend
 # probe): jax.default_backend() is a per-dispatch cost the tile-plan /
 # kernel-selection hot path must not pay, and the platform cannot
@@ -76,7 +79,7 @@ def configure(conf) -> None:
     node is in scope."""
     from spark_rapids_tpu import config as cfg
     global _default_backend, _interpret_mode, _tile_bytes
-    backend = str(conf.get(cfg.KERNEL_BACKEND) or PALLAS).strip().lower()
+    backend = str(conf.get(cfg.KERNEL_BACKEND) or XLA).strip().lower()
     if backend not in (XLA, PALLAS):
         raise ValueError(
             f"spark.rapids.tpu.kernel.backend must be 'xla' or "
@@ -127,31 +130,18 @@ def resolve(stamped: Optional[str] = None) -> str:
     return default_backend()
 
 
-def pallas_available() -> bool:
-    """Import probe, memoized: environments without the Pallas
-    extension degrade to XLA everywhere (counted as fallbacks with
-    reason ``unavailable``)."""
-    global _pallas_available
-    if _pallas_available is None:
-        try:
-            from jax.experimental import pallas  # noqa: F401
-            from jax.experimental.pallas import tpu  # noqa: F401
-            _pallas_available = True
-        except Exception:
-            _pallas_available = False
-    return _pallas_available
-
-
 def interpret() -> bool:
     """Run Pallas kernels in interpreter mode?  ``auto`` (default):
-    interpret unless the active jax backend is a real TPU — so tier-1
-    CPU runs execute the genuine kernel bodies.  The knob pins it for
-    debugging (``true``) or to force Mosaic compilation (``false``).
+    interpret when the active jax backend is not a TPU — so tier-1
+    CPU runs execute the genuine kernel bodies — and compile on a TPU.
+    The knob pins it for debugging (``true``) or to force Mosaic
+    compilation (``false``).
 
-    The ``auto`` probe (``jax.default_backend()``) is memoized: it used
-    to re-resolve on every dispatch/tile-plan lookup, but the active
-    platform cannot change mid-process — only the pinned modes bypass
-    the memo (they are a plain mode-string compare anyway)."""
+    The ``auto`` probe (``jax.default_backend()``) is memoized: the
+    active platform cannot change mid-process — only the pinned modes
+    bypass the memo (they are a plain mode-string compare anyway).  A
+    probe that raises propagates: answering "interpret" there would
+    run a TPU's kernels interpreted without anyone asking for it."""
     global _auto_interpret
     with _lock:
         mode = _interpret_mode
@@ -160,11 +150,8 @@ def interpret() -> bool:
     if mode in ("false", "0", "no", "off"):
         return False
     if _auto_interpret is None:
-        try:
-            import jax
-            _auto_interpret = jax.default_backend() != "tpu"
-        except Exception:
-            _auto_interpret = True
+        import jax
+        _auto_interpret = jax.default_backend() != "tpu"
     return _auto_interpret
 
 
@@ -242,15 +229,17 @@ def selection_snapshot() -> dict:
 def choose(family: str, backend: str, supported: bool,
            reason: str = "unsupported") -> str:
     """Resolve one call site's backend: ``pallas`` only when requested
-    AND available AND the kernel covers this shape/dtype; anything else
-    is an observable per-kernel fallback to ``xla``."""
+    AND the kernel covers this shape/dtype — a static decision, made
+    and counted before dispatch.  An uncovered shape is an observable
+    per-kernel deselection to ``xla``.  Nothing here (or after it)
+    catches a compile failure of a selected kernel: it raises, and so
+    does the Pallas import when ``pallas`` was asked for."""
     if backend != PALLAS:
-        return XLA
-    if not pallas_available():
-        fallback(family, "unavailable")
         return XLA
     if not supported:
         fallback(family, reason)
         return XLA
+    from jax.experimental import pallas  # noqa: F401
+    from jax.experimental.pallas import tpu  # noqa: F401
     hit(family)
     return PALLAS

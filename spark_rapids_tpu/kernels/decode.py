@@ -99,28 +99,23 @@ def _unpack_xla(bytes_arr: jnp.ndarray, w: int, ncap: int) -> jnp.ndarray:
                    axis=1)
 
 
-def _unpack_body(w: int, B: int):
-    """Pallas kernel body for one [B]-value block: bytes -> LE u32
-    words -> static (word, shift) slots — bit-identical integer math to
-    ``_unpack_xla``'s word path, zero gathers."""
-    def kernel(b_ref, o_ref):
-        by = b_ref[:]
-        # byte->LE-word shifts built with an in-kernel iota: a closure
-        # constant array would be a captured value pallas_call rejects
-        sh = jax.lax.broadcasted_iota(jnp.uint32, (1, 4), 1) * \
-            jnp.uint32(8)
-        words = (by.reshape(-1, 4).astype(jnp.uint32) << sh
-                 ).sum(axis=1, dtype=jnp.uint32)
-        W = words.reshape(B // 32, w)
+def _unpack_body(w: int):
+    """Pallas kernel body for one block of 32-value groups, laid out
+    one group per LANE: ``w_ref`` is [w, L] (word a of every group in
+    row a), ``o_ref`` is [32, L] (value j of every group in row j).
+    Static (word, shift) slots per output row — bit-identical integer
+    math to ``_unpack_xla``'s word path, zero gathers, and only
+    row-sliced 2-D vector ops (the chip's compiler refuses the 1-D
+    byte->word reshape and the strided column picks of a [groups, w]
+    layout)."""
+    def kernel(w_ref, o_ref):
         mask = jnp.uint32((1 << w) - 1)
-        outs = []
         for j in range(32):
             a, s = (j * w) >> 5, (j * w) & 31
-            v = W[:, a] >> jnp.uint32(s)
+            v = w_ref[a, :] >> jnp.uint32(s)
             if s + w > 32:
-                v = v | (W[:, a + 1] << jnp.uint32(32 - s))
-            outs.append(v & mask)
-        o_ref[:] = jnp.stack(outs, axis=1).reshape(-1)
+                v = v | (w_ref[a + 1, :] << jnp.uint32(32 - s))
+            o_ref[j, :] = v & mask
     return kernel
 
 
@@ -135,15 +130,23 @@ def _unpack_pallas(bytes_arr: jnp.ndarray, w: int,
                    ncap: int) -> jnp.ndarray:
     from jax.experimental import pallas as pl
     B = min(ncap, _unpack_block(ncap))
-    bpb = B * w // 8                  # bytes per block
-    return pl.pallas_call(
-        _unpack_body(w, B),
-        grid=(ncap // B,),
-        in_specs=[pl.BlockSpec((bpb,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((B,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((ncap,), jnp.uint32),
+    G, L = ncap // 32, B // 32        # 32-value groups: total / block
+    # bytes -> LE u32 words and the group-per-lane transposes are plain
+    # dense XLA ops around the kernel
+    b4 = bytes_arr.reshape(-1, 4).astype(jnp.uint32)
+    words = (b4[:, 0] | (b4[:, 1] << jnp.uint32(8)) |
+             (b4[:, 2] << jnp.uint32(16)) | (b4[:, 3] << jnp.uint32(24)))
+    out = pl.pallas_call(
+        _unpack_body(w),
+        grid=(G // L,),
+        # i32 row index: under x64 a Python 0 traces as i64, which the
+        # chip's compiler refuses in an index map
+        in_specs=[pl.BlockSpec((w, L), lambda i: (jnp.int32(0), i))],
+        out_specs=pl.BlockSpec((32, L), lambda i: (jnp.int32(0), i)),
+        out_shape=jax.ShapeDtypeStruct((32, G), jnp.uint32),
         interpret=kb.interpret(),
-    )(bytes_arr)
+    )(words.reshape(G, w).T)
+    return out.T.reshape(-1)
 
 
 def _unpack_supported(w: int, ncap: int, nbytes: int) -> bool:

@@ -95,6 +95,10 @@ def tpu_semaphore(metrics=None):
         gate.release()
 
 
+# memory budget assumed on a platform that is not a TPU
+_HOST_PLATFORM_BUDGET = 8 << 30
+
+
 class TpuDeviceManager:
     """Holds device handles + memory budget (XLA owns the real allocator)."""
 
@@ -105,13 +109,15 @@ class TpuDeviceManager:
         self.devices = jax.devices()
         self.default_device = self.devices[0]
         self.pool_fraction = pool_fraction
-        stats = {}
-        try:
-            stats = self.default_device.memory_stats() or {}
-        except Exception:
-            pass
-        limit = stats.get("bytes_limit")
-        self.hbm_budget = int(limit * pool_fraction) if limit else 8 << 30
+        if self.default_device.platform == "tpu":
+            # the budget is the device's own limit or start-up fails:
+            # a guessed pool would admit work against memory that may
+            # not exist
+            limit = self.default_device.memory_stats()["bytes_limit"]
+            self.hbm_budget = int(limit * pool_fraction)
+        else:
+            # host platforms (the CPU test platform) report no limit
+            self.hbm_budget = _HOST_PLATFORM_BUDGET
 
     @classmethod
     def get(cls) -> "TpuDeviceManager":

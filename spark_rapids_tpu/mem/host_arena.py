@@ -3,13 +3,14 @@
 Role analog: the RMM arena allocator + pinned host pool of the reference
 (reference: GpuDeviceManager.scala:196-270), managing *host* staging memory
 under TPU/XLA (which owns HBM itself).  Builds the shared library on first
-use with g++; falls back to a pure-Python malloc-per-allocation shim if no
-toolchain is available, keeping the API identical.
+use with g++ (a failed build raises); where there is no g++ at all a
+pure-Python malloc-per-allocation shim keeps the API identical.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,20 +25,25 @@ _SRC = os.path.join(os.path.dirname(os.path.dirname(
 
 
 def _build_lib() -> Optional[ctypes.CDLL]:
-    so_path = os.path.join(os.path.dirname(_SRC), "libarena.so")
-    if not os.path.exists(so_path) or \
-            os.path.getmtime(so_path) < os.path.getmtime(_SRC):
+    # the library's name carries the hash of the source it was built
+    # from: what loads is always built from the committed arena.cpp,
+    # never a stale binary left in the tree (file times do not survive
+    # a copy or a checkout, so they cannot decide this)
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    so_path = os.path.join(os.path.dirname(_SRC),
+                           f"libarena-{digest}.so")
+    if not os.path.exists(so_path):
+        tmp = f"{so_path}.{os.getpid()}.tmp"
         try:
             subprocess.run(
                 ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 _SRC, "-o", so_path],
+                 _SRC, "-o", tmp],
                 check=True, capture_output=True)
-        except (subprocess.CalledProcessError, FileNotFoundError):
-            return None
-    try:
-        lib = ctypes.CDLL(so_path)
-    except OSError:
-        return None
+        except FileNotFoundError:
+            return None         # no toolchain: the Python shim
+        os.replace(tmp, so_path)    # atomic under concurrent builders
+    lib = ctypes.CDLL(so_path)
     lib.arena_create.restype = ctypes.c_void_p
     lib.arena_create.argtypes = [ctypes.c_size_t, ctypes.c_size_t]
     lib.arena_destroy.argtypes = [ctypes.c_void_p]
@@ -60,6 +66,12 @@ def _get_lib() -> Optional[ctypes.CDLL]:
         if _LIB is None:
             _LIB = _build_lib() or False
     return _LIB or None
+
+
+def native_available() -> bool:
+    """Is the arena the compiled ``native/arena.cpp`` (built on first
+    use) or the Python shim (no ``g++`` on this machine)?"""
+    return _get_lib() is not None
 
 
 class ArenaAllocation:

@@ -26,11 +26,7 @@ What replay does NOT do: it does not touch the in-process kernel cache
 (exec/kernel_cache) — the serving path still traces each kernel on
 first use, but that trace's compile classifies ``persistent`` (a cache
 read, milliseconds) instead of ``fresh`` (the CI corpus-replay gate
-asserts exactly this on ``/compiles``).  Donating kernels are absent
-from the corpus by design: they are barred from the persistent cache
-(jax 0.4.37 reload mis-applies donation aliasing — see
-exec/kernel_cache._no_persistent_cache) and pay one fresh compile per
-process instead.
+asserts exactly this on ``/compiles``).
 
 Registry counters: ``sched.precompile.plans`` / ``.programs`` /
 ``.warmed`` / ``.skipped`` (no payload) / ``.failed`` / ``.dedup``.

@@ -230,14 +230,11 @@ class QueryService:
 
     @staticmethod
     def _derived_budget() -> int:
-        """Default budget: the device manager's HBM pool (XLA's
-        bytes_limit x pool fraction; 8 GiB when the backend reports no
-        limit — the CPU test platform)."""
-        try:
-            from spark_rapids_tpu.mem.device import TpuDeviceManager
-            return int(TpuDeviceManager.get().hbm_budget)
-        except Exception:
-            return 8 << 30
+        """Default budget: the device manager's HBM pool (the
+        device's bytes_limit x pool fraction on a TPU; a fixed 8 GiB
+        on the CPU test platform, chosen by platform)."""
+        from spark_rapids_tpu.mem.device import TpuDeviceManager
+        return int(TpuDeviceManager.get().hbm_budget)
 
     # -- estimates -----------------------------------------------------------
     def _estimate(self, plan, explicit: Optional[int]) -> int:

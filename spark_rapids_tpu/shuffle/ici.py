@@ -44,25 +44,6 @@ from spark_rapids_tpu.expr.eval_tpu import ColVal, hash_colval
 from spark_rapids_tpu.plan.logical import Schema
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the top-level API (with
-    ``check_vma``) when present, else the experimental module (whose
-    equivalent knob is ``check_rep``).  Raises NotImplementedError with
-    a skip-friendly reason when neither exists."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-    except ImportError as e:
-        raise NotImplementedError(
-            "this jax has neither jax.shard_map nor "
-            "jax.experimental.shard_map — ICI shuffle unavailable"
-        ) from e
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def partition_targets(key_vals: Sequence[ColVal], n_parts: int,
                       seed: int = 42) -> jnp.ndarray:
     """Spark-compatible murmur3 pmod partition ids
@@ -215,8 +196,8 @@ def make_distributed_agg_step(mesh: Mesh, axis: str,
     out_dtypes = _probe_out_dtypes(schema, groupings, aggregates, out_names)
     out_specs = (_col_specs(out_dtypes, P(axis)), P(axis))
 
-    step = _shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
+    step = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
     return jax.jit(step), out_dtypes
 
 
@@ -351,9 +332,9 @@ def make_exchange_step(mesh: Mesh, axis: str, names, dtypes, aux_key):
         return _cols_to_leaves(received.columns), jnp.reshape(
             jnp.asarray(received.num_rows, dtype=jnp.int32), (1,))
 
-    step = jax.jit(_shard_map(
+    step = jax.jit(jax.shard_map(
         local_step, mesh=mesh, in_specs=(P(axis), P(axis)),
-        out_specs=(P(axis), P(axis))))
+        out_specs=(P(axis), P(axis)), check_vma=False))
     _STEP_CACHE[key] = step
     return step
 
@@ -470,9 +451,9 @@ def ring_broadcast_batch(batch: DeviceBatch) -> dict:
         return _cols_to_leaves(out.columns), jnp.reshape(
             jnp.asarray(out.num_rows, jnp.int32), (1,))
 
-    step = jax.jit(_shard_map(
+    step = jax.jit(jax.shard_map(
         local_step, mesh=mesh, in_specs=(P("shuffle"), P("shuffle")),
-        out_specs=(P(), P())))
+        out_specs=(P(), P()), check_vma=False))
     out_leaves, out_rows = step(leaves, counts)
     n_out = int(np.asarray(out_rows)[0])
 

@@ -1,0 +1,42 @@
+"""CPU rehearsal of ``chip_smoke.py``: the script's phases run here at
+a few thousand rows so the script cannot rot between chip runs.  The
+device check is the one thing not rehearsed — the test stands in for
+it (the script itself offers no way round ``require_tpu``)."""
+
+import json
+
+import pytest
+
+
+def _lines(capsys):
+    return [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("{")]
+
+
+def test_smoke_phases_on_cpu(capsys):
+    import chip_smoke
+    device = chip_smoke.device_info()
+    assert device["platform"] == "cpu"
+    # parity with the plain reference and zero fallbacks are asserted
+    # inside the phases; any failure raises out of run()
+    chip_smoke.run(rows=6000, seed=5, device=device, ici=False)
+    out = _lines(capsys)
+    assert out[-1] == {"ok": True, "device": device}
+    phases = [o.get("phase") for o in out[:-1]]
+    assert phases.count("q6") == 3 and phases.count("q3") == 1
+    q6 = [o for o in out if o.get("phase") == "q6"]
+    assert all(o["kernel.dispatches"] > 0 for o in q6), \
+        "an execution did not reach the device (result cache?)"
+    assert {o["lo"] for o in q6} == set(chip_smoke.Q6_BINDINGS)
+    sel = next(o for o in out if o.get("phase") ==
+               "kernel_backend_selection")
+    assert not any(k.startswith("kernel.backend.pallas.hits")
+                   for k in sel), "default backend selected Pallas"
+
+
+def test_smoke_refuses_without_tpu(capsys):
+    import chip_smoke
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main([])
+    assert e.value.code not in (0, None)
+    assert capsys.readouterr().out == ""    # no result line, no data

@@ -663,8 +663,9 @@ def test_warm_join_zero_fresh_compiles(tmp_path):
         {"k": [i % 6 for i in range(1800)],
          "x": [float(i % 120) for i in range(1800)]}), p)
     env = dict(os.environ)
-    env["SPARK_RAPIDS_TPU_CPU_COMPILE_CACHE"] = "1"
-    env.pop("SPARK_RAPIDS_TPU_COMPILE_CACHE", None)
+    # the shared store's compile cache applies only where jax's own
+    # variable does not place the cache (the environment wins)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     mgr = FleetManager(
         str(tmp_path / "store"),
         base_conf={

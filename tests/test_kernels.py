@@ -522,13 +522,14 @@ def test_decode_null_validity_interaction(tmp_path):
 
 def test_backend_knob_configures_process_default():
     from spark_rapids_tpu import TpuSparkSession
-    TpuSparkSession({"spark.rapids.tpu.kernel.backend": "xla"})
-    assert kb.default_backend() == "xla"
-    # a session WITHOUT the knob re-asserts the default — PALLAS since
-    # the PR 14 flip (the scan_cache.configure idiom: no leakage into
-    # later sessions)
-    TpuSparkSession({})
+    TpuSparkSession({"spark.rapids.tpu.kernel.backend": "pallas"})
     assert kb.default_backend() == "pallas"
+    # a session WITHOUT the knob re-asserts the default — XLA, the
+    # path every program of which compiles for the chip
+    # (tests/test_chip_compile.py; the scan_cache.configure idiom: no
+    # leakage into later sessions)
+    TpuSparkSession({})
+    assert kb.default_backend() == "xla"
     with pytest.raises(ValueError):
         TpuSparkSession({"spark.rapids.tpu.kernel.backend": "vulkan"})
     with pytest.raises(ValueError):

@@ -138,10 +138,11 @@ def test_background_start_and_wait(tmp_path):
         stats["programs"]
 
 
-def test_donating_programs_record_no_replay_payload(tmp_path):
-    """Donating kernels are barred from the persistent cache, so the
-    corpus must never carry a payload that would re-write them into
-    it.  A fused chain over a donate-safe producer exercises one."""
+def test_donating_programs_record_replay_payload(tmp_path):
+    """Donating kernels are cached and replayed like any other
+    program: the corpus carries their payload (donate_argnums rides
+    the recorded jit kwargs).  A fused chain over a donate-safe
+    producer exercises one."""
     s, corpus = _corpus_session(tmp_path)
     df = s.create_dataframe(
         {"k": [i % 5 for i in range(800)],
@@ -157,4 +158,7 @@ def test_donating_programs_record_no_replay_payload(tmp_path):
     recs = [json.loads(line) for line in open(corpus)]
     fused = [p for r in recs for p in r["programs"]
              if p["family"] == "fused_stage"]
-    assert fused and not any(p.get("replay") for p in fused)
+    assert fused and all(p.get("replay") for p in fused)
+    from spark_rapids_tpu.exec import kernel_cache as kc
+    assert any(kc.load_replay_payload(p["replay"])["jit"].get(
+        "donate_argnums") for p in fused)
