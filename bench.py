@@ -133,7 +133,7 @@ def _dispatch_count_probe(n: int = 160_000, files: int = 2) -> dict:
             # first, so the unfused number excludes every kernel the
             # two paths share (scan decode, agg update/merge/final) —
             # it is NOT a standalone compile-breadth figure; compare
-            # compile bills via bench_compile_bill.py fresh processes
+            # compile bills in fresh processes (the /compiles ledger)
             "kernels_compiled_incremental": int(cold_misses),
             "dispatches_saved":
                 int(d.get("fusion.dispatchesSaved", 0)),
@@ -1340,8 +1340,7 @@ def _write_trend_file(result: dict, n: int, files: int,
     is set), so the perf trajectory across PRs is machine-readable
     from a single rolling series — `BENCH_trend.json` is the one
     canonical trend file (earlier per-PR snapshot files were folded
-    into it and deleted); `bench_compile_bill.py --abi-report`
-    appends `kind: "compile_bill"` records to the same series."""
+    into it and deleted)."""
     probe = result.get("dispatch_probe") or {}
     conc = result.get("concurrent") or {}
     kern = result.get("kernels") or {}
@@ -1475,9 +1474,8 @@ def append_trend_record(record: dict,
                         out_name: str = "BENCH_trend.json") -> str:
     """Append one record to the rolling trend series — the ONE writer
     of the 'spark-rapids-tpu-bench-trend/3' file (bench runs append
-    their run records here; bench_compile_bill.py --abi-report appends
-    ``kind: "compile_bill"`` records through the same code path, so
-    schema/locking/corrupt-handling changes happen in one place)."""
+    their run records here, so schema/locking/corrupt-handling changes
+    happen in one place)."""
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         out_name)
     series = {"schema": "spark-rapids-tpu-bench-trend/3", "runs": []}

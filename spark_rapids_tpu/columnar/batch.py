@@ -24,6 +24,7 @@ byte-tensor string kernels (SURVEY.md §7 hard part #3).
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, replace
 from typing import List, Optional, Sequence, Tuple
 
@@ -562,7 +563,8 @@ def _run_compact(b: DeviceBatch, fn, t: int) -> DeviceBatch:
     return DeviceBatch(b.names, nb.columns, nb.num_rows)
 
 
-def _compact_for_download(batches: Sequence[DeviceBatch]):
+def _compact_for_download(batches: Sequence[DeviceBatch],
+                          wait_span: Optional[str] = None):
     """Re-bucket batches whose capacity vastly exceeds their row count
     (e.g. an aggregate output that inherited a multi-million-row concat
     capacity) so the terminal download moves rows, not padding.
@@ -571,7 +573,14 @@ def _compact_for_download(batches: Sequence[DeviceBatch]):
     kernel — including the plain full-capacity pack of batches that end
     up uncompacted — is built and dispatched BEFORE the single fused
     row-count read, so nothing compiles or loads after the first
-    (dispatch-degrading) download."""
+    (dispatch-degrading) download.
+
+    That read is the first point at which the host blocks on the
+    device: everything dispatched so far has to finish before the
+    counts arrive.  ``wait_span`` names it in the trace (the terminal
+    collect passes ``collect.deviceWait``); it is a name round a wait
+    that is there anyway, never a sync of its own."""
+    from spark_rapids_tpu.obs import trace as obstrace
     traced = [b for b in batches
               if not isinstance(b.num_rows, (int, np.integer))]
     candidates = {}
@@ -601,7 +610,9 @@ def _compact_for_download(batches: Sequence[DeviceBatch]):
         if len(devs) > 1:
             tgt = sorted(devs, key=lambda d: d.id)[0]
             scalars = [jax.device_put(s, tgt) for s in scalars]
-        counts = np.asarray(jnp.stack(scalars))
+        with obstrace.span(wait_span, cat="query") if wait_span \
+                else contextlib.nullcontext():
+            counts = np.asarray(jnp.stack(scalars))
         for b, n in zip(traced, counts):
             b.num_rows = int(n)
     out, out_packed = [], []
@@ -630,9 +641,17 @@ def _slice_head(batch: DeviceBatch, cap: int) -> DeviceBatch:
 
 def to_arrow_all(batches: Sequence[DeviceBatch]) -> List[pa.Table]:
     """Convert many batches: ALL pack kernels dispatch before the first
-    download, so every device op runs on the fast pre-download path."""
-    batches, packed = _compact_for_download(batches)
-    return [to_arrow(b, p) for b, p in zip(batches, packed)]
+    download, so every device op runs on the fast pre-download path.
+
+    Two spans split the terminal collect: ``collect.deviceWait`` (the
+    first blocking read-back, which ends when the device has done the
+    query's work) and ``collect.download`` (the compacted batches'
+    pack, the host copies and the Arrow build)."""
+    from spark_rapids_tpu.obs import trace as obstrace
+    batches, packed = _compact_for_download(
+        batches, wait_span="collect.deviceWait")
+    with obstrace.span("collect.download", cat="query"):
+        return [to_arrow(b, p) for b, p in zip(batches, packed)]
 
 
 def to_arrow(batch: DeviceBatch,
@@ -785,7 +804,7 @@ def _concat_batches_nosync(batches: Sequence[DeviceBatch],
 
     from spark_rapids_tpu.exec import kernel_abi, kernel_cache as kc
     cap = bucket_rows(sum(b.capacity for b in batches), min_bucket)
-    key = ("concat_nosync", cap,
+    key = ("concat", cap,
            tuple(kernel_abi.erased_key(b) for b in batches))
     fn = kc.get_kernel(key, lambda: _concat_nosync_impl,
                        static_argnames=("cap",))

@@ -608,7 +608,7 @@ KERNEL_ABI_ENABLED = conf(
     "near-miss batch sizes share one compiled program. Every erased "
     "shape is a subset of the legacy power-of-two ladder, so disabling "
     "this only multiplies compiles — it never changes results (the "
-    "bench_compile_bill --abi-report gate diffs the two).", bool)
+    "ci.sh ABI-collapse gate diffs the two).", bool)
 
 KERNEL_ABI_TIER_STRIDE = conf(
     "spark.rapids.tpu.kernel.abi.tierStride", 2,

@@ -218,7 +218,7 @@ class _Timed:
     query here at batch granularity — one thread-local read + one bool
     check when no cancellation is pending."""
 
-    __slots__ = ("metrics", "name", "t0")
+    __slots__ = ("metrics", "name", "t0", "sid")
 
     def __init__(self, metrics: Metrics, name: Optional[str]):
         self.metrics = metrics
@@ -226,13 +226,19 @@ class _Timed:
 
     def __enter__(self):
         _cancel.check_current()
+        # a named span is open on this thread until exit, so what runs
+        # inside (kernel.compile, a nested operator) hangs under it
+        self.sid = _trace.open_span() \
+            if self.name is not None and _trace.is_enabled() else 0
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *a):
         dur = time.perf_counter_ns() - self.t0
         self.metrics.add_time_ns(dur)
-        if self.name is not None:
+        if self.sid:
+            _trace.close_span(self.sid, self.name, self.t0, dur)
+        elif self.name is not None:
             _trace.record(self.name, self.t0, dur)
 
 
@@ -241,7 +247,7 @@ def timed(metrics: Metrics, name: Optional[str] = None):
 
 
 class _TimedExtra:
-    __slots__ = ("metrics", "key", "t0")
+    __slots__ = ("metrics", "key", "t0", "sid")
 
     def __init__(self, metrics: Metrics, key: str):
         self.metrics = metrics
@@ -249,13 +255,17 @@ class _TimedExtra:
 
     def __enter__(self):
         _cancel.check_current()   # prefetch-thread batch checkpoint
+        self.sid = _trace.open_span() if _trace.is_enabled() else 0
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *a):
         dur = time.perf_counter_ns() - self.t0
         self.metrics.add_extra(self.key, dur)
-        _trace.record(self.key, self.t0, dur)
+        if self.sid:
+            _trace.close_span(self.sid, self.key, self.t0, dur)
+        else:
+            _trace.record(self.key, self.t0, dur)
 
 
 def timed_extra(metrics: Metrics, key: str):

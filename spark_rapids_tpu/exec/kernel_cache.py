@@ -15,7 +15,10 @@ so batch capacity does not belong in the key.
 
 from __future__ import annotations
 
+import functools
+import re
 import threading
+import time as _time
 from collections import OrderedDict
 from typing import Any, Callable
 
@@ -100,19 +103,6 @@ def exprs_sig(exprs) -> Any:
     return tuple(expr_sig(e) for e in exprs)
 
 
-# -- compile-bill instrumentation (PERF.md "compile bill") ------------------
-# When SRT_COMPILE_LOG is set, every kernel call whose (key, arg-shape)
-# combination is new is timed and recorded — jax.jit compiles lazily per
-# shape bucket, so the first call's wall is trace+compile (+ one async
-# dispatch).  dump_compile_log()
-# returns [(kernel key repr, shape sig repr, seconds)].
-import os as _os
-import time as _time
-
-COMPILE_LOG_ENABLED = bool(_os.environ.get("SRT_COMPILE_LOG"))
-_COMPILE_LOG: list = []
-
-
 def _shape_sig(args, kwargs):
     # the treedef rides the signature as the OBJECT (hashable, eq by
     # structure) — repr'ing it per dispatch would dominate the
@@ -145,31 +135,10 @@ class _ShapeSeen:
             return True
 
 
-def _instrument(key, fn):
-    seen = _ShapeSeen()
-
-    def wrapped(*args, **kwargs):
-        sig = _shape_sig(args, kwargs)
-        if not seen.claim(sig):
-            return fn(*args, **kwargs)
-        t0 = _time.perf_counter()
-        out = fn(*args, **kwargs)
-        dt_ = _time.perf_counter() - t0
-        with _LOCK:
-            _COMPILE_LOG.append((repr(key)[:160], repr(sig[1])[:120],
-                                 dt_))
-        return out
-    return wrapped
-
-
-def dump_compile_log() -> list:
-    with _LOCK:
-        return list(_COMPILE_LOG)
-
-
 def _replay_payload(inner: Callable, jit_kwargs: dict,
-                    args, kwargs) -> "str | None":
-    """Pickle (traceable, jit kwargs, abstract argument shapes) into a
+                    args, kwargs, family: str = None) -> "str | None":
+    """Pickle (traceable, jit kwargs, abstract argument shapes, kernel
+    family: the program's name, see :func:`jit_named`) into a
     base64 replay payload for the precompile corpus — everything the
     AOT precompile service (sched/precompile.py) needs to re-lower and
     re-compile this exact program in a fresh process, with no data, no
@@ -193,7 +162,8 @@ def _replay_payload(inner: Callable, jit_kwargs: dict,
     try:
         sds = jax.tree_util.tree_map(to_sds, (args, kwargs))
         raw = pickle.dumps({"fn": inner, "jit": jit_kwargs,
-                            "args": sds[0], "kwargs": sds[1]},
+                            "args": sds[0], "kwargs": sds[1],
+                            "family": family},
                            protocol=pickle.HIGHEST_PROTOCOL)
         if len(raw) > (2 << 20):
             return None          # pathological payload: skip, don't bloat
@@ -209,6 +179,15 @@ def load_replay_payload(payload: str):
     import pickle
     import zlib
     return pickle.loads(zlib.decompress(base64.b64decode(payload)))
+
+
+def jit_replayed(spec: dict) -> Callable:
+    """The jitted callable of a loaded replay payload, built as
+    ``get_kernel`` built the original: the module's name is part of
+    what jax's persistent cache hashes, so a program warmed under
+    another name is never hit."""
+    return jit_named(spec["fn"], spec.get("family") or "other",
+                     **(spec.get("jit") or {}))
 
 
 def _observe_compiles(key: Any, fn: Callable, backend: str = None,
@@ -252,21 +231,12 @@ def _observe_compiles(key: Any, fn: Callable, backend: str = None,
             if replay_src is not None and obscompile.corpus_path() \
                     and obscompile.corpus_replay_enabled():
                 replay = _replay_payload(replay_src[0], replay_src[1],
-                                         args, kwargs)
+                                         args, kwargs, family=fam)
             obscompile.record_compile(
                 key=key, family=fam, backend=bk, leaves=sig[1],
                 t0_ns=t0, dur_ns=dur,
                 tier=obscompile.classify_tier(probe),
-                replay=replay)
-            if COMPILE_LOG_ENABLED:
-                # the legacy SRT_COMPILE_LOG ledger shares this
-                # wrapper's first-call detection (one _shape_sig per
-                # dispatch, not two); _instrument only installs for
-                # kernels built while the observatory is disabled
-                with _LOCK:
-                    _COMPILE_LOG.append((repr(key)[:160],
-                                         repr(sig[1])[:120],
-                                         dur / 1e9))
+                replay=replay, build=obscompile.build_split(probe))
     return wrapped
 
 
@@ -290,6 +260,35 @@ def _family(key: Any) -> str:
     if isinstance(key, tuple) and key and isinstance(key[0], str):
         return key[0]
     return "other"
+
+
+def program_name(family: str) -> str:
+    """``jit_<family>``: what a device trace, an HLO dump and the
+    persistent cache call a kernel family's programs.  The family cut
+    to ``[a-z0-9_]``, nothing else: no key hash, no operator id, no
+    shape, so one family at one set of shapes stays one executable.
+    jax puts the ``jit_`` before the traced function's name."""
+    return "jit_" + _function_name(family)
+
+
+def _function_name(family: str) -> str:
+    return re.sub(r"[^a-z0-9_]", "_", str(family).lower()) or "other"
+
+
+def jit_named(inner: Callable, family: str, **jit_kwargs) -> Callable:
+    """``jax.jit(inner, **jit_kwargs)`` whose program is named after
+    its kernel family (:func:`program_name`).  jax names a program
+    after the traced function's ``__name__``; a ``functools.partial``
+    or a closure has none worth reading (``jit__unknown``,
+    ``jit__lambda``, ``jit_kernel``), so the traceable is wrapped in a
+    function that carries the family's.  ``functools.wraps`` keeps
+    ``inner``'s signature visible, which is what ``static_argnames``
+    and ``donate_argnums`` are resolved against."""
+    @functools.wraps(inner)
+    def named(*args, **kwargs):
+        return inner(*args, **kwargs)
+    named.__name__ = named.__qualname__ = _function_name(family)
+    return jax.jit(named, **jit_kwargs)
 
 
 def _count_dispatches(key: Any, fn: Callable,
@@ -353,48 +352,6 @@ def get_kernel(key: Any, builder: Callable[[], Callable],
     the sum of the two program-tier counters."""
     from spark_rapids_tpu.obs import registry as _obsreg
     fam = _family(key)
-    pairs = [("kernel.dispatches", 1), (f"kernel.dispatches.{fam}", 1)]
-    if backend:
-        pairs.append((f"kernel.dispatches.{fam}.{backend}", 1))
-    pairs = tuple(pairs)
-
-    def wrapped(*args, **kwargs):
-        _obsreg.get_registry().inc_many(*pairs)
-        # ledger: every dispatch bills the owning tenant with the SAME
-        # n as the global counter — the CI exactness gate's invariant
-        _acct.charge("kernel.dispatches", 1)
-        return fn(*args, **kwargs)
-    return wrapped
-
-
-def get_kernel(key: Any, builder: Callable[[], Callable],
-               oom_retry: bool = True, backend: str = None,
-               **jit_kwargs) -> Callable:
-    """Return the cached jitted kernel for ``key``, building+jitting via
-    ``builder`` on first use (LRU-bounded).
-
-    ``oom_retry=False`` skips the HBM-OOM retry wrapper — required when
-    the kernel donates input buffers (a retry would replay arguments
-    the failed dispatch may already have consumed).  Call sites that
-    donate must fold the donation into ``key``: the same signature
-    jitted with and without ``donate_argnums`` is two executables.
-
-    ``backend`` tags this kernel's per-dispatch family counter with the
-    kernel backend ('pallas'/'xla') at backend-aware call sites; the
-    backend must already be folded into ``key`` by the caller (two
-    backends are two executables).
-
-    Cache-tier counters (the compile-observatory split): an in-memory
-    hit here bumps ``kernel.cache.memHits`` (``kernel.cache.hits`` is
-    its documented legacy alias, key granularity); a miss invokes the
-    builder (``kernel.cache.misses``, distinct KEYS built), after which
-    each first (key, shape) call classifies as ``kernel.cache.compiles``
-    (fresh XLA compile) or ``kernel.cache.persistentHits`` (persistent-
-    cache reload) via obs/compile.py — note the granularity: one key
-    can lazily compile several shape-bucket programs, so misses is not
-    the sum of the two program-tier counters."""
-    from spark_rapids_tpu.obs import registry as _obsreg
-    fam = _family(key)
     with _LOCK:
         fn = _CACHE.get(key)
         if fn is not None:
@@ -407,7 +364,7 @@ def get_kernel(key: Any, builder: Callable[[], Callable],
     _obsreg.get_registry().inc_many(
         ("kernel.cache.misses", 1), (f"kernel.cache.misses.{fam}", 1))
     inner = builder()
-    fn = jax.jit(inner, **jit_kwargs)
+    fn = jit_named(inner, fam, **jit_kwargs)
     from spark_rapids_tpu.obs import compile as _obscompile
     observed = _obscompile.is_enabled()
     if observed:
@@ -417,10 +374,6 @@ def get_kernel(key: Any, builder: Callable[[], Callable],
     if oom_retry:
         fn = _with_oom_recovery(fn)
     fn = _count_dispatches(key, fn, backend)
-    if COMPILE_LOG_ENABLED and not observed:
-        # legacy SRT_COMPILE_LOG path for observatory-disabled builds;
-        # observed kernels feed _COMPILE_LOG from _observe_compiles
-        fn = _instrument(key, fn)
     with _LOCK:
         cur = _CACHE.setdefault(key, fn)
         if len(_CACHE) > _MAX_ENTRIES:

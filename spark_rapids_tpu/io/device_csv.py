@@ -32,12 +32,12 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
                                              _bucket_strlen, bucket_rows)
+from spark_rapids_tpu.exec.kernel_cache import jit_named
 from spark_rapids_tpu.plan.logical import Schema
 
 
@@ -79,8 +79,9 @@ def prescan(raw: bytes, n_cols: int, sep: bytes = b",",
     return a, n_rows, [max(int(w), 1) for w in widths]
 
 
-@partial(jax.jit, static_argnames=("n_cols", "cap", "widths",
-                                   "dtypes_key", "sep", "parse_cols"))
+@partial(jit_named, family="decode_csv",
+         static_argnames=("n_cols", "cap", "widths", "dtypes_key", "sep",
+                          "parse_cols"))
 def _decode_kernel(raw: jnp.ndarray, n_rows, n_cols: int, cap: int,
                    widths: Tuple[int, ...], dtypes_key: Tuple[str, ...],
                    sep: int, parse_cols: Tuple[int, ...]):

@@ -36,13 +36,13 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.orc as paorc
 
-import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
                                              _bucket_strlen, bucket_rows,
                                              from_arrow)
+from spark_rapids_tpu.exec.kernel_cache import jit_named
 from spark_rapids_tpu.io.device_parquet import (RunTable, _def_expand,
                                                 _dict_gather, _pad_np,
                                                 _string_dict_matrix,
@@ -403,7 +403,7 @@ def decode_byte_rle(buf: bytes, n: int) -> np.ndarray:
 # Device expansion (big-endian twin of device_parquet._expand_runs)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("cap",))
+@partial(jit_named, family="decode_runs_be", static_argnames=("cap",))
 def _expand_runs_be(runs_mat: jnp.ndarray, packed: jnp.ndarray,
                     cap: int) -> jnp.ndarray:
     """Expand SHORT_REPEAT/DIRECT runs; DIRECT regions are MSB-first.

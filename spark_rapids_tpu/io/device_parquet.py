@@ -35,12 +35,12 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as papq
 
-import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
                                              bucket_rows, from_arrow)
+from spark_rapids_tpu.exec.kernel_cache import jit_named
 from spark_rapids_tpu.io import parquet_meta as pm
 from spark_rapids_tpu.plan.logical import Schema
 
@@ -204,14 +204,15 @@ def expand_runs_matrix(runs_mat: jnp.ndarray, packed: jnp.ndarray,
                      unpacked)
 
 
-@partial(jax.jit, static_argnames=("cap",))
+@partial(jit_named, family="decode_runs", static_argnames=("cap",))
 def _expand_runs_packed(runs_mat: jnp.ndarray, packed: jnp.ndarray,
                         cap: int) -> jnp.ndarray:
     """Jitted wrapper over expand_runs_matrix (one upload per stream)."""
     return expand_runs_matrix(runs_mat, packed, cap)
 
 
-@partial(jax.jit, static_argnames=("cap",))
+@partial(jit_named, family="decode_def_expand",
+         static_argnames=("cap",))
 def _def_expand(levels: jnp.ndarray, values: jnp.ndarray, n_rows,
                 cap: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """validity + per-row values from def levels and non-null-compacted
@@ -228,7 +229,8 @@ def _def_expand(levels: jnp.ndarray, values: jnp.ndarray, n_rows,
     return out, valid
 
 
-@partial(jax.jit, static_argnames=("cap",))
+@partial(jit_named, family="decode_dict_gather",
+         static_argnames=("cap",))
 def _dict_gather(indices: jnp.ndarray, dictionary: jnp.ndarray,
                  valid: jnp.ndarray, cap: int
                  ) -> jnp.ndarray:
@@ -573,7 +575,7 @@ def _to_cap(col: DeviceColumn, cap: int) -> DeviceColumn:
     return _to_cap_jit(col, cap=cap)
 
 
-@partial(jax.jit, static_argnames=("cap",))
+@partial(jit_named, family="decode_to_cap", static_argnames=("cap",))
 def _to_cap_jit(col: DeviceColumn, cap: int) -> DeviceColumn:
     idx = jnp.arange(cap)
     valid_src = idx < col.capacity

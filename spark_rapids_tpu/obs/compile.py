@@ -14,8 +14,12 @@ Every first call of a (kernel-cache key, arg-shape) program through
 
   * kernel family + cache-key repr + canonical shape/dtype signature
   * backend the executable was built under (``pallas``/``xla``)
-  * compile wall (trace + XLA compile + one dispatch; the dispatch
-    share is not measured on the attached chip)
+  * first-call wall, ``kernel.compile.wallNs``: everything the first
+    call took, which is NOT compile time (tracing, lowering, the
+    backend compile or the read-back of a cached executable, and the
+    dispatch — an async one returns at once, one the device has to
+    queue waits).  The parts jax itself reports are split out as
+    ``build`` on the event and in the ``kernel.build.*`` counters
   * cache tier — ``fresh`` (a real XLA compile) vs ``persistent`` (the
     executable reloaded from the persistent XLA compilation cache),
     classified from jax's own ``/jax/compilation_cache/*`` monitoring
@@ -36,6 +40,13 @@ bounded per-query attribution table.  Surfaces:
   * ``kernel.compile.*`` counters + the ``kernel.compile.wallMs``
     histogram on ``/metrics``, and the cache-tier split
     ``kernel.cache.memHits`` / ``.persistentHits`` / ``.compiles``
+  * the build split, four process counters fed by jax's own duration
+    events for EVERY program jax builds in the process (the engine's
+    kernels, and the eager ``jnp`` operations round them):
+    ``kernel.build.traceNs`` (function to jaxpr), ``.lowerNs`` (jaxpr
+    to MLIR module), ``.compileNs`` (backend compile of a program the
+    persistent cache did not hold) and ``.loadNs`` (a program read
+    back from the persistent cache)
   * the ``/compiles`` endpoint route (obs/server.py): live ledger
     table + churn report + per-query attribution
   * a "compile" QueryProfile section and ``compile_s`` in
@@ -126,6 +137,10 @@ def configure(enabled: bool,
         _storm_threshold = max(1, int(storm_threshold))
         _corpus_path = str(corpus_path or "")
         _corpus_replay = bool(corpus_replay)
+    if _enabled:
+        # from session start, so the build split sees what set-up
+        # builds before the first kernel goes through get_kernel
+        _ensure_listener()
 
 
 def corpus_replay_enabled() -> bool:
@@ -164,12 +179,57 @@ def reset() -> None:
 _tls = threading.local()
 _listener_installed = False
 
+# -- the build split ---------------------------------------------------------
+# jax reports how long each step of building a program took, on the
+# building thread, as the step ends (jax 0.9.0: _src/dispatch.py,
+# _src/compiler.py).  A jit traced inside another's trace reports its
+# own trace duration first, and the outer one's holds it, so only the
+# outermost counts: the scalar listener sees each step START (jax
+# records the start time under the same name).  The backend-compile
+# bracket spans the persistent-cache lookup too; where the lookup gave
+# an executable (its retrieval event comes first), the whole bracket is
+# load, else compile: one first call adds to one of the two.
+BUILD_PARTS = ("trace", "lower", "compile", "load")
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
 
 def _jax_cache_listener(event: str, **kwargs) -> None:
     if event == "/jax/compilation_cache/cache_hits":
         _tls.pc_hits = getattr(_tls, "pc_hits", 0) + 1
     elif event == "/jax/compilation_cache/cache_misses":
         _tls.pc_misses = getattr(_tls, "pc_misses", 0) + 1
+
+
+def _jax_start_listener(event: str, value, **kwargs) -> None:
+    if event == _TRACE_EVENT:
+        _tls.trace_depth = getattr(_tls, "trace_depth", 0) + 1
+
+
+def _add_build(part: str, secs: float) -> None:
+    ns = int(secs * 1e9)
+    built = getattr(_tls, "built", None)
+    if built is None:
+        built = _tls.built = dict.fromkeys(BUILD_PARTS, 0)
+    built[part] += ns
+    obsreg.get_registry().inc(f"kernel.build.{part}Ns", ns)
+
+
+def _jax_duration_listener(event: str, duration: float, **kwargs) -> None:
+    if event == _TRACE_EVENT:
+        depth = getattr(_tls, "trace_depth", 1) - 1
+        _tls.trace_depth = max(depth, 0)
+        if depth <= 0:
+            _add_build("trace", duration)
+    elif event == _LOWER_EVENT:
+        _add_build("lower", duration)
+    elif event == _RETRIEVAL_EVENT:
+        _tls.retrieved = True
+    elif event == _BACKEND_EVENT:
+        retrieved, _tls.retrieved = getattr(_tls, "retrieved", False), False
+        _add_build("load" if retrieved else "compile", duration)
 
 
 def _ensure_listener() -> None:
@@ -182,25 +242,39 @@ def _ensure_listener() -> None:
         try:
             from jax._src import monitoring
             monitoring.register_event_listener(_jax_cache_listener)
+            monitoring.register_scalar_listener(_jax_start_listener)
+            monitoring.register_event_duration_secs_listener(
+                _jax_duration_listener)
         except Exception:
             pass                      # tier degrades to 'fresh' for all
         _listener_installed = True
 
 
-def probe_begin() -> Tuple[int, int]:
-    """Snapshot this thread's persistent-cache event counters before a
-    potential compile; pass the result to :func:`classify_tier`."""
+def probe_begin() -> Tuple[int, int, Dict[str, int]]:
+    """Snapshot this thread's persistent-cache event counters and what
+    it has spent building programs, before a potential compile; pass
+    the result to :func:`classify_tier` and :func:`build_split`."""
     _ensure_listener()
-    return (getattr(_tls, "pc_hits", 0), getattr(_tls, "pc_misses", 0))
+    return (getattr(_tls, "pc_hits", 0), getattr(_tls, "pc_misses", 0),
+            dict(getattr(_tls, "built", None) or ()))
 
 
-def classify_tier(probe: Tuple[int, int]) -> str:
+def build_split(probe) -> Dict[str, int]:
+    """``{"traceNs", "lowerNs", "compileNs", "loadNs"}`` this thread
+    spent since ``probe``: the parts of a first call that jax itself
+    timed.  What is left of the call's wall is dispatch and waiting."""
+    built = getattr(_tls, "built", None) or {}
+    return {f"{part}Ns": built.get(part, 0) - probe[2].get(part, 0)
+            for part in BUILD_PARTS}
+
+
+def classify_tier(probe) -> str:
     """``fresh`` when any real XLA compile happened in the window,
     ``persistent`` when the window saw only persistent-cache reloads.
     A window with neither event (persistent cache not configured, or a
     program jax already held in memory) reports ``fresh`` — the
     conservative reading for a compile-bill instrument."""
-    h0, m0 = probe
+    h0, m0 = probe[:2]
     if getattr(_tls, "pc_misses", 0) - m0 > 0:
         return TIER_FRESH
     if getattr(_tls, "pc_hits", 0) - h0 > 0:
@@ -395,9 +469,12 @@ def _bucket_key(key: Any) -> Any:
 
 def record_compile(key: Any, family: str, backend: str,
                    leaves: Sequence[Any], t0_ns: int, dur_ns: int,
-                   tier: str, replay: Optional[str] = None) -> None:
+                   tier: str, replay: Optional[str] = None,
+                   build: Optional[Dict[str, int]] = None) -> None:
     """Record one CompileEvent (called by the kernel-cache observe
     wrapper on the first call of each (key, shape) program).
+    ``dur_ns`` is the first call's wall, whatever happened in it;
+    ``build`` (:func:`build_split`) the parts of it jax timed.
     ``replay`` is the optional AOT replay payload (base64, built by
     kernel_cache._replay_payload) that rides the program's corpus
     record only — never the ring or the /compiles events (payloads are
@@ -423,6 +500,8 @@ def record_compile(key: Any, family: str, backend: str,
                "signature": sig, "backend": backend, "tier": tier,
                "wall_ms": round(dur_ns / 1e6, 3),
                "query_id": qid, "plan_digest": digest}
+        if build:
+            evt["build"] = build
         _ring.append(evt)
         fam = _families.get(family)
         if fam is None:
@@ -484,7 +563,7 @@ def record_compile(key: Any, family: str, backend: str,
     obstrace.record("kernel.compile", t0_ns, dur_ns, cat="kernel",
                     args={"family": family, "tier": tier,
                           "backend": backend, "query": qid,
-                          "signature": sig})
+                          "signature": sig, **(build or {})})
     if storm_fired is not None:
         obsreg.get_registry().inc("kernel.compile.storms")
         obsrec.record_event("compile.storm", query=qid,

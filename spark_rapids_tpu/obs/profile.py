@@ -9,8 +9,12 @@ spill and arena high-water marks, the plan-time ``explain`` fallback
 report, and the query's span window (exportable as a Chrome trace).
 
 Assembly: :class:`QueryRun` is opened by ``TpuSparkSession._execute``
-before planning; ``finish()`` carves the registry delta and span window
-and walks the executed plan.  Surfaces:
+before planning; ``finish()`` carves the registry delta and walks the
+executed plan.  The profile's ``spans`` are the tracer's spans that
+carry this query's id (obs/trace.py), resolved when read: what the
+serve layer records after ``finish()`` (``serve.stream``, the
+``serve.request`` root) is in the profile a caller reads after the
+last chunk.  Surfaces:
 ``session.last_query_profile()``, ``DataFrame.explain("profile")``,
 ``profile.to_json()`` and ``profile.dump_chrome_trace(path)``.
 """
@@ -56,7 +60,8 @@ _COMPILE_SECTION = ("kernel.cache.compiles", "kernel.cache.memHits",
 
 
 def _section_of(name: str) -> str:
-    if name.startswith("kernel.compile.") or name in _COMPILE_SECTION:
+    if name.startswith(("kernel.compile.", "kernel.build.")) \
+            or name in _COMPILE_SECTION:
         return "compile"
     if name.startswith(_SHARING_PREFIXES):
         return "sharing"
@@ -145,12 +150,26 @@ class QueryProfile:
     metrics: Dict[str, Dict[str, Any]]   # section -> flat metric dict
     wall_breakdown: Dict[str, float]     # phase -> seconds
     explain_lines: List[str]
+    # the query's span tree as dicts; read through the ``spans``
+    # property installed below the class (this is the constructor's
+    # argument: what a stub profile passes, and the fallback once the
+    # ring has dropped the query's spans)
     spans: List[Dict[str, Any]]
     # canonical logical-plan digest (plan/digest.py): alias-insensitive
     # identity shared with the kernel-cache keys and the serving tier's
     # result-set cache; also a /queries column
     plan_digest: Optional[str] = None
     _raw_spans: List[Any] = field(default_factory=list, repr=False)
+
+    def raw_spans(self) -> List[Any]:
+        """The ring's spans with this query's id, while the ring holds
+        at least as many as ``finish()`` saw (it drops the oldest
+        first); after that, what ``finish()`` saw."""
+        live = obstrace.query_spans(self.query_id) \
+            if obstrace.is_enabled() else []
+        if live and len(live) >= len(self._raw_spans):
+            self._raw_spans = live
+        return self._raw_spans
 
     # -- rendering ---------------------------------------------------------
     def to_dict(self) -> Dict[str, Any]:
@@ -185,7 +204,21 @@ class QueryProfile:
 
     def dump_chrome_trace(self, path: str) -> str:
         """Write this query's span window as Chrome trace-event JSON."""
-        return obstrace.dump_chrome_trace(path, self._raw_spans)
+        return obstrace.dump_chrome_trace(path, self.raw_spans())
+
+
+def _spans_get(self) -> List[Dict[str, Any]]:
+    raw = self.raw_spans()
+    return obstrace.span_dicts(raw) if raw else self._given_spans
+
+
+def _spans_set(self, given: List[Dict[str, Any]]) -> None:
+    self._given_spans = given
+
+
+# after the dataclass is built, so that ``spans`` stays a required
+# constructor argument and is not taken for a field default
+QueryProfile.spans = property(_spans_get, _spans_set)
 
 
 def _sectioned(delta: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
@@ -283,13 +316,15 @@ def _breakdown(plan: Optional[ExecNodeProfile],
 
 
 class _Phase:
-    __slots__ = ("run", "name", "t0")
+    __slots__ = ("run", "name", "t0", "sid")
 
     def __init__(self, run: "QueryRun", name: str):
         self.run = run
         self.name = name
 
     def __enter__(self):
+        # open on the thread, so the operators' spans hang under it
+        self.sid = obstrace.open_span() if obstrace.is_enabled() else 0
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -297,7 +332,9 @@ class _Phase:
         dur = time.perf_counter_ns() - self.t0
         self.run.phases[self.name] = \
             self.run.phases.get(self.name, 0) + dur
-        obstrace.record(f"query.{self.name}", self.t0, dur, cat="query")
+        if self.sid:
+            obstrace.close_span(self.sid, f"query.{self.name}", self.t0,
+                                dur, cat="query")
 
 
 class QueryRun:
@@ -321,13 +358,13 @@ class QueryRun:
         self._view = obsreg.get_registry().view()
         self._span_mark = obstrace.mark()
         self._t0 = time.perf_counter_ns()
-        wait = self.sched_extra.get("sched.queueWaitNs", 0)
-        if wait:
-            # re-record the pre-execution queue wait inside this
-            # query's span window, so its trace shows the wait
-            obstrace.record("sched.queueWait", self._t0 - int(wait),
-                            int(wait), cat="sched",
-                            args={"query": query_id})
+        # who records the root of this query's span tree: the serve
+        # layer for a served query (``serve.request``, request receipt
+        # to END frame), this run otherwise (``query``, over the queue
+        # wait and the run).  A nested collect runs under the outer
+        # query's token and belongs to that query's tree.
+        self._owns_root = "sched.sessionId" not in self.sched_extra \
+            and not self.sched_extra.get("sched.nested")
 
     def phase(self, name: str) -> _Phase:
         return _Phase(self, name)
@@ -363,7 +400,17 @@ class QueryRun:
                 sections["spill"]["spill.hostBytesNow"] = cat.host_bytes
                 sections["spill"]["spill.arenaPeakBytes"] = \
                     cat.host_arena.peak()
-        raw_spans = obstrace.spans_since(self._span_mark)
+        if self._owns_root:
+            wait = int(self.sched_extra.get("sched.queueWaitNs", 0))
+            obstrace.record_root("query", self._t0 - wait,
+                                 wall_ns + wait, self.query_id)
+        # the spans with this query's id so far (the profile resolves
+        # them anew when read); a nested collect's spans carry the
+        # outer query's id, so it keeps the window it ran in
+        raw_spans = obstrace.query_spans(self.query_id) \
+            if obstrace.is_enabled() else []
+        if not raw_spans:
+            raw_spans = obstrace.spans_since(self._span_mark)
         prof = QueryProfile(
             query_id=self.query_id,
             plan_digest=self.plan_digest,

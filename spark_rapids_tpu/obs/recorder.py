@@ -186,8 +186,9 @@ class FlightRecorder:
 
         dump("profile.json",
              profile.to_dict() if profile is not None else None)
+        raw_spans = getattr(profile, "raw_spans", None)
         dump("trace.json", obstrace.chrome_trace(
-            getattr(profile, "_raw_spans", []) or []))
+            raw_spans() if raw_spans is not None else []))
         with open(os.path.join(bundle, "events.jsonl"), "w") as f:
             for evt in self.events():
                 f.write(json.dumps(evt, default=str) + "\n")

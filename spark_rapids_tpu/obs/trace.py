@@ -17,6 +17,19 @@ order is children-before-parents); the Chrome exporter re-derives the
 nesting per thread from the intervals and emits matched ``B``/``E``
 event pairs a Perfetto / chrome://tracing load renders as a flame
 graph.
+
+**One tree a query.**  Every span carries an id minted at *entry*, the
+id of its ``parent`` and the ``query`` it works for.  The parent is the
+innermost span open on the thread at entry (a thread-local stack that
+``span()`` and ``exec/base``'s ``timed``/``timed_extra`` push and pop);
+the query comes from the thread's ``CancelToken``
+(``sched/cancel.current()``), so prefetch, task-pool and streamer
+threads label their spans with the query they work for.  A span with a
+query and no open parent hangs under that query's root
+(:func:`root_id`): ``serve.request`` for a served query, ``query``
+otherwise.  :func:`query_spans` is a query's tree; ``mark()`` /
+``spans_since()`` stay for callers that want a window of the ring
+whatever the query (the executor's reply).
 """
 
 from __future__ import annotations
@@ -25,20 +38,31 @@ import itertools
 import json
 import threading
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 DEFAULT_BUFFER_SPANS = 65536
 
-# one span record:
-#   (seq, tid, name, cat, t0_ns, dur_ns, depth, args)
-Span = Tuple[int, int, str, str, int, int, int, Optional[Dict[str, Any]]]
+# one span record (indices 0-7 are the original eight; 8-10 appended):
+#   (seq, tid, name, cat, t0_ns, dur_ns, depth, args,
+#    span id, parent span id (0: none), query id (None: no query))
+Span = Tuple[int, int, str, str, int, int, int, Optional[Dict[str, Any]],
+             int, int, Optional[int]]
+SID, PARENT, QUERY = 8, 9, 10
 
 _enabled = False
 _ring: deque = deque(maxlen=DEFAULT_BUFFER_SPANS)
-_seq = itertools.count()
+_seq = itertools.count()          # record order: the carve marks
+_ids = itertools.count(1)         # span ids, minted at entry
 _lock = threading.Lock()
-_tls = threading.local()
+_tls = threading.local()          # .stack: ids of the spans open here
+
+# query id -> [id of the query's root span, whether it is recorded].
+# Minted by whoever asks first: the children that hang under it usually
+# end (and are recorded) before the root itself.  Bounded; a query that
+# old has left the ring.
+_MAX_ROOTS = 4096
+_roots: "OrderedDict[int, list]" = OrderedDict()
 
 # cross-process stitching state: synthetic lane ids for spans merged
 # from other processes (executor map stages), plus human labels the
@@ -75,6 +99,7 @@ def is_enabled() -> bool:
 def clear() -> None:
     with _lock:
         _ring.clear()
+        _roots.clear()
 
 
 def mark() -> int:
@@ -83,16 +108,115 @@ def mark() -> int:
     return next(_seq)
 
 
-def record(name: str, t0_ns: int, dur_ns: int, cat: str = "exec",
-           args: Optional[Dict[str, Any]] = None,
-           depth: Optional[int] = None) -> None:
-    """Record one completed span. No-op (one bool check) when disabled."""
+_current_token = None
+
+
+def current_query() -> Optional[int]:
+    """The query this thread works for: the id on its installed
+    ``CancelToken`` (None outside any query).  ``sched.cancel`` imports
+    nothing of ``obs``; the package ``sched`` does, hence the late
+    import."""
+    global _current_token
+    if _current_token is None:
+        from spark_rapids_tpu.sched import cancel
+        _current_token = cancel.current
+    tok = _current_token()
+    return tok.query_id if tok is not None else None
+
+
+def _root_locked(query: int) -> list:
+    root = _roots.get(query)
+    if root is None:
+        root = _roots[query] = [next(_ids), False]
+        while len(_roots) > _MAX_ROOTS:
+            _roots.popitem(last=False)
+    return root
+
+
+def root_id(query: int) -> int:
+    """The id of ``query``'s root span, minted on the first ask."""
+    with _lock:
+        return _root_locked(query)[0]
+
+
+def record_root(name: str, t0_ns: int, dur_ns: int, query: int,
+                cat: str = "query",
+                args: Optional[Dict[str, Any]] = None) -> None:
+    """Record ``query``'s root span: the one every span of the query
+    with no open parent hangs under.  A second root of one query (a
+    coalesced batch answers several requests from one execution) hangs
+    under the first."""
     if not _enabled:
         return
+    with _lock:
+        root = _root_locked(query)
+        first, root[1] = not root[1], True
+    if first:
+        record(name, t0_ns, dur_ns, cat, args, depth=0, parent=0,
+               query=query, sid=root[0])
+    else:
+        record(name, t0_ns, dur_ns, cat, args, depth=1, parent=root[0],
+               query=query)
+
+
+def _parent_here(stack, query: Optional[int]) -> int:
+    """Where a span with no parent of its own hangs: under the
+    innermost span open on this thread, else under its query's root."""
+    if stack:
+        return stack[-1]
+    return root_id(query) if query is not None else 0
+
+
+def open_span() -> int:
+    """Entry half of a span for callers that keep their own timing
+    (``exec/base``): mints the id and pushes it on this thread's stack
+    of open spans.  Call only when enabled, and hand the id to
+    :func:`close_span`."""
+    sid = next(_ids)
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    stack.append(sid)
+    return sid
+
+
+def close_span(sid: int, name: str, t0_ns: int, dur_ns: int,
+               cat: str = "exec",
+               args: Optional[Dict[str, Any]] = None) -> None:
+    """Exit half: pops ``sid`` (and anything left open above it) and
+    records the span under what is then innermost."""
+    stack = getattr(_tls, "stack", None)
+    if stack and sid in stack:
+        del stack[stack.index(sid):]
+    record(name, t0_ns, dur_ns, cat, args,
+           depth=len(stack) + 1 if stack else 1, sid=sid)
+
+
+def record(name: str, t0_ns: int, dur_ns: int, cat: str = "exec",
+           args: Optional[Dict[str, Any]] = None,
+           depth: Optional[int] = None, parent: Optional[int] = None,
+           query: Optional[int] = None, sid: Optional[int] = None
+           ) -> None:
+    """Record one completed span. No-op (one bool check) when disabled.
+
+    ``parent`` defaults to the innermost span open on this thread, else
+    to the query's root; ``query`` to the thread's (see the module
+    docstring).  ``sid`` is given by who minted the id at entry
+    (:func:`open_span`, :func:`record_root`)."""
+    if not _enabled:
+        return
+    stack = getattr(_tls, "stack", None)
     if depth is None:
-        depth = getattr(_tls, "depth", 0)
+        depth = len(stack) if stack else 0
+    if query is None:
+        query = current_query()
+    if sid is None:
+        sid = next(_ids)
+    if parent is None:
+        parent = _parent_here(stack, query)
     _ring.append((next(_seq), threading.get_ident(), name, cat,
-                  int(t0_ns), int(dur_ns), depth, args))
+                  int(t0_ns), int(dur_ns), depth, args, sid, parent,
+                  query))
 
 
 class _NoopSpan:
@@ -111,7 +235,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "t0", "_depth")
+    __slots__ = ("name", "cat", "args", "t0", "sid")
 
     def __init__(self, name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -120,17 +244,14 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        d = getattr(_tls, "depth", 0)
-        self._depth = d + 1
-        _tls.depth = self._depth
+        self.sid = open_span()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *a):
         dur = time.perf_counter_ns() - self.t0
-        _tls.depth = self._depth - 1
-        record(self.name, self.t0, dur, self.cat, self.args,
-               depth=self._depth)
+        close_span(self.sid, self.name, self.t0, dur, self.cat,
+                   self.args)
         return False
 
 
@@ -153,14 +274,24 @@ def record_foreign(spans: Sequence[Span], offset_ns: int,
     ``label`` (``label/t0``, ``label/t1``, ... when the foreign process
     used several threads) that the Chrome exporter names via
     thread_name metadata — executor map stages render as their own
-    lanes in Perfetto.  Returns the number of spans merged.  No-op when
-    tracing is disabled."""
+    lanes in Perfetto.  The spans join the tree of the query the
+    calling thread works for: ids are minted anew, links among the
+    foreign spans are kept, and those whose parent did not come along
+    hang under the caller's open span (else the query's root).  Returns
+    the number of spans merged.  No-op when tracing is disabled."""
     if not _enabled or not spans:
         return 0
+    query = current_query()
+    above = _parent_here(getattr(_tls, "stack", None), query)
+    # children were recorded before their parents: mint every id first
+    ids = {s[SID]: next(_ids) for s in spans if len(s) > SID}
     n = 0
     with _lock:
         for s in spans:
-            seq_, ftid, name, cat, t0, dur, depth, args = s
+            seq_, ftid, name, cat, t0, dur, depth, args = s[:8]
+            sid = ids[s[SID]] if len(s) > SID else next(_ids)
+            parent = ids.get(s[PARENT], above) if len(s) > PARENT \
+                else above
             key = (label, ftid)
             lane = _lane_map.get(key)
             if lane is None:
@@ -184,7 +315,7 @@ def record_foreign(spans: Sequence[Span], offset_ns: int,
             a.setdefault("lane", _tid_labels[lane])
             _ring.append((next(_seq), lane, name, cat,
                           int(t0) + int(offset_ns), int(dur),
-                          int(depth), a))
+                          int(depth), a, sid, parent, query))
             n += 1
     return n
 
@@ -204,12 +335,21 @@ def spans_since(seq_mark: int) -> List[Span]:
     return [s for s in snapshot() if s[0] >= seq_mark]
 
 
+def query_spans(query: int) -> List[Span]:
+    """Every span in the ring that worked for ``query``, whichever
+    thread recorded it and whenever: the query's tree, as far as the
+    ring still holds it."""
+    return [s for s in snapshot() if s[QUERY] == query]
+
+
 def span_dicts(spans: Sequence[Span]) -> List[Dict[str, Any]]:
     """JSON-friendly rendering (the QueryProfile ``spans`` section)."""
     out = []
-    for seq, tid, name, cat, t0, dur, depth, args in spans:
+    for seq, tid, name, cat, t0, dur, depth, args, sid, parent, query \
+            in spans:
         d = {"name": name, "cat": cat, "tid": tid, "ts_ns": t0,
-             "dur_ns": dur, "depth": depth}
+             "dur_ns": dur, "depth": depth, "id": sid,
+             "parent": parent, "query": query}
         if args:
             d["args"] = args
         out.append(d)

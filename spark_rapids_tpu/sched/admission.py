@@ -325,10 +325,12 @@ class AdmissionController:
         if req.queue_wait_ns:
             reg.inc("sched.queueWaitNs", req.queue_wait_ns)
             reg.observe("sched.queueWait", req.queue_wait_ns)
+            # the worker has no token installed yet: name the query
             obstrace.record("sched.queueWait", req.enqueue_ns,
                             req.queue_wait_ns, cat="sched",
                             args={"query": req.query_id,
-                                  "priority": req.priority})
+                                  "priority": req.priority},
+                            query=req.query_id)
         self._maybe_pressure(req.estimate)
         return AdmissionSlot(self, req)
 

@@ -109,8 +109,6 @@ class PrecompileService:
         program (deduplicated on (key, signature) across records).
         Returns the stats dict; never raises on per-program failures
         (counted as ``failed``)."""
-        import jax
-
         from spark_rapids_tpu.exec import kernel_cache as kc
         t0 = time.perf_counter()
         reg = obsreg.get_registry()
@@ -175,7 +173,7 @@ class PrecompileService:
                 self._yield_to_serving()
                 try:
                     spec = kc.load_replay_payload(payload)
-                    jitted = jax.jit(spec["fn"], **(spec["jit"] or {}))
+                    jitted = kc.jit_replayed(spec)
                     jitted.lower(*spec["args"],
                                  **(spec["kwargs"] or {})).compile()
                     with self._lock:

@@ -63,6 +63,7 @@ from typing import Any, Dict, Optional, Tuple
 
 from spark_rapids_tpu.obs import recorder as obsrec
 from spark_rapids_tpu.obs import registry as obsreg
+from spark_rapids_tpu.obs import trace as obstrace
 from spark_rapids_tpu.serve import faults as serve_faults
 from spark_rapids_tpu.serve import result_cache, wire
 from spark_rapids_tpu.serve.faults import ServeFaultAction
@@ -295,6 +296,14 @@ class ServeSession:
                 "client_addr": self.client_addr}
 
 
+# receipt time of the request the connection's reader thread is
+# handling (``perf_counter_ns``, the tracer's clock): every path from
+# ``_handle_request`` to an ``_Inflight`` (sql, execute, the batcher's
+# offer, resume) runs on that thread, so the ``serve.request`` span
+# starts at receipt whichever path built the ``_Inflight``
+_RECEIPT = threading.local()
+
+
 class _Inflight:
     """One query being answered on one connection: its future (None for
     a result-cache hit or a resumed stream) and the client-credit
@@ -313,6 +322,11 @@ class _Inflight:
         # latency observe against these at stream time
         self.t0_ns = time.monotonic_ns()
         self.template = template
+        # the same instant for the span tree (``serve.request``), and
+        # whether that span is recorded
+        self.req_ns = getattr(_RECEIPT, "ns", None) \
+            or time.perf_counter_ns()
+        self.traced = False
 
     def add_credit(self, n: int) -> None:
         with self._cv:
@@ -975,6 +989,7 @@ class ServeServer:
                         msg: Dict[str, Any]) -> bool:
         """Dispatch one REQ; returns False when the connection should
         close (the ``close`` op)."""
+        _RECEIPT.ns = time.perf_counter_ns()
         op = str(msg.get("op", ""))
         reg = obsreg.get_registry()
         reg.inc("serve.requests")
@@ -1408,10 +1423,12 @@ class ServeServer:
         try:
             if feed is not None:
                 reg.inc("serve.dedup.chunkFeedStreams")
+                t_feed = time.perf_counter_ns()
                 status, fed = self._stream_from_feed(conn, infl, feed,
                                                      fut.query_id,
                                                      release)
                 if status in ("done", "dead"):
+                    self._trace_request(infl, fut.query_id, t_feed)
                     return
                 # leader stream died or stalled before finishing: fall
                 # back to whole-result streaming off this follower's own
@@ -1476,6 +1493,35 @@ class ServeServer:
                 # error-path net: no-op when the stream finished cleanly
                 fl.chunk_feed.abort()
             release()
+            # a query that failed streamed nothing: its tree still
+            # gets its root (no-op after ``_stream_table``)
+            self._trace_request(infl, fut.query_id)
+
+    @staticmethod
+    def _trace_request(infl: _Inflight, query_id,
+                       t_stream: Optional[int] = None) -> None:
+        """The serve layer's two spans of one request, once: the
+        ``serve.request`` root of the query's span tree (request
+        receipt to the END frame) and, where chunks went out,
+        ``serve.stream`` (chunk encode and send, ``t_stream`` to now).
+        A request that ran no query (result-cache hit, resumed stream)
+        has both with no query id."""
+        if infl.traced or not obstrace.is_enabled():
+            return
+        infl.traced = True
+        now = time.perf_counter_ns()
+        # no span is open and no token installed on a streamer thread:
+        # ``serve.stream`` hangs under the query's root, or under none
+        if t_stream is not None:
+            obstrace.record("serve.stream", t_stream, now - t_stream,
+                            cat="serve", query=query_id)
+        if query_id is None:
+            obstrace.record("serve.request", infl.req_ns,
+                            now - infl.req_ns, cat="serve")
+        else:
+            obstrace.record_root("serve.request", infl.req_ns,
+                                 now - infl.req_ns, query_id,
+                                 cat="serve")
 
     def _stream_from_feed(self, conn: _Conn, infl: _Inflight,
                           feed: _ChunkFeed, query_id, release
@@ -1556,6 +1602,7 @@ class ServeServer:
                       after_seq: int = 0, observe_first: bool = True,
                       feed: Optional[_ChunkFeed] = None) -> None:
         reg = obsreg.get_registry()
+        t_stream = time.perf_counter_ns()
         chunks = wire.table_chunks(table, self._chunk_rows)
         total = max(1, math.ceil(max(1, table.num_rows)
                                  / self._chunk_rows))
@@ -1650,3 +1697,5 @@ class ServeServer:
                 pass
         except wire.WireError:
             infl.abort()
+        finally:
+            self._trace_request(infl, query_id, t_stream)

@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+from spark_rapids_tpu.exec.kernel_cache import jit_named
 from spark_rapids_tpu.exec.tpu_aggregate import (finalize_aggregate,
                                                  make_spec, merge_aggregate,
                                                  update_aggregate)
@@ -101,10 +102,13 @@ def exchange(stacked_cols: List[DeviceColumn], counts: jnp.ndarray,
              axis: str) -> Tuple[List[DeviceColumn], jnp.ndarray]:
     """One tiled all_to_all per buffer: bucket d of device s lands on
     device d as block s.  (The whole UCX client/server/bounce-buffer
-    machinery of the reference collapses into this collective.)"""
+    machinery of the reference collapses into this collective.)  The
+    collectives sit in the named scope ``ici.exchange``, which their
+    HLO metadata carries into a device trace."""
     def a2a(x):
-        return lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
-                              tiled=True)
+        with jax.named_scope("ici.exchange"):
+            return lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
+                                  tiled=True)
     out_cols = []
     for c in stacked_cols:
         out_cols.append(DeviceColumn(
@@ -198,7 +202,7 @@ def make_distributed_agg_step(mesh: Mesh, axis: str,
 
     step = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    return jax.jit(step), out_dtypes
+    return jit_named(step, "ici_agg"), out_dtypes
 
 
 def _probe_out_dtypes(schema, groupings, aggregates, out_names):
@@ -332,9 +336,9 @@ def make_exchange_step(mesh: Mesh, axis: str, names, dtypes, aux_key):
         return _cols_to_leaves(received.columns), jnp.reshape(
             jnp.asarray(received.num_rows, dtype=jnp.int32), (1,))
 
-    step = jax.jit(jax.shard_map(
+    step = jit_named(jax.shard_map(
         local_step, mesh=mesh, in_specs=(P(axis), P(axis)),
-        out_specs=(P(axis), P(axis)), check_vma=False))
+        out_specs=(P(axis), P(axis)), check_vma=False), "ici_exchange")
     _STEP_CACHE[key] = step
     return step
 
@@ -451,9 +455,9 @@ def ring_broadcast_batch(batch: DeviceBatch) -> dict:
         return _cols_to_leaves(out.columns), jnp.reshape(
             jnp.asarray(out.num_rows, jnp.int32), (1,))
 
-    step = jax.jit(jax.shard_map(
+    step = jit_named(jax.shard_map(
         local_step, mesh=mesh, in_specs=(P("shuffle"), P("shuffle")),
-        out_specs=(P(), P()), check_vma=False))
+        out_specs=(P(), P()), check_vma=False), "ici_join")
     out_leaves, out_rows = step(leaves, counts)
     n_out = int(np.asarray(out_rows)[0])
 

@@ -143,7 +143,7 @@ def for_chip(one_chip, smoke_programs):
 _XLA_CASES = {
     "decode": ("pq_fused6",),
     "agg_update": ("agg_update",),
-    "agg_merge": ("agg_merge", "concat_nosync"),
+    "agg_merge": ("agg_merge", "concat"),
     "agg_finalize": ("agg_final",),
     "sort_keys": ("sort_keys", "shared_digit_sort", "sort_apply"),
     "join_probe": ("probe_count", "probe_emit_u"),

@@ -128,6 +128,81 @@ def test_observed_compile_via_get_kernel():
                for e in evs)
 
 
+def _scaled(factor, x):
+    return x * factor
+
+
+@pytest.mark.parametrize("family,want", [
+    ("agg_update", "jit_agg_update"),
+    ("Probe-Emit.D", "jit_probe_emit_d"),        # cut to [a-z0-9_]
+])
+def test_programs_are_named_by_kernel_family(monkeypatch, family, want):
+    """The module a kernel lowers to is ``jit_<family>`` whatever the
+    traceable is (a partial and a lambda have no name of their own),
+    and a replay payload re-jitted as sched/precompile.py does gives
+    the same name: an AOT-warmed program under another module name
+    would never be hit."""
+    import functools
+    built = []
+    real = kc.jit_named
+    monkeypatch.setattr(
+        kc, "jit_named",
+        lambda inner, fam, **kw: built.append(real(inner, fam, **kw))
+        or built[-1])
+    x = jnp.arange(32)
+    inner = functools.partial(_scaled, 3)
+    fn = kc.get_kernel((family, "tnamed", want), lambda: inner)
+    assert list(fn(x)[:2]) == [0, 3]
+    assert kc.program_name(family) == want
+    (jitted,) = built
+    assert f"module @{want} " in jitted.lower(x).as_text()
+    spec = kc.load_replay_payload(
+        kc._replay_payload(inner, {}, (x,), {}, family=family))
+    replayed = kc.jit_replayed(spec).lower(*spec["args"])
+    assert f"module @{want} " in replayed.as_text()
+    # static arguments resolve against the traceable's own signature
+    sliced = real(lambda b, cap: b[:cap], "dl_compact",
+                  static_argnames=("cap",))
+    assert sliced(x, 4).shape == sliced(x, cap=4).shape == (4,)
+    assert "module @jit_dl_compact " in sliced.lower(x, cap=4).as_text()
+
+
+def test_build_split_counters_and_event_parts():
+    """A first call adds to ``kernel.build.traceNs`` and to exactly one
+    of ``.compileNs`` / ``.loadNs`` (a compile, or a read-back from the
+    persistent cache); a second call of the same shape adds to none.
+    The parts ride the CompileEvent."""
+    import time as _time
+    names = [f"kernel.build.{p}Ns" for p in obscompile.BUILD_PARTS]
+
+    def slow_to_trace(x):
+        _time.sleep(0.02)             # tracing is host time
+        return jnp.cumsum(x * 5 + 1)
+    fn = kc.get_kernel(("tbuild", _time.time_ns()), lambda: slow_to_trace)
+    x = jnp.arange(512)       # an eager program of its own: built here
+    view = obsreg.get_registry().view()
+    fn(x)
+    first = view.delta()["counters"]
+    assert first.get("kernel.build.traceNs", 0) >= 20e6
+    assert first.get("kernel.build.lowerNs", 0) > 0
+    assert (first.get("kernel.build.compileNs", 0) > 0) != \
+        (first.get("kernel.build.loadNs", 0) > 0), first
+    (evt,) = [e for e in obscompile.events() if e["family"] == "tbuild"]
+    assert set(evt["build"]) == {"traceNs", "lowerNs", "compileNs",
+                                 "loadNs"}
+    assert evt["build"]["traceNs"] >= 20e6
+    # the tier and the split agree on what the first call did
+    loaded = evt["build"]["loadNs"] > 0
+    assert evt["tier"] == (obscompile.TIER_PERSISTENT if loaded
+                           else obscompile.TIER_FRESH)
+    # the first call's wall holds the parts jax timed
+    assert evt["wall_ms"] * 1e6 >= 0.9 * sum(evt["build"].values())
+    view = obsreg.get_registry().view()
+    fn(x)
+    second = view.delta()["counters"]
+    assert not any(second.get(n) for n in names), second
+
+
 # ---------------------------------------------------------------------------
 # query attribution
 # ---------------------------------------------------------------------------
