@@ -563,6 +563,39 @@ def _run_compact(b: DeviceBatch, fn, t: int) -> DeviceBatch:
     return DeviceBatch(b.names, nb.columns, nb.num_rows)
 
 
+def read_row_counts(batches: Sequence[DeviceBatch],
+                    wait_span: Optional[str] = None) -> List[int]:
+    """Row counts of ``batches`` on the host.  Counts that are still
+    device scalars come back in ONE transfer (stacked, copied once),
+    however many batches there are; where every count is host-known
+    nothing is read.  The batches are left as they are.
+
+    The copy blocks until every program that feeds a count has run, so
+    it belongs where the host has nothing left to enqueue (the terminal
+    collect, a pipeline breaker's last input).  ``wait_span`` names that
+    wait in the trace."""
+    from spark_rapids_tpu.obs import trace as obstrace
+    counts = [b.num_rows for b in batches]
+    traced = [i for i, n in enumerate(counts)
+              if not isinstance(n, (int, np.integer))]
+    if traced:
+        # distributed (ICI) readers hand out batches committed to
+        # different mesh devices; colocate the count scalars before the
+        # fused stack+read
+        scalars = [jnp.asarray(counts[i], dtype=jnp.int32)
+                   for i in traced]
+        devs = {d for s in scalars for d in s.devices()}
+        if len(devs) > 1:
+            tgt = sorted(devs, key=lambda d: d.id)[0]
+            scalars = [jax.device_put(s, tgt) for s in scalars]
+        with obstrace.span(wait_span, cat="query") if wait_span \
+                else contextlib.nullcontext():
+            got = np.asarray(jnp.stack(scalars))
+        for i, n in zip(traced, got):
+            counts[i] = n
+    return [int(n) for n in counts]
+
+
 def _compact_for_download(batches: Sequence[DeviceBatch],
                           wait_span: Optional[str] = None):
     """Re-bucket batches whose capacity vastly exceeds their row count
@@ -580,9 +613,6 @@ def _compact_for_download(batches: Sequence[DeviceBatch],
     counts arrive.  ``wait_span`` names it in the trace (the terminal
     collect passes ``collect.deviceWait``); it is a name round a wait
     that is there anyway, never a sync of its own."""
-    from spark_rapids_tpu.obs import trace as obstrace
-    traced = [b for b in batches
-              if not isinstance(b.num_rows, (int, np.integer))]
     candidates = {}
     full_packed = []
     for b in batches:
@@ -600,24 +630,10 @@ def _compact_for_download(batches: Sequence[DeviceBatch],
                     _dispatch_pack(_run_compact(b, fn, t))
         # full-capacity pack, reused if this batch stays uncompacted
         full_packed.append(_dispatch_pack(b))
-    if traced:
-        # distributed (ICI) readers hand out batches committed to
-        # different mesh devices; colocate the count scalars before the
-        # fused stack+read
-        scalars = [jnp.asarray(b.num_rows, dtype=jnp.int32)
-                   for b in traced]
-        devs = {d for s in scalars for d in s.devices()}
-        if len(devs) > 1:
-            tgt = sorted(devs, key=lambda d: d.id)[0]
-            scalars = [jax.device_put(s, tgt) for s in scalars]
-        with obstrace.span(wait_span, cat="query") if wait_span \
-                else contextlib.nullcontext():
-            counts = np.asarray(jnp.stack(scalars))
-        for b, n in zip(traced, counts):
-            b.num_rows = int(n)
     out, out_packed = [], []
-    for b, fp in zip(batches, full_packed):
-        n = int(b.num_rows)
+    for b, fp, n in zip(batches, full_packed,
+                        read_row_counts(batches, wait_span)):
+        b.num_rows = n
         tier = _dl_tier(n, b.capacity)
         if tier is not None and id(b) in candidates and \
                 tier in candidates[id(b)]:

@@ -1021,6 +1021,9 @@ class TpuHashAggregateExec(TpuExec):
                             reg.inc("incremental.deltaBatches")
                             reg.inc("serve.incremental.deltaBatches")
                         partials.append(register_or_hold(partial))
+                        # the handle alone keeps the partial alive, so
+                        # the cut below can release it
+                        del partial
                 if not partials:
                     if self.groupings:
                         return  # grouped agg over empty input -> no rows
@@ -1033,6 +1036,7 @@ class TpuHashAggregateExec(TpuExec):
                         inc["sink"].update_batches = n_updates
                     yield self._final_kernel(empty)
                     return
+                _shrink_partials(partials, bool(self.groupings))
                 if len(partials) == 1:
                     merged = partials[0].get()
                 else:
@@ -1060,6 +1064,63 @@ class TpuHashAggregateExec(TpuExec):
         if self.per_partition:
             return [run([it]) for it in self.children[0].execute()]
         return [run(self.children[0].execute())]
+
+
+def _shrink_partials(partials: List, grouped: bool) -> None:
+    """Size the buffered partials by what they hold.  An update emits
+    its partial at the input batch's capacity with its group count on
+    the device, so four groups out of a 4M-row batch sit in a 4M-row
+    buffer, and the concatenation, the merge and everything downstream
+    of the aggregate would run at the sum of those capacities.
+
+    Called once, after the last input batch has been dispatched (the
+    aggregate is a pipeline breaker: the host has nothing left to
+    enqueue): ONE read-back of all the counts, then every partial whose
+    tier ``bucket_rows(n_groups)`` is below its capacity is cut down to
+    that tier, in place in ``partials``, the cut batch taking the full
+    one's spill handle so the large buffers are released before the
+    merge.  Rows are prefix-dense, so the cut is a head slice that keeps
+    the columns' hints.  Cutting to the tier and not to the count keeps
+    every capacity on the ABI ladder: a group count that wanders inside
+    a tier compiles nothing.  ``num_rows`` stays the device scalar, so
+    ``concat_batches`` keeps its no-sync path, keyed by capacities.
+    Where the groups fill their tier nothing is cut and the read is the
+    only cost.  A global aggregate's partial holds one row by
+    construction and needs no read."""
+    from spark_rapids_tpu.columnar.batch import read_row_counts
+    from spark_rapids_tpu.exec import kernel_abi, kernel_cache as kc
+    from spark_rapids_tpu.mem.spill import register_or_hold
+    from spark_rapids_tpu.obs import registry as obsreg, trace as obstrace
+    reg = obsreg.get_registry()
+    # the step's own span, round the wait and the cuts' dispatches: the
+    # device idles while the host does either, and a trace lays those
+    # gaps to the innermost span that covers them
+    with obstrace.span("agg.shrink"):
+        batches = [p.get() for p in partials]
+        if not grouped:
+            counts = [1] * len(batches)
+        else:
+            if any(not isinstance(b.num_rows, (int, np.integer))
+                   for b in batches):
+                reg.inc("agg.partials.read")
+            counts = read_row_counts(batches, wait_span="agg.countWait")
+        shrunk = rows_cut = 0
+        for i, (b, n) in enumerate(zip(batches, counts)):
+            tier = bucket_rows(n)
+            if tier >= b.capacity:
+                continue
+            fn = kc.get_kernel(
+                ("agg_shrink", kernel_abi.erased_key(b), tier),
+                lambda: _slice_batch, static_argnames=("n2",))
+            cut = fn(kernel_abi.erase(b, pad=False), n2=tier)
+            partials[i].close()
+            partials[i] = register_or_hold(
+                DeviceBatch(b.names, cut.columns, cut.num_rows))
+            shrunk += 1
+            rows_cut += b.capacity - tier
+    if shrunk:
+        reg.inc("agg.partials.shrunk", shrunk)
+        reg.inc("agg.partials.rowsCut", rows_cut)
 
 
 def _make_empty_buffer_batch(exec_: TpuHashAggregateExec) -> DeviceBatch:

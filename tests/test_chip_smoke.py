@@ -17,6 +17,11 @@ def test_smoke_phases_on_cpu(capsys):
     import chip_smoke
     device = chip_smoke.device_info()
     assert device["platform"] == "cpu"
+    # the script reports the process's counters, as its own process
+    # would have them: not what an earlier test file of this worker
+    # (one that runs kernel.backend=pallas) left in the registry
+    from spark_rapids_tpu.obs import registry as obsreg
+    obsreg.reset_registry()
     # parity with the plain reference and zero fallbacks are asserted
     # inside the phases; any failure raises out of run()
     chip_smoke.run(rows=6000, seed=5, device=device, ici=False)
