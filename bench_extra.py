@@ -137,8 +137,7 @@ def _build_join_pipeline(fact, items, warehouses):
     import jax.numpy as jnp
     from spark_rapids_tpu.columnar.batch import (bucket_rows, from_arrow,
                                                  DeviceBatch)
-    from spark_rapids_tpu.exec.tpu_join import (_PROBE_MAX_BITS,
-                                                _probe_code_bits,
+    from spark_rapids_tpu.exec.tpu_join import (_KeyRange,
                                                 _probe_count_kernel,
                                                 _probe_emit_unique_kernel)
     from spark_rapids_tpu.exec.tpu_aggregate import (
@@ -151,6 +150,12 @@ def _build_join_pipeline(fact, items, warehouses):
     # unreferenced 'state' column from the warehouse scan; the loop
     # harness mirrors the pruned build side
     wb = from_arrow(warehouses.select(["warehouse_sk"]))
+    # each dimension's key range, as the execs read it once a build
+    import pyarrow.compute as pc
+    _dim_range = {
+        k: _KeyRange.fit(*([int(v)] for v in
+                           pc.min_max(t[k]).as_py().values()))
+        for k, t in (("item_sk", items), ("warehouse_sk", warehouses))}
 
     def _renamed(build, stream, bkey, skey):
         bnames = [f"__b{i}" for i in range(build.num_cols)]
@@ -172,11 +177,11 @@ def _build_join_pipeline(fact, items, warehouses):
         take."""
         b2, s2, bk, sk, bnames, snames = _renamed(build, stream, bkey,
                                                   skey)
-        bits = _probe_code_bits(b2, s2, bk, sk)
-        assert bits is not None and bits <= _PROBE_MAX_BITS, bits
-        out = _probe_emit_unique_kernel(b2, s2, bk, sk, variant,
-                                        out_cap, bnames, snames, False,
-                                        bits)
+        kr = _dim_range[bkey]
+        out = _probe_emit_unique_kernel(b2, s2, kr.base, kr.extent, bk,
+                                        sk, variant, out_cap, bnames,
+                                        snames, False, kr.entries,
+                                        (True,))
         names = (stream.names +
                  [f"b_{n}" for n in build.names])
         return DeviceBatch(names, out.columns, out.num_rows)
@@ -185,11 +190,11 @@ def _build_join_pipeline(fact, items, warehouses):
     # probe count kernel does per batch)
     def _count(build, stream, bkey, skey):
         b2, s2, bk, sk, _, _ = _renamed(build, stream, bkey, skey)
-        bits = _probe_code_bits(b2, s2, bk, sk)
-        assert bits is not None and bits <= _PROBE_MAX_BITS, bits
+        kr = _dim_range[bkey]
 
         def f(b2, s2):
-            return _probe_count_kernel(b2, s2, bk, sk, "inner", bits)
+            return _probe_count_kernel(b2, s2, kr.base, kr.extent, bk,
+                                       sk, "inner", kr.entries, (True,))
         total, maxm = jax.jit(f)(b2, s2)
         assert int(maxm) <= 1, int(maxm)
         return int(total)

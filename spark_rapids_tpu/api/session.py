@@ -285,9 +285,10 @@ class TpuSparkSession:
 
     # -- planning & execution ----------------------------------------------
     def _plan_physical(self, plan: lp.LogicalPlan) -> OverrideResult:
+        from spark_rapids_tpu.plan import optimizer
+        plan = optimizer.rewrite_implicit_joins(plan)
         if self.conf.get(cfg.COLUMN_PRUNING):
-            from spark_rapids_tpu.plan.optimizer import prune_columns
-            plan = prune_columns(plan)
+            plan = optimizer.prune_columns(plan)
         cpu_plan = plan_cpu(plan, self.conf)
         result = TpuOverrides.apply(cpu_plan, self.conf)
         if self.conf.test_enabled:

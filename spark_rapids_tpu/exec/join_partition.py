@@ -388,7 +388,8 @@ def _run_level(exec_obj, state: GraceJoinState, build: List[_Part],
             lb, rb = s, b
         lb = lb if lb is not None else _empty_side(exec_obj, 0)
         rb = rb if rb is not None else _empty_side(exec_obj, 1)
-        yield from exec_obj._join_pair(lb, rb)
+        kr = exec_obj._key_range(lb if build_is_left else rb, build_is_left)
+        yield from exec_obj._join_pair(lb, rb, kr)
         return
     # streamed (inner/left/semi/anti, build = right): probe handles
     # re-stream one at a time against the held build partition
@@ -398,12 +399,13 @@ def _run_level(exec_obj, state: GraceJoinState, build: List[_Part],
             _close_parts(state, probe)
             return
         b = _empty_side(exec_obj, 1)
+    kr = exec_obj._key_range(b, False)    # once a build partition
     with sp.register_or_hold(b) as rh:
         for p in probe:
             pb = _unpark(state, p)
             if not int(pb.num_rows):
                 continue
-            yield from exec_obj._join_pair(pb, rh.get())
+            yield from exec_obj._join_pair(pb, rh.get(), kr)
 
 
 def grace_join(exec_obj, probe_input, build_batches: List[DeviceBatch],

@@ -374,100 +374,166 @@ def _semi_kernel(build, stream, order, seg0, build_keys, stream_keys,
 
 
 # ---------------------------------------------------------------------------
-# Direct-address probe path (narrow keys)
+# Direct-address probe path (integer keys whose RANGE fits the table)
 # ---------------------------------------------------------------------------
 #
-# When every join key is integer-backed with a narrow vbits range hint,
-# the biased key fields pack into one u32 code and the hash table of
-# cudf's hash join (GpuHashJoin.scala:193-326) becomes a DENSE
-# direct-address table: one i32 scatter per build row, ONE gather per
-# stream row to find its match range.  This removes the combined-space
-# sort entirely — the sort-merge path's dominant cost is the (cap_b +
-# cap_s)-sized sort plus ~10 bookkeeping gathers per row; the probe path
-# pays 1-2 table gathers per stream row and per-output-column gathers
-# only.  Falls back to the sort path for wide/float/string keys or full
-# outer joins.
+# When every join key is integer-backed and the build side's keys span
+# few enough values, the hash table of cudf's hash join
+# (GpuHashJoin.scala:193-326) becomes a DENSE direct-address table
+# addressed by ``key - base``: one i32 scatter per build row, ONE gather
+# per stream row to find its match range.  This removes the
+# combined-space sort entirely — the sort-merge path's dominant cost is
+# the (cap_b + cap_s)-sized sort plus ~10 bookkeeping gathers per row;
+# the probe path pays 1-2 table gathers per stream row and
+# per-output-column gathers only.
+#
+# What picks the path is the keys' range, not their magnitude: TPC-DS
+# ``d_date_sk`` runs from 2,415,022 and spans 73,049 values.  The range
+# is ONE read of the build keys' min and max where the build side is
+# complete (a pipeline breaker: the host has nothing of this join left
+# to enqueue), handed to ``_join_pair`` by each caller.  ``base``
+# and ``extent`` are runtime arguments and the table's size a capacity
+# tier, so a new range mints no new program.  Strings, floats, full
+# outer joins and ranges past the table's limit take the sort-merge
+# path.
 
-_PROBE_MAX_BITS = 22    # direct table <= 4M entries (2 x 16 MiB i32)
+_DIRECT_MAX_ENTRIES = 1 << 22   # direct table <= 4M entries (2 x 16 MiB i32)
 
 
-def _probe_code_bits(build: DeviceBatch, stream: DeviceBatch,
-                     build_keys: Sequence[str],
-                     stream_keys: Sequence[str]) -> Optional[int]:
-    """Static (host-side) width of the packed direct-address code, or
-    None when the narrow encoding does not apply.  Mirrors the field
-    widths `_narrow_key_codes` produces (encode_fields with
-    nullable=True: 1 null bit + vbits value bits per key)."""
-    total = 0
-    for kb, ks in zip(build_keys, stream_keys):
-        b, s = build.column(kb), stream.column(ks)
-        for c in (b, s):
-            if c.dtype.is_string or c.dtype.is_floating or \
-                    c.dtype.is_bool or c.dtype.is_nested or \
-                    c.dtype.is_temporal:
+class _KeyRange:
+    """Host-side facts of one build side's keys for the direct table:
+    ``base`` and ``extent`` (int64 arrays, one entry a key; the table
+    is addressed by the mixed-radix number of ``key - base`` under
+    ``extent``) and ``entries``, the table's size tier."""
+
+    __slots__ = ("base", "extent", "entries")
+
+    def __init__(self, base, extent, entries: int):
+        self.base = np.asarray(base, dtype=np.int64)
+        self.extent = np.asarray(extent, dtype=np.int64)
+        self.entries = entries
+
+    @classmethod
+    def fit(cls, lo: Sequence[int], hi: Sequence[int]
+            ) -> Optional["_KeyRange"]:
+        """The range ``[lo, hi]`` a key (Python ints), or None where
+        the table would pass its limit."""
+        extent = [h - l + 1 for l, h in zip(lo, hi)]
+        entries = 1
+        for e in extent:
+            entries *= e
+        if entries > _DIRECT_MAX_ENTRIES:
+            return None
+        return cls(lo, extent, bucket_rows(entries))
+
+
+def _direct_key_widths(lschema: Schema, rschema: Schema,
+                       left_keys: Sequence[str],
+                       right_keys: Sequence[str]
+                       ) -> Optional[Tuple[bool, ...]]:
+    """Static (schema-only) eligibility for the direct table: per key
+    pair whether ``key - base`` needs 64-bit arithmetic, or None where
+    a key is no integer (strings, floats, bools, dates, nested: the
+    sort-merge path)."""
+    wide = []
+    for lk, rk in zip(left_keys, right_keys):
+        ld, rd = lschema.field(lk).dtype, rschema.field(rk).dtype
+        for d in (ld, rd):
+            if d.is_string or d.is_floating or d.is_bool or \
+                    d.is_nested or d.is_temporal:
                 return None
-        out_dt = b.dtype if b.dtype == s.dtype \
-            else dt.promote(b.dtype, s.dtype)
+        out_dt = ld if ld == rd else dt.promote(ld, rd)
         if not out_dt.is_numeric or out_dt.is_floating:
             return None
-        vb, _nn = _combined_hints([b, s])
-        npd = np.dtype(out_dt.to_np())
-        vb = min(vb or 64, npd.itemsize * 8)
-        if vb > 32 or vb >= 64:
-            return None
-        total += vb + 1                     # null flag + biased value
-    return total if total else None
+        wide.append(np.dtype(out_dt.to_np()).itemsize > 4)
+    return tuple(wide) if wide else None
+
+
+def _range_kernel(build: DeviceBatch, key_pos: Sequence[int]):
+    """``[[min, max], ...]`` (int64) of each build key over the rows
+    that can match: live, no key null.  An empty side reads min > max."""
+    valid = build.row_mask()
+    for i in key_pos:
+        valid = valid & build.columns[i].validity
+    out = []
+    for i in key_pos:
+        d = build.columns[i].data
+        info = jnp.iinfo(d.dtype)
+        out.append(jnp.stack([
+            jnp.min(jnp.where(valid, d, info.max)).astype(jnp.int64),
+            jnp.max(jnp.where(valid, d, info.min)).astype(jnp.int64)]))
+    return jnp.stack(out)
+
+
+def _direct_codes(cols: Sequence[ColVal], wide: Sequence[bool], base,
+                  extent):
+    """Table address of each row, and whether the row has one: every
+    key non-null and inside ``[base, base + extent)``.  The difference
+    wraps, so one unsigned compare is the whole range check whatever
+    the key's magnitude."""
+    code = ok = None
+    for i, v in enumerate(cols):
+        if wide[i]:
+            d = jax.lax.bitcast_convert_type(
+                v.data.astype(jnp.int64) - base[i], jnp.uint64)
+            inside = d < extent[i].astype(jnp.uint64)
+        else:
+            d = jax.lax.bitcast_convert_type(
+                v.data.astype(jnp.int32) - base[i].astype(jnp.int32),
+                jnp.uint32)
+            inside = d < extent[i].astype(jnp.uint32)
+        inside = inside & v.validity
+        d = jnp.where(inside, d.astype(jnp.int32), 0)
+        code = d if code is None else \
+            code * extent[i].astype(jnp.int32) + d
+        ok = inside if ok is None else ok & inside
+    return code, ok
 
 
 def _probe_tables(build: DeviceBatch, stream: DeviceBatch,
                   build_keys: Sequence[str], stream_keys: Sequence[str],
-                  bits: int):
-    """Shared probe-side prologue: per-side u32 codes, valid masks, and
-    the dense per-code build count table."""
-    bk = _key_vals(build, build_keys)
-    sk = _key_vals(stream, stream_keys)
-    combined = [_concat_colvals(b, s) for b, s in zip(bk, sk)]
-    code = _narrow_key_codes(combined, 0)
-    null_key = jnp.zeros((code.shape[0],), dtype=jnp.bool_)
-    for v in combined:
-        null_key = null_key | ~v.validity
-    cap_b = build.capacity
-    code = code.astype(jnp.uint32)
-    T = 1 << bits
-    bcode = code[:cap_b].astype(jnp.int32)
-    scode = code[cap_b:].astype(jnp.int32)
-    bvalid = build.row_mask() & ~null_key[:cap_b]
-    svalid = stream.row_mask() & ~null_key[cap_b:]
-    cnt = jnp.zeros((T,), jnp.int32).at[
-        jnp.where(bvalid, bcode, T)].add(1, mode="drop")
+                  entries: int, wide, base, extent):
+    """Shared probe-side prologue: per-side table addresses, valid
+    masks, and the dense per-address build count table.  A null key
+    has no address, so it matches nothing from either side."""
+    bcode, bok = _direct_codes(_key_vals(build, build_keys), wide, base,
+                               extent)
+    scode, sok = _direct_codes(_key_vals(stream, stream_keys), wide,
+                               base, extent)
+    bvalid = build.row_mask() & bok
+    svalid = stream.row_mask() & sok
+    cnt = jnp.zeros((entries,), jnp.int32).at[
+        jnp.where(bvalid, bcode, entries)].add(1, mode="drop")
     m = jnp.where(svalid, jnp.take(cnt, scode), 0)
     return bcode, scode, bvalid, svalid, cnt, m
 
 
-def _probe_count_kernel(build, stream, build_keys, stream_keys, how,
-                        bits):
+def _probe_count_kernel(build, stream, base, extent, build_keys,
+                        stream_keys, how, entries, wide):
     """(total output rows i64, max per-stream-row match count i32)."""
     _, _, _, _, _, m = _probe_tables(build, stream, build_keys,
-                                     stream_keys, bits)
+                                     stream_keys, entries, wide, base,
+                                     extent)
     m_out = jnp.where(stream.row_mask(), jnp.maximum(m, 1), 0) \
         if how == "left" else m
     return jnp.sum(m_out, dtype=jnp.int64), jnp.max(m)
 
 
-def _probe_emit_unique_kernel(build, stream, build_keys, stream_keys,
-                              how, out_cap, build_names, stream_names,
-                              build_first_in_output, bits):
+def _probe_emit_unique_kernel(build, stream, base, extent, build_keys,
+                              stream_keys, how, out_cap, build_names,
+                              stream_names, build_first_in_output,
+                              entries, wide):
     """Emit when every build key is unique (max match count <= 1): the
     dense table maps code -> build row directly, output rows are stream
     rows (left: in place; inner: compacted), no expansion machinery."""
     bcode, scode, bvalid, svalid, _cnt, _m = _probe_tables(
-        build, stream, build_keys, stream_keys, bits)
-    T = 1 << bits
+        build, stream, build_keys, stream_keys, entries, wide, base,
+        extent)
     cap_b, cap_s = build.capacity, stream.capacity
     # row+1 sentinel table: 0 = no build row, ONE gather gives both the
     # match flag and the row
-    rows1 = jnp.zeros((T,), jnp.int32).at[
-        jnp.where(bvalid, bcode, T)].set(
+    rows1 = jnp.zeros((entries,), jnp.int32).at[
+        jnp.where(bvalid, bcode, entries)].set(
         jnp.arange(cap_b, dtype=jnp.int32) + 1, mode="drop")
     hit = jnp.where(svalid, jnp.take(rows1, scode), 0)
     matched = hit > 0
@@ -503,16 +569,17 @@ def _probe_emit_unique_kernel(build, stream, build_keys, stream_keys,
     return DeviceBatch(names, cols, total_out)
 
 
-def _probe_emit_dup_kernel(build, stream, border, build_keys,
-                           stream_keys, how, out_cap, build_names,
-                           stream_names, build_first_in_output, bits):
+def _probe_emit_dup_kernel(build, stream, border, base, extent,
+                           build_keys, stream_keys, how, out_cap,
+                           build_names, stream_names,
+                           build_first_in_output, entries, wide):
     """Emit with duplicated build keys: build rows grouped by code via
     the (small) build-side sort ``border``, match ranges from the dense
     start/count tables, output expansion via cumsum + set-scatter +
     cummax forward fill (no combined-space sort)."""
     bcode, scode, bvalid, svalid, cnt, m = _probe_tables(
-        build, stream, build_keys, stream_keys, bits)
-    T = 1 << bits
+        build, stream, build_keys, stream_keys, entries, wide, base,
+        extent)
     cap_b, cap_s = build.capacity, stream.capacity
     starts_tbl = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(cnt)[:-1]])
@@ -548,10 +615,11 @@ def _probe_emit_dup_kernel(build, stream, border, build_keys,
     return DeviceBatch(names, cols, total_out)
 
 
-def _probe_semi_kernel(build, stream, build_keys, stream_keys, anti,
-                       bits):
+def _probe_semi_kernel(build, stream, base, extent, build_keys,
+                       stream_keys, anti, entries, wide):
     _, _, _, _, _, m = _probe_tables(build, stream, build_keys,
-                                     stream_keys, bits)
+                                     stream_keys, entries, wide, base,
+                                     extent)
     keep = (m == 0) if anti else (m > 0)
     return compact(stream, keep & stream.row_mask())
 
@@ -562,6 +630,7 @@ class _BroadcastBuildMixin:
     def _init_build(self, build_side: str) -> None:
         self.build_side = build_side
         self._built = None
+        self._built_range = None
         self._build_done = False
         import threading
         self._build_lock = threading.Lock()
@@ -573,14 +642,24 @@ class _BroadcastBuildMixin:
         # per probe (reference: broadcast build kept as
         # SpillableColumnarBatch, GpuBroadcastExchangeExec)
         from spark_rapids_tpu.mem.spill import register_or_hold
+        from spark_rapids_tpu.obs import trace as obstrace
         with self._build_lock:
             if not self._build_done:
                 side = 1 if self.build_side == "right" else 0
-                built = _gather(self.children[side])
+                with obstrace.span("join.build"):
+                    built = _gather(self.children[side])
+                    if built is not None:
+                        self._built_range = self._built_key_range(built)
                 self._built = None if built is None \
                     else register_or_hold(built)
                 self._build_done = True
         return None if self._built is None else self._built.get()
+
+    def _built_key_range(self, built: DeviceBatch):
+        """A hash join's facts about the gathered side's keys (read
+        once, here, where the side is complete); nothing for a
+        nested-loop join."""
+        return None
 
 
 class _HashJoinBase(TpuExec):
@@ -598,6 +677,12 @@ class _HashJoinBase(TpuExec):
         self.condition = condition
         self._schema = schema
         self._kernels = {}
+        # which keys need 64-bit arithmetic in the direct table; None:
+        # the sort-merge path (a key that is no integer, a full outer
+        # join, whose unmatched build rows the probe kernels do not emit)
+        self._direct_wide = None if how == "full" else \
+            _direct_key_widths(left.schema, right.schema,
+                               self.left_keys, self.right_keys)
 
     @property
     def schema(self) -> Schema:
@@ -619,21 +704,64 @@ class _HashJoinBase(TpuExec):
         order = sortkeys.shared_lexsort(jnp.reshape(packed, (1, -1)))
         return order, seg0
 
+    def _build_is_left(self, build_side: str) -> bool:
+        """Which child ``_join_pair`` builds on: semi/anti on the
+        right, right outer on the left (left outer, sides swapped)."""
+        return self.how not in ("semi", "anti") and \
+            (build_side == "left" or self.how == "right")
+
+    def _key_range(self, build: DeviceBatch, build_is_left: bool
+                   ) -> Optional[_KeyRange]:
+        """What the direct table needs to know of a complete build side
+        (its real names, as the child gave it): each key's base and
+        extent and the table's tier, or None where the join takes the
+        sort-merge path (a key that is no integer, a full outer join, a
+        range past the table's limit).
+
+        ONE read of the keys' min and max (none for a side the host
+        knows to be empty).  The read blocks until the build side has
+        run, so callers make it where that side is complete and before
+        the stream side is pulled: once a build, not once a stream
+        batch."""
+        if self._direct_wide is None:
+            return None
+        from spark_rapids_tpu.exec import kernel_abi, kernel_cache as kc
+        from spark_rapids_tpu.obs import registry as obsreg
+        from spark_rapids_tpu.obs import trace as obstrace
+        keys = self.left_keys if build_is_left else self.right_keys
+        pos = tuple(build.names.index(k) for k in keys)
+        if isinstance(build.num_rows, (int, np.integer)) and \
+                not build.num_rows:
+            return _KeyRange.fit([0] * len(pos), [0] * len(pos))
+        eb = kernel_abi.erase(build)
+        fn = kc.get_kernel(("join_range", pos, _side_key(eb)),
+                           lambda: lambda b: _range_kernel(b, pos))
+        with obstrace.span("join.rangeWait", cat="query"):
+            got = np.asarray(fn(eb))
+        obsreg.get_registry().inc("join.rangeReads")
+        lo, hi = [int(v) for v in got[:, 0]], [int(v) for v in got[:, 1]]
+        if any(l > h for l, h in zip(lo, hi)):
+            lo, hi = [0] * len(pos), [0] * len(pos)   # nothing to match
+        return _KeyRange.fit(lo, hi)
+
     def _probe_pair(self, build: DeviceBatch, stream: DeviceBatch,
                     bkeys, skeys, emit_how: str, build_first: bool,
-                    bits: int):
-        """Direct-address probe join (narrow keys): count -> host picks
-        the unique or duplicated-build-key emit variant."""
+                    kr: _KeyRange):
+        """Direct-address probe join: count -> host picks the unique or
+        duplicated-build-key emit variant."""
         from spark_rapids_tpu.exec import kernel_cache as kc
-        sig = (bits, emit_how, tuple(bkeys), tuple(skeys),
+        wide, entries = self._direct_wide, kr.entries
+        sig = (entries, wide, emit_how, tuple(bkeys), tuple(skeys),
                _side_key(build), _side_key(stream))
         ckey = ("probe_count",) + sig
         if ckey not in self._kernels:
             self._kernels[ckey] = kc.get_kernel(
-                ckey, lambda: lambda b, s: _probe_count_kernel(
-                    b, s, bkeys, skeys, emit_how, bits))
+                ckey, lambda: lambda b, s, base, ext: _probe_count_kernel(
+                    b, s, base, ext, bkeys, skeys, emit_how, entries,
+                    wide))
         with timed(self.metrics, "join.probeCount"):
-            total, maxm = self._kernels[ckey](build, stream)
+            total, maxm = self._kernels[ckey](build, stream, kr.base,
+                                              kr.extent)
             total, maxm = int(total), int(maxm)
         if total >= (1 << 31):
             raise MemoryError(
@@ -651,18 +779,21 @@ class _HashJoinBase(TpuExec):
                     build_first) + sig
             if ekey not in self._kernels:
                 self._kernels[ekey] = kc.get_kernel(
-                    ekey, lambda: lambda b, s: _probe_emit_unique_kernel(
-                        b, s, bkeys, skeys, emit_variant, out_cap,
-                        build.names, stream.names, build_first, bits))
+                    ekey, lambda: lambda b, s, base, ext:
+                    _probe_emit_unique_kernel(
+                        b, s, base, ext, bkeys, skeys, emit_variant,
+                        out_cap, build.names, stream.names, build_first,
+                        entries, wide))
             with timed(self.metrics, "join.probeEmit"):
-                out = self._kernels[ekey](build, stream)
+                out = self._kernels[ekey](build, stream, kr.base,
+                                          kr.extent)
         else:
             out_cap = bucket_rows(total)
             pkey = ("probe_bpack",) + sig
             if pkey not in self._kernels:
-                def bpack(b, s):
+                def bpack(b, s, base, ext):
                     bcode, _, bvalid, _, _, _ = _probe_tables(
-                        b, s, bkeys, skeys, bits)
+                        b, s, bkeys, skeys, entries, wide, base, ext)
                     key = jnp.where(bvalid, bcode.astype(jnp.uint64),
                                     jnp.uint64(0xFFFFFFFF))
                     return jnp.reshape(key, (1, -1))
@@ -671,13 +802,17 @@ class _HashJoinBase(TpuExec):
             ekey = ("probe_emit_d", out_cap, build_first) + sig
             if ekey not in self._kernels:
                 self._kernels[ekey] = kc.get_kernel(
-                    ekey, lambda: lambda b, s, o: _probe_emit_dup_kernel(
-                        b, s, o, bkeys, skeys, emit_how, out_cap,
-                        build.names, stream.names, build_first, bits))
+                    ekey, lambda: lambda b, s, o, base, ext:
+                    _probe_emit_dup_kernel(
+                        b, s, o, base, ext, bkeys, skeys, emit_how,
+                        out_cap, build.names, stream.names, build_first,
+                        entries, wide))
             with timed(self.metrics, "join.probeEmit"):
                 border = sortkeys.shared_lexsort(
-                    self._kernels[pkey](build, stream))
-                out = self._kernels[ekey](build, stream, border)
+                    self._kernels[pkey](build, stream, kr.base,
+                                        kr.extent))
+                out = self._kernels[ekey](build, stream, border,
+                                          kr.base, kr.extent)
         out = DeviceBatch(self._schema.names, out.columns, out.num_rows)
         if self.condition is not None:
             v = eval_tpu.evaluate(self.condition, out)
@@ -687,9 +822,23 @@ class _HashJoinBase(TpuExec):
         yield out
 
     def _join_pair(self, left: DeviceBatch, right: DeviceBatch,
+                   key_range: Optional[_KeyRange],
                    build_side: str = "right"):
-        """Join two single batches; yields 0 or 1 output batches."""
+        """Join two single batches; yields 0 or 1 output batches.
+        ``key_range`` is :meth:`_key_range` of the side
+        :meth:`_build_is_left` names, made by the caller once a build
+        (None: the sort-merge path)."""
+        from spark_rapids_tpu.exec import kernel_cache as kc
+        from spark_rapids_tpu.obs import registry as obsreg
         how = self.how
+        build_is_left = self._build_is_left(build_side)
+        kr = key_range
+        reg = obsreg.get_registry()
+        if kr is None:
+            reg.inc("join.path.sortMerge")
+        else:
+            reg.inc("join.path.direct")
+            reg.gauge_max("join.table.entries", kr.entries)
         # canonicalize both sides at the dispatch boundary: positional
         # __l*/__r* names (dodges duplicate-name lookups AND erases the
         # user schema from the kernel identity) + ABI hint bucketing /
@@ -700,18 +849,20 @@ class _HashJoinBase(TpuExec):
         right = _canon_side(right, "__r")
 
         if how in ("semi", "anti"):
-            from spark_rapids_tpu.exec import kernel_cache as kc
-            bits = _probe_code_bits(right, left, rkeys, lkeys)
-            if bits is not None and bits <= _PROBE_MAX_BITS:
-                key = ("probe_semi", how, bits, tuple(lkeys),
+            if kr is not None:
+                wide, entries = self._direct_wide, kr.entries
+                key = ("probe_semi", how, entries, wide, tuple(lkeys),
                        tuple(rkeys), _side_key(left),
                        _side_key(right))
                 if key not in self._kernels:
                     self._kernels[key] = kc.get_kernel(
-                        key, lambda: lambda b, s: _probe_semi_kernel(
-                            b, s, rkeys, lkeys, how == "anti", bits))
+                        key, lambda: lambda b, s, base, ext:
+                        _probe_semi_kernel(
+                            b, s, base, ext, rkeys, lkeys,
+                            how == "anti", entries, wide))
                 with timed(self.metrics, "join.semi"):
-                    out = self._kernels[key](right, left)
+                    out = self._kernels[key](right, left, kr.base,
+                                             kr.extent)
             else:
                 key = ("semi", how, tuple(lkeys), tuple(rkeys),
                        _side_key(left), _side_key(right))
@@ -729,7 +880,7 @@ class _HashJoinBase(TpuExec):
                               out.num_rows)
             return
 
-        if build_side == "left" or how == "right":
+        if build_is_left:
             # right outer == left outer with sides swapped
             build, stream = left, right
             bkeys, skeys = lkeys, rkeys
@@ -741,12 +892,9 @@ class _HashJoinBase(TpuExec):
             emit_how = how
             build_first = False
 
-        from spark_rapids_tpu.exec import kernel_cache as kc
-        bits = _probe_code_bits(build, stream, bkeys, skeys)
-        if bits is not None and bits <= _PROBE_MAX_BITS and \
-                emit_how in ("inner", "left"):
+        if kr is not None:
             yield from self._probe_pair(build, stream, bkeys, skeys,
-                                        emit_how, build_first, bits)
+                                        emit_how, build_first, kr)
             return
         ckey = ("count", emit_how, tuple(bkeys), tuple(skeys),
                 _side_key(build), _side_key(stream))
@@ -817,6 +965,7 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
             coalesce goals keep probe batches per partition few.
             """
             from spark_rapids_tpu.mem.spill import register_or_hold
+            from spark_rapids_tpu.obs import trace as obstrace
             if oocore is not None:
                 rbs = [b for b in rit if int(b.num_rows)]
                 build_bytes = sum(int(b.nbytes()) for b in rbs)
@@ -840,11 +989,13 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
                 right = _empty_like(self.children[1].schema)
             # the build partition is held across the whole stream probe
             # loop — keep it spillable between probe batches
+            with obstrace.span("join.build"):
+                kr = self._key_range(right, False)
             with register_or_hold(right) as rh:
                 for lb in lit:
                     if not int(lb.num_rows):
                         continue
-                    yield from self._join_pair(lb, rh.get())
+                    yield from self._join_pair(lb, rh.get(), kr)
 
         def run_gathered(lit, rit):
             """right/full: unmatched-build emission needs every stream
@@ -878,7 +1029,10 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
                         _empty_like(self.children[1].schema)
                 else:
                     return
-            yield from self._join_pair(left, right)
+            build_is_left = self._build_is_left("right")
+            kr = self._key_range(left if build_is_left else right,
+                                 build_is_left)
+            yield from self._join_pair(left, right, kr)
 
         run = run_gathered if self.how in ("right", "full") \
             else run_streamed
@@ -895,6 +1049,12 @@ class TpuBroadcastHashJoinExec(_BroadcastBuildMixin, _HashJoinBase):
                  transport: str = "local"):
         super().__init__(*args)
         self._init_build(build_side)
+        # Spark's build-side validity, as the planner applies it
+        # (left/semi/anti broadcast the right, right outer the left):
+        # the pair's table is built on the broadcast side, so its key
+        # range is read once a build and never once a stream batch
+        assert self._build_is_left(build_side) == (build_side == "left"), \
+            f"a {self.how} join cannot broadcast its {build_side} side"
         # 'ici': replicate the build side over the device mesh with one
         # mesh broadcast so each stream shard joins against its LOCAL
         # copy (GpuBroadcastExchangeExec analog) instead of depending on
@@ -903,6 +1063,9 @@ class TpuBroadcastHashJoinExec(_BroadcastBuildMixin, _HashJoinBase):
         self._bcast_map = None
         import threading
         self._bcast_lock = threading.Lock()
+
+    def _built_key_range(self, built: DeviceBatch):
+        return self._key_range(built, self.build_side == "left")
 
     def _build_broadcast(self):
         built = self._build()   # takes _build_lock itself
@@ -955,10 +1118,13 @@ class TpuBroadcastHashJoinExec(_BroadcastBuildMixin, _HashJoinBase):
                 build = self._build_for(sb)
                 b = build if build is not None else \
                     _empty_like(self.children[1 - stream_side].schema)
+                # read once, in _build; an empty side is host-known
+                kr = self._built_range if build is not None else \
+                    self._built_key_range(b)
                 if self.build_side == "right":
-                    yield from self._join_pair(sb, b, "right")
+                    yield from self._join_pair(sb, b, kr, "right")
                 else:
-                    yield from self._join_pair(b, sb, "left")
+                    yield from self._join_pair(b, sb, kr, "left")
 
         return [run(it) for it in sits]
 

@@ -27,7 +27,7 @@ per-operator fallback philosophy applied at column granularity).
 from __future__ import annotations
 
 import struct
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 from typing import List, Optional, Tuple
 
@@ -68,6 +68,9 @@ class RunTable:
     values: List[int]
     bit_bases: List[int]
     widths: List[int]
+    # io/parquet_fused's layout of this stream (what no batch changes),
+    # made on first use of the finished table and kept with it
+    layout: object = field(default=None, repr=False, compare=False)
 
     @staticmethod
     def empty() -> "RunTable":

@@ -50,6 +50,7 @@ and processes.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -181,40 +182,63 @@ def _all_valid(runs: RunTable) -> bool:
                for r, v in zip(runs.is_rle, runs.values))
 
 
-def _stream_quads(runs: RunTable, packed: bytes,
-                  add_region) -> List[Tuple[int, int, int, int]]:
-    """Per-run (start, end, A, C) for one stream.
+@dataclass
+class _StreamLayout:
+    """What of one stream's run layout no batch changes, so that a scan
+    of cached chunk plans walks no run again: made once a (cached)
+    ``RunTable`` and kept on it.  Arrays are int64, one entry a run
+    (``start``, ``end``, ``c``, ``carry``) or a bit-packed run (``w``,
+    ``rel``)."""
+    start: np.ndarray     # first element of each run
+    end: np.ndarray
+    c: np.ndarray         # value*2+1 for an RLE run, 0 for a bit-packed
+    carry: np.ndarray     # the last bit-packed run at or before, -1: none
+    w: np.ndarray         # bit-packed runs' widths
+    # value offset of a bit-packed run's byte region within what this
+    # stream adds to its width's buffer, less the run's start (element
+    # i of the run reads dense_all[dense_off[w] + base + rel + i])
+    rel: np.ndarray
+    # the byte regions as (width, lo, hi) of the packed buffer: a run's
+    # region ends where the next bit-packed run's begins, so neighbours
+    # of one width are one slice
+    slices: List[Tuple[int, int, int]]
+    totals: Dict[int, int]    # values added to each width's buffer
 
-    A = dense_all index of the run's first value minus the run's start
-    (so element i of the run reads dense_all[A + i]); C packs the RLE
-    value and flag as value*2+is_rle.  ``add_region(w, bytes) -> value
-    offset`` appends a bit-packed byte region to the width-w buffer and
-    returns its value offset within that buffer (resolved to a global
-    dense_all offset later via a per-width base)."""
-    n = len(runs.counts)
-    bp = [i for i in range(n) if not runs.is_rle[i]]
-    region_end = {}
-    for j, i in enumerate(bp):
-        b1 = runs.bit_bases[bp[j + 1]] // 8 if j + 1 < len(bp) \
-            else len(packed)
-        region_end[i] = b1
-    quads = []
-    pos = 0
-    for i in range(n):
-        c = runs.counts[i]
-        start, end = pos, pos + c
-        pos = end
-        if runs.is_rle[i]:
-            # A is irrelevant for RLE elements; carry 0 markers — the
-            # delta chain re-telescopes through whatever value we pick,
-            # and the slice path never reads A when C's flag is set
-            quads.append((start, end, None, (runs.values[i] << 1) | 1))
-        else:
-            w = runs.widths[i]
-            b0 = runs.bit_bases[i] // 8
-            off = add_region(w, packed[b0:region_end[i]])
-            quads.append((start, end, (w, off - start), 0))
-    return quads
+
+def _stream_layout(runs: RunTable, packed_len: int) -> _StreamLayout:
+    """``runs``' layout over its packed buffer of ``packed_len`` bytes,
+    from the table where an earlier scan left it."""
+    if runs.layout is not None:
+        return runs.layout
+    counts = np.asarray(runs.counts, dtype=np.int64)
+    end = np.cumsum(counts)
+    start = end - counts
+    is_rle = np.asarray(runs.is_rle, dtype=np.bool_)
+    c = np.where(is_rle,
+                 (np.asarray(runs.values, dtype=np.int64) << 1) | 1, 0)
+    bp = np.flatnonzero(~is_rle)
+    m = bp.shape[0]
+    carry = np.full(counts.shape[0], -1, dtype=np.int64)
+    carry[bp] = np.arange(m)
+    carry = np.maximum.accumulate(carry)
+    w = np.asarray(runs.widths, dtype=np.int64)[bp]
+    b0 = np.asarray(runs.bit_bases, dtype=np.int64)[bp] // 8
+    b1 = np.append(b0[1:], packed_len)
+    nvals = (b1 - b0) * 8 // w
+    rel = np.zeros(m, dtype=np.int64)
+    totals: Dict[int, int] = {}
+    for u in np.unique(w):
+        of = w == u
+        rel[of] = np.cumsum(nvals[of]) - nvals[of]
+        totals[int(u)] = int(nvals[of].sum())
+    rel -= start[bp]
+    cuts = np.flatnonzero(np.diff(w)) + 1
+    slices = [(int(w[i]), int(b0[i]), int(b1[k - 1]))
+              for i, k in zip(np.append(0, cuts), np.append(cuts, m))] \
+        if m else []
+    runs.layout = _StreamLayout(start, end, c, carry, w, rel, slices,
+                                totals)
+    return runs.layout
 
 
 def assemble(plans: List[List[Optional[ChunkPlan]]],
@@ -275,14 +299,20 @@ def assemble(plans: List[List[Optional[ChunkPlan]]],
 
     width_bytes: Dict[int, List[bytes]] = {}
     width_vals: Dict[int, int] = {}
+    # per stream: its layout and, a width, the values the streams
+    # before it added to that width's buffer
+    streams: List[Tuple[_StreamLayout, Dict[int, int]]] = []
 
-    def add_region(w: int, b: bytes) -> int:
-        off = width_vals.get(w, 0)
-        width_bytes.setdefault(w, []).append(b)
-        width_vals[w] = off + len(b) * 8 // w
-        return off
+    def add_stream(runs: RunTable, packed: bytes) -> int:
+        lay = _stream_layout(runs, len(packed))
+        streams.append((lay, {w: width_vals.get(w, 0)
+                              for w in lay.totals}))
+        for w, lo, hi in lay.slices:
+            width_bytes.setdefault(w, []).append(packed[lo:hi])
+        for w, n in lay.totals.items():
+            width_vals[w] = width_vals.get(w, 0) + n
+        return len(streams) - 1
 
-    stream_quads: List[List[Tuple]] = []
     meta: List[int] = []
     specs: List[List[_SegSpec]] = []
 
@@ -306,13 +336,9 @@ def assemble(plans: List[List[Optional[ChunkPlan]]],
                          defer=(ci in defer_cols and
                                 p.mode in ("dict", "dict_str")))
             if nullable:
-                s.def_stream = len(stream_quads)
-                stream_quads.append(_stream_quads(
-                    p.def_runs, p.def_packed, add_region))
+                s.def_stream = add_stream(p.def_runs, p.def_packed)
             if p.mode in ("dict", "dict_str", "bool"):
-                s.val_stream = len(stream_quads)
-                stream_quads.append(_stream_quads(
-                    p.val_runs, p.val_packed, add_region))
+                s.val_stream = add_stream(p.val_runs, p.val_packed)
             if p.mode == "plain":
                 key = str(p.plain_np.dtype)
                 s.plain_key = key
@@ -368,51 +394,53 @@ def assemble(plans: List[List[Optional[ChunkPlan]]],
     if dense_len > int(_BIG):
         raise UnsupportedChunk("packed streams too long for fused decode")
 
-    # -- resolve stream runs to (start, end, A, C) with global A, and
-    # -- split into the slice path and the general path
+    # -- resolve stream runs to (start, end, A, C) with global A (an
+    # -- RLE run carries the A of the bit-packed run before it: the
+    # -- delta chain re-telescopes through whatever value it holds, and
+    # -- the slice path never reads A when C's flag is set), and split
+    # -- into the slice path and the general path
     stream_path: List[Tuple[str, int]] = []
-    sruns_rows: List[np.ndarray] = []
-    gruns_rows: List[np.ndarray] = []
+    n_slice = n_gen = 0
     max_slice_runs = 1
     max_gen_runs = 1
-    resolved: List[List[Tuple[int, int, int, int]]] = []
-    for quads in stream_quads:
-        rs = []
-        a_carry = 0
-        for (start, end, pv, c) in quads:
-            if pv is not None:
-                w, rel = pv
-                a_carry = dense_off[w] + rel
-            rs.append((start, end, a_carry, c))
-        resolved.append(rs)
-        if len(rs) <= _SLICE_MAX_RUNS:
-            stream_path.append(("slice", len(sruns_rows)))
-            sruns_rows.append(None)   # placeholder, filled below
-            max_slice_runs = max(max_slice_runs, len(rs) or 1)
+    resolved: List[np.ndarray] = []
+    for lay, base in streams:
+        a_bp = lay.rel.copy()
+        for w, b in base.items():
+            a_bp[lay.w == w] += dense_off[w] + b
+        resolved.append(
+            np.where(lay.carry >= 0, a_bp[np.maximum(lay.carry, 0)], 0)
+            if a_bp.shape[0] else np.zeros_like(lay.start))
+        n = lay.start.shape[0]
+        if n <= _SLICE_MAX_RUNS:
+            stream_path.append(("slice", n_slice))
+            n_slice += 1
+            max_slice_runs = max(max_slice_runs, n or 1)
         else:
-            stream_path.append(("general", len(gruns_rows)))
-            gruns_rows.append(None)
-            max_gen_runs = max(max_gen_runs, len(rs))
+            stream_path.append(("general", n_gen))
+            n_gen += 1
+            max_gen_runs = max(max_gen_runs, n)
 
     nslcap = _bucket_strlen(max_slice_runs)
     rcap = bucket_rows(max_gen_runs, 8)
-    for si, rs in enumerate(resolved):
-        path, idx = stream_path[si]
+    sruns_rows: List[np.ndarray] = []
+    gruns_rows: List[np.ndarray] = []
+    for (lay, _), a, (path, _) in zip(streams, resolved, stream_path):
+        n = lay.start.shape[0]
         if path == "slice":
             mat = np.zeros((nslcap, 4), dtype=np.int32)
             mat[:, 0] = _BIG        # empty range: start == end == BIG
             mat[:, 1] = _BIG
-            for r, (st, en, a, c) in enumerate(rs):
-                mat[r] = (st, en, a, c)
-            sruns_rows[idx] = mat
+            mat[:n, 0], mat[:n, 1] = lay.start, lay.end
+            mat[:n, 2], mat[:n, 3] = a, lay.c
+            sruns_rows.append(mat)
         else:
             mat = np.zeros((rcap, 3), dtype=np.int32)
             mat[:, 0] = _BIG        # scatter target past vcap: dropped
-            prev_a = prev_c = 0
-            for r, (st, en, a, c) in enumerate(rs):
-                mat[r] = (st, a - prev_a, c - prev_c)
-                prev_a, prev_c = a, c
-            gruns_rows[idx] = mat
+            mat[:n, 0] = lay.start
+            mat[:n, 1] = np.diff(a, prepend=0)
+            mat[:n, 2] = np.diff(lay.c, prepend=0)
+            gruns_rows.append(mat)
 
     arrays: Dict[str, np.ndarray] = {
         "nrows": np.asarray(n_rows, dtype=np.int32),
@@ -1076,43 +1104,62 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
     bk = kb.resolve(backend)
 
     with phase("scan.hostPrepTime"):
-        plans, fallbacks, list_cols = _collect_plans(
-            sources, schema, wanted, host_threads, metrics=metrics)
-
-        dev_cols = [c for c, p in zip(wanted, plans) if p is not None]
-        dev_dtypes = [d for d, p in zip(out_dtypes, plans)
-                      if p is not None]
-        dev_plans = [p for p in plans if p is not None]
-
+        from spark_rapids_tpu.io import scan_cache as sc
         total = sum(n_rows)
         cap = bucket_rows(max(total, 1))
 
-        pushed = None
-        if pushed_filter is not None and bk == kb.PALLAS:
-            # every column the condition reads must be device-decoded
-            # in THIS batch (a fallback/list/partition operand would
-            # evaluate against a placeholder) — ineligible batches keep
-            # the ordinary decode, per-kernel-fallback style
-            from spark_rapids_tpu.expr import ir as _ir
-            ref_names = {scan_names[b.ordinal] for b in _ir.collect(
-                pushed_filter,
-                lambda e: isinstance(e, _ir.BoundReference))}
-            if ref_names <= set(dev_cols):
-                pushed = pushed_filter
-            else:
-                kb.fallback("scan.filterDecode", "condition_columns")
+        # off the pallas backend a batch's upload set is a function of
+        # its files' chunks alone (no pushed filter, no process knob),
+        # so a batch of unchanged files is walked, packed and uploaded
+        # once, not once a query
+        stamps = tuple((sc.handle_key(pf, path), rg)
+                       for pf, path, rg in sources)
+        akey = (stamps, tuple(wanted), tuple(d.name for d in out_dtypes),
+                bk) if bk != kb.PALLAS and \
+            all(s is not None for s, _ in stamps) else None
+        kept = sc.get_assembled(akey)
+        if kept is not None:
+            fp, dev_arrays = kept
+            dev_cols, fallbacks, list_cols = list(wanted), [], {}
+            # every plan the set stands for counts as served
+            sc.count_plan_hits(metrics, len(wanted) * len(sources))
+        else:
+            fp, dev_arrays = None, None
+            plans, fallbacks, list_cols = _collect_plans(
+                sources, schema, wanted, host_threads, metrics=metrics)
 
-        fp = assemble(dev_plans, dev_dtypes, dev_cols, n_rows,
-                      backend=bk, pushed_filter=pushed,
-                      scan_names=scan_names) \
-            if dev_plans else None
+            dev_cols = [c for c, p in zip(wanted, plans) if p is not None]
+            dev_dtypes = [d for d, p in zip(out_dtypes, plans)
+                          if p is not None]
+            dev_plans = [p for p in plans if p is not None]
+            if len(dev_cols) != len(wanted):
+                akey = None     # only a wholly fused batch is kept
+
+            pushed = None
+            if pushed_filter is not None and bk == kb.PALLAS:
+                # every column the condition reads must be device-decoded
+                # in THIS batch (a fallback/list/partition operand would
+                # evaluate against a placeholder) — ineligible batches keep
+                # the ordinary decode, per-kernel-fallback style
+                from spark_rapids_tpu.expr import ir as _ir
+                ref_names = {scan_names[b.ordinal] for b in _ir.collect(
+                    pushed_filter,
+                    lambda e: isinstance(e, _ir.BoundReference))}
+                if ref_names <= set(dev_cols):
+                    pushed = pushed_filter
+                else:
+                    kb.fallback("scan.filterDecode", "condition_columns")
+
+            if dev_plans:
+                fp = assemble(dev_plans, dev_dtypes, dev_cols, n_rows,
+                              backend=bk, pushed_filter=pushed,
+                              scan_names=scan_names)
         if fp is not None and fp.pushed is not None:
             kb.hit("scan.filterDecode")
 
     with phase("scan.uploadTime"):
-        dev_arrays = {k: jnp.asarray(v) for k, v in fp.arrays.items()} \
-            if fp is not None else None
-        if fp is not None:
+        if fp is not None and dev_arrays is None:
+            dev_arrays = {k: jnp.asarray(v) for k, v in fp.arrays.items()}
             # upload-byte accounting: global counter + tenant ledger,
             # same n (the exactness invariant)
             from spark_rapids_tpu.obs import accounting as _acct
@@ -1122,11 +1169,15 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
             if up:
                 _obsreg.get_registry().inc("scan.bytesUploaded", up)
                 _acct.charge("scan.bytesUploaded", up)
+            if akey is not None:
+                # the host copy has done its work: what is kept is the
+                # plan's static half and the arrays where they now live
+                fp = dataclasses.replace(fp, arrays={})
+                sc.put_assembled(akey, (fp, dev_arrays), up)
 
         extra_cols: Dict[str, DeviceColumn] = dict(list_cols)
         if fallbacks:
             import pyarrow.parquet as papq
-            from spark_rapids_tpu.io import scan_cache as sc
             opened: Dict[str, Any] = {}
 
             def reader(pf, path):

@@ -22,7 +22,13 @@ multi-file reader clips row groups without re-reading the tail):
   * eviction is LRU at file granularity under a byte budget
     (``spark.rapids.tpu.sql.scan.metadataCache.maxBytes``) — run
     tables and packed buffers are the dominant cost and are accounted
-    per plan.
+    per plan;
+  * what the budget has left over holds the *assembled upload sets* of
+    whole scan batches (``get_assembled`` / ``put_assembled``): a batch
+    of cached plans is packed into its upload arrays and uploaded
+    once, not once a query.  They are derived data resident in HBM, so
+    they are the first to go, never push a plan out, and are all
+    dropped under memory pressure.
 
 Lookups stat the file every time (µs against ms-scale walks), so an
 overwritten file is never served stale plans.  All entry points are
@@ -38,6 +44,7 @@ from __future__ import annotations
 import hashlib
 import io as _io
 import os
+import sys
 import threading
 from collections import OrderedDict
 from typing import Any, Dict, Optional, Tuple
@@ -56,6 +63,10 @@ _FILES: "OrderedDict[Tuple, _FileEntry]" = OrderedDict()
 # immediately instead of lingering until eviction)
 _PATH_KEY: Dict[str, Tuple] = {}
 _TOTAL_BYTES = 0
+
+# batch key -> (assembled upload set, bytes), LRU order (oldest first)
+_ASSEMBLED: "OrderedDict[Tuple, Tuple[Any, int]]" = OrderedDict()
+_ASSEMBLED_BYTES = 0
 
 _HITS = 0
 _MISSES = 0
@@ -149,7 +160,9 @@ def stats() -> Dict[str, int]:
         return {"hits": _HITS, "misses": _MISSES,
                 "evictions": _EVICTIONS,
                 "invalidations": _INVALIDATIONS,
-                "entries": len(_FILES), "bytes": _TOTAL_BYTES}
+                "entries": len(_FILES), "bytes": _TOTAL_BYTES,
+                "assembled": len(_ASSEMBLED),
+                "assembled_bytes": _ASSEMBLED_BYTES}
 
 
 def clear() -> None:
@@ -158,10 +171,12 @@ def clear() -> None:
 
 
 def _clear_locked() -> None:
-    global _TOTAL_BYTES
+    global _TOTAL_BYTES, _ASSEMBLED_BYTES
     _FILES.clear()
     _PATH_KEY.clear()
     _TOTAL_BYTES = 0
+    _ASSEMBLED.clear()
+    _ASSEMBLED_BYTES = 0
 
 
 # ---------------------------------------------------------------------------
@@ -304,6 +319,8 @@ def _purge_stale_locked(skey: Tuple) -> None:
     if entry is not None:
         _TOTAL_BYTES -= entry.nbytes
         _INVALIDATIONS += 1
+    for akey in [k for k in _ASSEMBLED if any(s == prev for s, _ in k[0])]:
+        _drop_assembled_locked(akey)
     _PATH_KEY[skey[1]] = skey
 
 
@@ -326,8 +343,15 @@ def _entry_locked(skey: Tuple) -> "_FileEntry":
     return entry
 
 
+def _drop_assembled_locked(akey: Tuple) -> None:
+    global _ASSEMBLED_BYTES
+    _ASSEMBLED_BYTES -= _ASSEMBLED.pop(akey)[1]
+
+
 def _evict_locked() -> None:
     global _TOTAL_BYTES, _EVICTIONS
+    while _ASSEMBLED and _TOTAL_BYTES + _ASSEMBLED_BYTES > _MAX_BYTES:
+        _drop_assembled_locked(next(iter(_ASSEMBLED)))
     while _TOTAL_BYTES > _MAX_BYTES and len(_FILES) > 1:
         old_key, old = _FILES.popitem(last=False)
         _TOTAL_BYTES -= old.nbytes
@@ -379,7 +403,7 @@ def get_footer(path: str, metrics=None) -> FooterInfo:
         with _LOCK:
             entry = _probe_locked(skey)
             if entry is not None and entry.footer is not None:
-                _bump_hits(metrics)
+                count_plan_hits(metrics)
                 return entry.footer
     md = papq.read_metadata(path)
     footer = FooterInfo(path, md, md.schema.to_arrow_schema(),
@@ -415,7 +439,7 @@ def get_chunk_plan(skey: Optional[Tuple], src, rg: int, leaf_idx: int,
             entry = _probe_locked(skey)
             cached = entry.plans.get(pkey) if entry is not None else None
         if cached is not None:
-            _bump_hits(metrics)
+            count_plan_hits(metrics)
             if isinstance(cached, Exception):
                 # fresh instance per raise: the cached one is shared
                 raise type(cached)(*cached.args)
@@ -450,15 +474,78 @@ def get_chunk_plan(skey: Optional[Tuple], src, rg: int, leaf_idx: int,
     return plan
 
 
-def _bump_hits(metrics) -> None:
+def get_assembled(key: Optional[Tuple]):
+    """The uploaded upload set of one scan batch, where an earlier scan
+    left it (``put_assembled``), else None.
+
+    ``key`` is ``(((stamp, row_group), ...), ...)``: the batch's sources
+    under the stamps their footers were parsed under (``handle_key``),
+    then whatever else the set depends on; None (a source with no
+    stamp) is never cached."""
+    if not _ENABLED or key is None:
+        return None
+    with _LOCK:
+        hit = _ASSEMBLED.get(key)
+        if hit is not None:
+            _ASSEMBLED.move_to_end(key)
+    _obsreg.get_registry().inc("scan.assembledCacheHits" if hit is not None
+                               else "scan.assembledCacheMisses")
+    return None if hit is None else hit[0]
+
+
+def put_assembled(key: Optional[Tuple], made, nbytes: int) -> None:
+    """Keep ``made`` (device-resident, ``nbytes`` of HBM) for the next
+    scan of the same batch.  It takes only the room the plans leave in
+    the budget, pushing older sets out; one that does not fit there is
+    not kept.  A set goes with its file's stamp, and all of them under
+    memory pressure (``pressure_spill``)."""
+    global _ASSEMBLED_BYTES, _SPILLER_REGISTERED
+    if key is None:
+        return
+    with _LOCK:
+        if not _SPILLER_REGISTERED:
+            from spark_rapids_tpu.mem import spill
+            spill.register_pressure_spiller(sys.modules[__name__])
+            _SPILLER_REGISTERED = True
+        # a ``configure`` that turned the cache off meanwhile stays
+        # the last word
+        if _ENABLED and key not in _ASSEMBLED and \
+                _TOTAL_BYTES + nbytes <= _MAX_BYTES:
+            _ASSEMBLED[key] = (made, int(nbytes))
+            _ASSEMBLED_BYTES += int(nbytes)
+            _evict_locked()
+
+
+def pressure_spill(bytes_needed: int = 1 << 62) -> int:
+    """``mem/spill``'s pressure hook, this module being the spiller:
+    let go of assembled sets, oldest first, until ``bytes_needed`` of
+    HBM are free of them (the next scan makes them again; one in
+    flight keeps its own reference until its decode is enqueued).
+    Returns the bytes dropped."""
+    freed = 0
+    with _LOCK:
+        while _ASSEMBLED and freed < bytes_needed:
+            akey = next(iter(_ASSEMBLED))
+            freed += _ASSEMBLED[akey][1]
+            _drop_assembled_locked(akey)
+    return freed
+
+
+_SPILLER_REGISTERED = False
+
+
+def count_plan_hits(metrics, n: int = 1) -> None:
+    """``n`` chunk plans served from the cache: one by a lookup, or
+    all the plans an assembled set (``get_assembled``) was made of."""
     global _HITS
     with _LOCK:
-        _HITS += 1
-    _count(metrics, "scan.planCacheHits")
+        _HITS += n
+    if metrics is not None:
+        metrics.add_extra("scan.planCacheHits", n)
     # mirrored into the unified metrics registry: the scan-cache
     # counters were one of the three disjoint stat channels the obs
     # layer folds together (obs/registry.py)
-    _obsreg.get_registry().inc("scan.planCacheHits")
+    _obsreg.get_registry().inc("scan.planCacheHits", n)
 
 
 def _bump_misses(metrics) -> None:

@@ -521,6 +521,9 @@ def hbm_oom_recover(e: BaseException) -> bool:
         return False
     cat = get_catalog()
     freed = cat.spill_to_fit(1 << 62)     # evict the whole device tier
+    # and the scans' uploaded page sets, which the next scan makes again
+    from spark_rapids_tpu.io import scan_cache
+    freed += scan_cache.pressure_spill()
     if freed > 0:
         # the flight recorder bundles a SUCCESSFUL query whose window
         # moved this counter — surviving only by evicting the whole

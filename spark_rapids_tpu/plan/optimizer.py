@@ -1,12 +1,19 @@
-"""Logical optimizations: column pruning.
+"""Logical optimizations: implicit joins to equi-joins with their
+one-sided conjuncts pushed under them, then column pruning.
 
-Reference analog: Spark's ``ColumnPruning`` rule, which the reference
-plugin inherits from Catalyst before ``GpuOverrides`` ever sees the
-plan — scans read only referenced columns.  This engine owns its whole
-stack, so the rule lives here: a top-down required-ordinal analysis over
-the bound logical plan, then a bottom-up rebuild that narrows
-``FileScan``/``InMemoryScan`` leaves and remaps every ancestor's
-``BoundReference`` ordinals through the changed schemas.
+Reference analog: Spark's ``PushPredicateThroughJoin`` and
+``ColumnPruning`` rules, which the reference plugin inherits from
+Catalyst before ``GpuOverrides`` ever sees the plan — a ``FROM a, b
+WHERE a.k = b.k AND a.x = 1`` arrives as an equi-join over a filtered
+``a``, and scans read only referenced columns.  This engine owns its
+whole stack, so both rules live here.
+
+``rewrite_implicit_joins`` works on the bound plan and changes no
+node's output schema, so no ancestor needs a remap.  ``prune_columns``
+is a top-down required-ordinal analysis over the bound logical plan,
+then a bottom-up rebuild that narrows ``FileScan``/``InMemoryScan``
+leaves and remaps every ancestor's ``BoundReference`` ordinals through
+the changed schemas.
 
 Pruning a scan matters twice on TPU: the device parquet decode skips
 whole column chunks (the q6 bench decodes 4 of 6 columns), and
@@ -18,6 +25,7 @@ from __future__ import annotations
 import copy
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.expr import ir
 from spark_rapids_tpu.plan import logical as lp
 from spark_rapids_tpu.plan.logical import Schema
@@ -68,6 +76,156 @@ def _shallow(node, **attrs):
 def _all(node) -> Set[int]:
     return set(range(len(node.schema.names)))
 
+
+# ---------------------------------------------------------------------------
+# implicit joins
+# ---------------------------------------------------------------------------
+
+# re-evaluating one of these under a join would see other rows, other
+# partitions or another draw than above it
+_PUSH_BARRIERS = (ir.MonotonicallyIncreasingID, ir.Rand, ir.PythonUDF,
+                  ir.InputFileName, ir.SparkPartitionID,
+                  ir.AggregateExpression, ir.WindowExpression)
+
+# sides a conjunct over one side alone may go under: the null-supplying
+# side of an outer join keeps its rows until the ON has been applied
+_PUSH_LEFT = ("cross", "inner", "left", "semi", "anti")
+_PUSH_RIGHT = ("cross", "inner", "right")
+
+
+def _conjuncts(e: ir.Expression) -> List[ir.Expression]:
+    """``a AND b AND c`` as ``[a, b, c]``, in the text's order."""
+    if isinstance(e, ir.And):
+        return _conjuncts(e.children[0]) + _conjuncts(e.children[1])
+    return [e]
+
+
+def _conjoin(parts: Sequence[ir.Expression]) -> ir.Expression:
+    out = parts[0]
+    for p in parts[1:]:
+        out = ir.And(out, p)
+        out.resolve()
+    return out
+
+
+def _key_pair(c: ir.Expression, n_l: int, lschema: Schema,
+              rschema: Schema) -> Optional[Tuple[str, str]]:
+    """``(left name, right name)`` where the conjunct is an equality of
+    one bare column of each side that the join can take as a key."""
+    if not isinstance(c, ir.EqualTo):
+        return None
+    a, b = c.children
+    if not (isinstance(a, ir.BoundReference)
+            and isinstance(b, ir.BoundReference)):
+        return None
+    if (a.ordinal < n_l) == (b.ordinal < n_l):
+        return None
+    if a.ordinal >= n_l:
+        a, b = b, a
+    lf = lschema.fields[a.ordinal]
+    rf = rschema.fields[b.ordinal - n_l]
+    # a join key is found by name on its side; float keys are left
+    # to the filter (the join normalizes NaN and -0.0, ``=`` need not)
+    if lschema.names.count(lf.name) != 1 or \
+            rschema.names.count(rf.name) != 1:
+        return None
+    for d in (lf.dtype, rf.dtype):
+        if d.is_nested or d.is_floating:
+            return None
+    if lf.dtype != rf.dtype and not (lf.dtype.is_numeric
+                                     and rf.dtype.is_numeric):
+        return None
+    return lf.name, rf.name
+
+
+def _push_through_join(f: lp.Filter, counts: List[int]
+                       ) -> Optional[lp.LogicalPlan]:
+    """``Filter(Join)`` with every conjunct the join allows moved into
+    or under the join; ``None`` where none can move."""
+    join = f.children[0]
+    left, right = join.children
+    n_l = len(left.schema.names)
+    semi = join.how in ("semi", "anti")
+    to_left, to_right, keys, keep = [], [], [], []
+    for c in _conjuncts(f.condition):
+        refs = _refs([c])
+        if not refs or ir.collect(
+                c, lambda n: isinstance(n, _PUSH_BARRIERS)):
+            keep.append(c)
+        elif semi or all(o < n_l for o in refs):
+            (to_left if join.how in _PUSH_LEFT else keep).append(c)
+        elif all(o >= n_l for o in refs):
+            (to_right if join.how in _PUSH_RIGHT else keep).append(c)
+        else:
+            pair = _key_pair(c, n_l, left.schema, right.schema) \
+                if join.how in ("cross", "inner") else None
+            (keys if pair else keep).append(pair or c)
+    if not (to_left or to_right or keys):
+        return None
+    if to_left:
+        left = lp.Filter(left, _conjoin(to_left))
+    if to_right:
+        shift = {o: o - n_l for o in range(n_l, len(join.schema.names))}
+        right = lp.Filter(right, _conjoin(_remap_all(to_right, shift)))
+    new = _shallow(join, children=(left, right))
+    if keys:
+        if join.how == "cross" or not join.left_keys:
+            counts[0] += 1
+        new.how = "inner"
+        new.left_keys = join.left_keys + [lk for lk, _ in keys]
+        new.right_keys = join.right_keys + [rk for _, rk in keys]
+        new.key_dtypes = list(join.key_dtypes)
+        for lk, rk in keys:
+            ld = left.schema.field(lk).dtype
+            rd = right.schema.field(rk).dtype
+            new.key_dtypes.append(ld if ld == rd else
+                                  dt.promote(ld, rd))
+    counts[1] += len(to_left) + len(to_right)
+    return lp.Filter(new, _conjoin(keep)) if keep else new
+
+
+def _rewrite_joins(node: lp.LogicalPlan, counts: List[int]
+                   ) -> lp.LogicalPlan:
+    while isinstance(node, lp.Filter) and \
+            isinstance(node.children[0], lp.Join):
+        pushed = _push_through_join(node, counts)
+        if pushed is None:
+            break
+        node = pushed
+    children = tuple(_rewrite_joins(c, counts) for c in node.children)
+    if all(n is o for n, o in zip(children, node.children)):
+        return node
+    return _shallow(node, children=children)
+
+
+def rewrite_implicit_joins(plan: lp.LogicalPlan) -> lp.LogicalPlan:
+    """The ``WHERE`` of an implicit join becomes the joins' keys.
+
+    A ``Filter`` over a ``Join`` is split into its conjuncts.  An
+    equality between a bare column of one side and a bare column of
+    the other becomes a key of that join (``cross``, or an ``inner``
+    with or without keys, ends as ``inner``); a conjunct over one side
+    alone goes under the join on that side, where the same rule meets
+    it again if that side is a join, and a scan's own filter push if it
+    is a scan; what is left stays above.  An outer join passes a
+    conjunct only to its preserved side, a semi or anti join to its
+    left.  Joins keep the order the text gives them.  Counted under
+    ``plan.rewrite.implicitJoins`` (joins that went from a product to
+    keys) and ``plan.rewrite.pushedConjuncts`` (conjuncts moved under
+    a join, once a join passed)."""
+    counts = [0, 0]
+    new = _rewrite_joins(plan, counts)
+    if counts[0] or counts[1]:
+        from spark_rapids_tpu.obs import registry as obsreg
+        obsreg.get_registry().inc_many(
+            ("plan.rewrite.implicitJoins", counts[0]),
+            ("plan.rewrite.pushedConjuncts", counts[1]))
+    return new
+
+
+# ---------------------------------------------------------------------------
+# column pruning
+# ---------------------------------------------------------------------------
 
 def prune_columns(plan: lp.LogicalPlan) -> lp.LogicalPlan:
     """Return an equivalent plan whose scans read only needed columns."""

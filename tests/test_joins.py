@@ -113,3 +113,108 @@ def test_string_key_join():
              .select(col("j").alias("k"), "rv"))
         return l.join(r, on="k", how="inner")
     assert_tpu_and_cpu_are_equal_collect(q, ignore_order=True)
+
+
+# -- path choice: the keys' range, not their magnitude --------------------
+
+def _range_join(session, first_key: int, span: int, dim_rows: int,
+                how: str, seed: int):
+    """A dimension of ``dim_rows`` distinct keys inside ``[first_key,
+    first_key + span)`` joined to 4,000 fact rows (half of their keys
+    the dimension's, the others anywhere in its range or a tenth of
+    the span below it, a tenth of all null), against pandas."""
+    import numpy as np
+    import pandas as pd
+    import pyarrow as pa
+    rng = np.random.default_rng(seed)
+    dk = np.sort(rng.choice(span, dim_rows, replace=False)) + first_key
+    dk[[0, -1]] = first_key, first_key + span - 1
+    dim = pa.table({"dk": dk.astype(np.int32),
+                    "dv": np.arange(dim_rows, dtype=np.int32)})
+    fk = np.where(rng.random(4000) < 0.5, rng.choice(dk, 4000),
+                  rng.integers(first_key - span // 10, first_key + span,
+                               4000))
+    fact = pa.table({
+        "fk": pa.array(fk.astype(np.int32), mask=rng.random(4000) < 0.1),
+        "fv": np.arange(4000, dtype=np.int32)})
+    got = (session.create_dataframe(fact)
+           .join(session.create_dataframe(dim), on=col("fk") == col("dk"),
+                 how=how).collect().to_pandas())
+    f, d = fact.to_pandas(), dim.to_pandas()
+    if how == "semi":
+        want = f[f.fk.isin(d.dk)]
+    elif how == "anti":
+        want = f[~f.fk.isin(d.dk)]
+    else:
+        want = f.merge(d, left_on="fk", right_on="dk", how=how)
+        if how == "left":       # pandas matches null keys to nothing too
+            assert want.dv.isna().sum() > 0
+    by = ["fv"]
+    pd.testing.assert_frame_equal(
+        got.sort_values(by).reset_index(drop=True),
+        want.sort_values(by).reset_index(drop=True), check_dtype=False)
+    return len(want)
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
+@pytest.mark.parametrize("case,first_key,span,dim_rows,path", [
+    ("julian", 2_415_022, 73_049, 73_049, "direct"),
+    ("from-1", 1, 73_049, 73_049, "direct"),
+    ("sparse", 2_415_022, 73_049, 600, "direct"),
+    ("past-4M", 1, 5_000_000, 600, "sortMerge"),
+])
+def test_join_path_is_chosen_by_key_range(session, how, case, first_key,
+                                          span, dim_rows, path):
+    from spark_rapids_tpu.obs import registry
+    if case == "from-1":
+        # the same span from another base, first: what the second
+        # base could mint, were the base part of the program
+        _range_join(session, 2_415_022, span, dim_rows, how, seed=7)
+    view = registry.get_registry().view()
+    assert _range_join(session, first_key, span, dim_rows, how, seed=7)
+    moved = view.delta()["counters"]
+    other = "sortMerge" if path == "direct" else "direct"
+    assert moved.get(f"join.path.{path}", 0) == 1
+    assert moved.get(f"join.path.{other}", 0) == 0
+    assert moved.get("join.rangeReads", 0) == 1
+    if case == "from-1":
+        assert moved.get("kernel.cache.compiles", 0) == 0
+        assert moved.get("kernel.cache.persistentHits", 0) == 0
+    if path == "direct":
+        assert registry.get_registry().gauge("join.table.entries") \
+            >= span
+
+
+def test_join_path_composite_keys_by_range(session):
+    """Two keys whose ranges multiply to fewer entries than the table
+    holds take the direct path; their value hints alone (two 32-bit
+    buckets) never would."""
+    import numpy as np
+    import pandas as pd
+    import pyarrow as pa
+    from spark_rapids_tpu.obs import registry
+    rng = np.random.default_rng(11)
+    # years far from zero, so that no 16-bit hint bounds the pair
+    dim = pa.table({"y": (np.repeat(np.arange(1998, 2003), 12) + 100_000)
+                    .astype(np.int32),
+                    "m": np.tile(np.arange(1, 13), 5).astype(np.int32),
+                    "dv": np.arange(60, dtype=np.int32)})
+    fact = pa.table({
+        "fy": pa.array((rng.integers(1996, 2005, 3000) + 100_000)
+                       .astype(np.int32), mask=rng.random(3000) < 0.05),
+        "fm": rng.integers(0, 14, 3000).astype(np.int32),
+        "fv": np.arange(3000, dtype=np.int32)})
+    view = registry.get_registry().view()
+    got = (session.create_dataframe(fact)
+           .join(session.create_dataframe(dim),
+                 on=(col("fy") == col("y")) & (col("fm") == col("m")))
+           .collect().to_pandas())
+    moved = view.delta()["counters"]
+    assert moved.get("join.path.direct", 0) == 1
+    assert moved.get("join.path.sortMerge", 0) == 0
+    want = fact.to_pandas().dropna(subset=["fy"]).merge(
+        dim.to_pandas(), left_on=["fy", "fm"], right_on=["y", "m"])
+    assert len(want) > 0
+    pd.testing.assert_frame_equal(
+        got.sort_values("fv").reset_index(drop=True),
+        want.sort_values("fv").reset_index(drop=True), check_dtype=False)

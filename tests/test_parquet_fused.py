@@ -82,3 +82,45 @@ def test_fused_fallback_column_missing_from_one_file(tmp_path):
     assert got.column("x").to_pylist() == list(range(1000))
     assert got.column("s").to_pylist() == \
         [f"v{i}" for i in range(600)] + [None] * 400
+
+
+def test_assemble_keeps_a_streams_layout_with_its_cached_plan(tmp_path):
+    """The per-run part of the fused plan is made once a cached chunk
+    plan: a second scan of the same file finds every stream's layout
+    on its run table, uploads the same arrays and decodes the same
+    rows.  One stream here holds several bit widths (a dictionary that
+    grows page after page), RLE stretches between bit-packed ones, and
+    nulls."""
+    from spark_rapids_tpu.io import parquet_fused as pqf
+    from spark_rapids_tpu.io import scan_cache
+    rng = np.random.default_rng(3)
+    n = 40_000
+    k = np.where((np.arange(n) // 500) % 2 == 0,
+                 np.repeat(rng.integers(0, 5, n // 100), 100),
+                 rng.integers(0, 1 + np.arange(n) // 40, n))
+    t = pa.table({"k": pa.array(k.astype(np.int32),
+                                mask=rng.random(n) < 0.05)})
+    path, _ = _write(tmp_path, "w.parquet", t, data_page_size=4096,
+                     row_group_size=n)
+    schema = Schema.from_arrow(t.schema)
+    scan_cache.configure(True, 256 << 20)
+    scan_cache.clear()
+    # the stamped handle a session's scan holds: its plans are cached
+    src, dtypes = [(scan_cache.open_source(path), path, 0)], \
+        [schema.field("k").dtype]
+    plans, fallbacks, _ = pqf._collect_plans(src, schema, ["k"], 1)
+    assert fallbacks == []
+    first = pqf.assemble(plans, dtypes, ["k"], [n])
+    lay = plans[0][0].val_runs.layout
+    assert len({w for w, _, _ in lay.slices}) > 1
+    assert (lay.c != 0).any() and (lay.c == 0).any()
+    again, _, _ = pqf._collect_plans(src, schema, ["k"], 1)
+    second = pqf.assemble(again, dtypes, ["k"], [n])
+    assert again[0][0].val_runs.layout is lay
+    assert first.key == second.key
+    for name, arr in first.arrays.items():
+        assert np.array_equal(arr, second.arrays[name]), name
+    batch, fallbacks = decode_row_groups_fused(src, schema)
+    assert fallbacks == []
+    got = to_arrow(batch)
+    assert_tables_equal(got, t.cast(got.schema))

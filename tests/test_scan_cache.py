@@ -313,3 +313,109 @@ def test_footer_dedup_schema_inference_then_scan(tmp_path):
     f = sc.get_footer(p)                   # scan-side lookup: a hit
     assert sc.stats()["hits"] > h0
     assert f.schema_arrow.names == t.schema.names
+
+
+def test_assembled_set_uploaded_once_and_dropped_with_its_stamp(tmp_path):
+    from spark_rapids_tpu.io import parquet_fused as pqf
+    from spark_rapids_tpu.obs import registry as obsreg
+    t = _table(seed=30)
+    p = _write(tmp_path, "a.parquet", t, row_group_size=1024)
+    schema = Schema.from_arrow(t.schema)
+    calls = []
+    orig = pqf.assemble
+    pqf.assemble = lambda *a, **k: calls.append(1) or orig(*a, **k)
+    try:
+        view = obsreg.get_registry().view()
+        b1, _ = decode_row_groups_fused(_sources(p), schema)
+        b2, _ = decode_row_groups_fused(_sources(p), schema)
+        moved = dict(view.delta()["counters"])
+        assert len(calls) == 1
+        assert moved["scan.assembledCacheMisses"] == 1
+        assert moved["scan.assembledCacheHits"] == 1
+        st = sc.stats()
+        assert st["assembled"] == 1
+        # what was uploaded once is what is held, and nothing more was
+        assert moved["scan.bytesUploaded"] == st["assembled_bytes"] > 0
+        assert st["bytes"] + st["assembled_bytes"] <= 256 << 20
+        assert_tables_equal(to_arrow(b2), to_arrow(b1))
+        # another column set is another batch
+        decode_row_groups_fused(_sources(p), schema, columns=["k", "s"])
+        assert len(calls) == 2 and sc.stats()["assembled"] == 2
+
+        # the file rewritten: both sets of the old stamp go with it
+        t_new = _table(n=2100, seed=31)
+        papq.write_table(t_new, p, row_group_size=1024)
+        st_ = os.stat(p)
+        os.utime(p, ns=(st_.st_atime_ns, st_.st_mtime_ns + 1_000_000))
+        b3, _ = decode_row_groups_fused(_sources(p), schema)
+        assert len(calls) == 3 and sc.stats()["assembled"] == 1
+        got = to_arrow(b3)
+        assert_tables_equal(got, t_new.cast(got.schema))
+    finally:
+        pqf.assemble = orig
+
+
+def test_assembled_sets_go_under_memory_pressure(tmp_path):
+    from spark_rapids_tpu.mem import spill
+    t = _table(seed=32)
+    p = _write(tmp_path, "a.parquet", t, row_group_size=1024)
+    schema = Schema.from_arrow(t.schema)
+    b1, _ = decode_row_groups_fused(_sources(p), schema)
+    held = sc.stats()["assembled_bytes"]
+    assert held > 0
+    # the admission-pressure hook and the OOM retry both reach them
+    assert spill._aux_pressure_spill(1 << 62) >= held
+    assert sc.stats()["assembled"] == 0
+    decode_row_groups_fused(_sources(p), schema)
+    assert sc.stats()["assembled"] == 1
+    assert spill.hbm_oom_recover(RuntimeError("RESOURCE_EXHAUSTED: x"))
+    assert sc.stats()["assembled"] == 0
+    b2, _ = decode_row_groups_fused(_sources(p), schema)
+    assert_tables_equal(to_arrow(b2), to_arrow(b1))
+
+
+def test_assembled_sets_take_only_what_the_plans_leave(tmp_path):
+    paths = [_write(tmp_path, f"f{i}.parquet", _table(seed=40 + i),
+                    row_group_size=1024) for i in range(2)]
+    schema = Schema.from_arrow(_table().schema)
+    decode_row_groups_fused(_sources(paths[0]), schema)
+    st = sc.stats()
+    plans, packed = st["bytes"], st["assembled_bytes"]
+    assert plans > 0 and packed > 0
+    evicted = st["evictions"]
+
+    # room for both files' plans and one assembled set: the older set
+    # goes, no plan does
+    sc.clear()
+    sc.configure(True, 2 * plans + packed + packed // 2)
+    for p in paths:
+        decode_row_groups_fused(_sources(p), schema)
+    st = sc.stats()
+    assert st["entries"] == 2 and st["evictions"] == evicted
+    assert st["assembled"] == 1
+    walks = pm.walk_count()
+    decode_row_groups_fused(_sources(paths[0]), schema)
+    assert pm.walk_count() == walks
+
+    # no room beside the plans: nothing assembled is kept
+    sc.clear()
+    sc.configure(True, plans + packed // 2)
+    decode_row_groups_fused(_sources(paths[0]), schema)
+    st = sc.stats()
+    assert st["assembled"] == 0 and st["entries"] == 1
+    assert st["evictions"] == evicted
+
+
+def test_assembled_set_not_cached_without_a_stamp(tmp_path):
+    t = _table(seed=50)
+    p = _write(tmp_path, "a.parquet", t, row_group_size=1024)
+    schema = Schema.from_arrow(t.schema)
+    pf = papq.ParquetFile(p)      # a plain handle pins no stamp
+    try:
+        srcs = [(pf, p, rg) for rg in range(pf.metadata.num_row_groups)]
+        b, _ = decode_row_groups_fused(srcs, schema)
+        assert sc.stats()["assembled"] == 0
+        got = to_arrow(b)
+        assert_tables_equal(got, t.cast(got.schema))
+    finally:
+        pf.close()
