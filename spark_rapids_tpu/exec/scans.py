@@ -20,6 +20,21 @@ blocks whose body runs ONE block-sized ``associative_scan`` and
 carries the running prefix — 4M int64 compiles in ~1.5 s and scoped
 VMEM stays ~block-sized.
 
+What is blocked is the recursion's DEPTH (log2(_BLOCK) levels whatever
+the capacity); the blocks' ORDER is kept, because a float result
+depends on the left fold of the carries.  Measured since (PERF.md §6,
+PR 29; one v5e chip, and the TPU compiler run for a described v5e):
+the ~1.5 s still holds (4M int64 1.4 s, 4M float64 5.3 s); inside
+TPC-H Q1's aggregate update a 4M-row float64 ``seg_scan`` is one
+``while`` of 128 trips and takes 24 ms (190 us a trip for the 371
+operations of its body), so the five such loops are 3.9% of a program
+whose time is its full-capacity gathers — the loop is not worth
+unrolling for speed.  Running every block's recursion at once gives
+the same bits, but laid out ``[_BLOCK, g]`` (one ``associative_scan``
+along axis 0) the compiler did not finish one 4M-row scan in 40
+minutes; laid out ``[g, _BLOCK]`` (along axis 1) the float64 scan took
+4.7 s and the int64 one had not finished after 14 minutes.
+
 Reference analog: none needed — cudf's prefix scans run on a GPU whose
 scratch is not a compile-time-bounded scoped space; this module is the
 TPU formulation of the same segmented-reduction building block.

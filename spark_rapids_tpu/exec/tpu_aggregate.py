@@ -21,6 +21,7 @@ Spark's NormalizeFloatingNumbers semantics (parity-critical).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -41,8 +42,8 @@ from spark_rapids_tpu.plan.logical import Schema
 _BIG = np.int64(1 << 62)
 
 # capacity ladder engages only when cap/4 reaches this rung size: below
-# it the second lax.cond branch's compile time would dominate
-# small-batch suites (tests may lower it to cover both branches)
+# it the extra branches' compile time would dominate small-batch suites
+# (tests may lower it to cover every branch)
 _LADDER_MIN_RUNG = 1 << 18
 
 
@@ -665,32 +666,39 @@ def _pad_batch(batch: DeviceBatch, cap: int) -> DeviceBatch:
     return DeviceBatch(batch.names, cols, batch.num_rows)
 
 
-def _laddered(batch: DeviceBatch, fn):
-    """Capacity ladder: when the batch's live rows fit in cap/4 (the
-    common case after a selective filter), run the whole aggregation at
-    that statically smaller shape — every sort pass, gather and scan
-    scales with capacity, not live rows.  Host-known row counts pick
-    the rung in Python; traced counts pick via one lax.cond (both
-    branches compile once, outputs padded back to cap)."""
-    cap = batch.capacity
-    rung = cap // 4
-    # engage only at real-workload scale: the second branch doubles the
-    # kernel's compile time, which would dominate small-batch suites
-    if rung < _LADDER_MIN_RUNG:
-        return fn(batch)
-    nr = batch.num_rows
+def _on_ladder(cap: int, nr, at):
+    """Capacity ladder: run ``at(cap2)`` at the lowest of cap/4, cap/2
+    and cap that holds the ``nr`` live rows — every sort pass, gather
+    and scan scales with capacity, not live rows, and capacity tiers
+    stand 4x apart, so a batch just over a tier (1.5M rows at 4,194,304)
+    or behind a selective filter carries mostly padding.  ``at`` pads
+    its outputs back to ``cap``.  Host-known row counts pick the rung
+    in Python; traced counts pick via one lax.switch (every branch
+    compiles once; safe since exec/scans.py keeps 64-bit scans out of
+    the pathological in-control-flow cumsum lowering)."""
+    # engage only at real-workload scale: each further branch adds the
+    # kernel's compile time again, which would dominate small-batch
+    # suites
+    if cap // 4 < _LADDER_MIN_RUNG:
+        return at(cap)
+    rungs = (cap // 4, cap // 2, cap)
     if isinstance(nr, (int, np.integer)):
-        if int(nr) <= rung:
-            return _pad_batch(fn(_slice_batch(batch, rung)), cap)
-        return fn(batch)
-    # traced counts pick via one lax.cond: both branches compile once
-    # (safe since exec/scans.py keeps 64-bit scans out of the
-    # pathological in-control-flow cumsum lowering), outputs pad back
-    # to cap
-    return jax.lax.cond(
-        nr <= rung,
-        lambda: _pad_batch(fn(_slice_batch(batch, rung)), cap),
-        lambda: fn(batch))
+        return at(next(r for r in rungs if int(nr) <= r))
+    over = sum((nr > r).astype(jnp.int32) for r in rungs[:-1])
+    return jax.lax.switch(over, [functools.partial(at, r) for r in rungs])
+
+
+def _laddered(batch: DeviceBatch, fn):
+    """``fn`` over the batch cut to its rung of the capacity ladder
+    (live rows are prefix-dense), outputs padded back to capacity."""
+    cap = batch.capacity
+
+    def at(cap2: int) -> DeviceBatch:
+        if cap2 == cap:
+            return fn(batch)
+        return _pad_batch(fn(_slice_batch(batch, cap2)), cap)
+
+    return _on_ladder(cap, batch.num_rows, at)
 
 
 def _gather_val(v: ColVal, sel: jnp.ndarray,
@@ -795,20 +803,11 @@ def update_aggregate(batch: DeviceBatch,
         live = jnp.arange(cap2) < n_rows
         return [_gather_val(v, s, live) for v in key_vals], s
 
-    rung = cap // 4
-    if rung < _LADDER_MIN_RUNG:
-        kv, s = gather_keys(cap)
-        return run(kv, agg_vals, cap, n_rows, s, keep)
+    def at(cap2: int) -> DeviceBatch:
+        kv, s = gather_keys(cap2)
+        return _pad_batch(run(kv, agg_vals, cap2, n_rows, s, keep), cap)
 
-    def small():
-        kv, s = gather_keys(rung)
-        return _pad_batch(run(kv, agg_vals, rung, n_rows, s, keep), cap)
-
-    def big():
-        kv, s = gather_keys(cap)
-        return run(kv, agg_vals, cap, n_rows, s, keep)
-
-    return jax.lax.cond(n_rows <= rung, small, big)
+    return _on_ladder(cap, n_rows, at)
 
 
 def merge_aggregate(batch: DeviceBatch, n_keys: int,

@@ -2,6 +2,7 @@
 aggregate/sort/limit (SURVEY.md §7 phases 2-3 milestone tests)."""
 
 import pyarrow as pa
+import pytest
 from spark_rapids_tpu import TpuSparkSession, col, lit, functions as F
 from tests.parity import (assert_tpu_and_cpu_are_equal_collect,
                           assert_tables_equal)
@@ -224,35 +225,41 @@ def test_filter_fuses_into_aggregate():
     assert filters2
 
 
-def test_fused_filter_ladder_both_branches(monkeypatch):
-    """Cover BOTH lax.cond ladder branches of the fused-filter
+@pytest.mark.parametrize("thresh,over,rung",
+                         [(7, 0, 128), (1, 128, 256), (-10, 256, 512)],
+                         ids=["quarter", "half", "full"])
+def test_fused_filter_ladder_every_branch(monkeypatch, thresh, over, rung):
+    """Cover EVERY lax.switch ladder branch of the fused-filter
     permutation compact at suite scale by lowering the engagement
     threshold (normally only the 4M-row bench reaches it)."""
     import numpy as np
+    from spark_rapids_tpu.exec import kernel_cache as kc
     from spark_rapids_tpu.exec import tpu_aggregate as agg
     from tests.parity import assert_tables_equal, with_cpu_session
     from spark_rapids_tpu import TpuSparkSession, col, functions as F
 
     monkeypatch.setattr(agg, "_LADDER_MIN_RUNG", 8)
+    kc.clear()      # no program traced under the default threshold
     rng = np.random.default_rng(33)
-    n = 512  # cap 512, rung 128
+    n = 512  # cap 512, rungs 128 and 256
     t = pa.table({
         "k": pa.array(rng.integers(0, 7, n), type=pa.int64()),
         "v": pa.array(rng.integers(-9, 9, n), type=pa.int64()),
     })
+    # selective -> cap/4; 7 values of 18 -> cap/2; all -> cap
+    assert over < int((t["v"].to_numpy() > thresh).sum()) <= rung
 
-    def q(s, thresh):
+    def q(s):
         df = s.create_dataframe(t)
         return df.filter(col("v") > thresh).group_by("k").agg(
             F.count("*").alias("c"), F.sum("v").alias("sv"),
             F.max("v").alias("mx"))
 
-    for thresh in (7, -10):   # selective -> small branch; all -> big
-        cpu = with_cpu_session(lambda s: q(s, thresh).collect())
-        got = TpuSparkSession(
-            {"spark.rapids.tpu.sql.variableFloatAgg.enabled": True})
-        out = q(got, thresh).collect()
-        assert_tables_equal(cpu, out, ignore_order=True)
+    cpu = with_cpu_session(lambda s: q(s).collect())
+    got = TpuSparkSession(
+        {"spark.rapids.tpu.sql.variableFloatAgg.enabled": True})
+    assert_tables_equal(cpu, q(got).collect(), ignore_order=True)
+    kc.clear()
 
 
 def test_rollup_subtotals():
