@@ -30,7 +30,7 @@ SF10_ROWS = 28_800_000      # TPC-DS SF10 store_sales
 SF1_ROWS = 2_880_000        # TPC-DS SF1 store_sales (--chips 4)
 ROWS_PER_FILE = 1_800_000   # one scan batch (reader.batchSizeRows 2^21)
 DICT_COLUMNS = ["ss_sold_date_sk", "ss_item_sk", "ss_quantity"]
-RTOL = 1e-9                 # float aggregates (bench.py's parity gate)
+RTOL = 1e-9                 # float aggregates (the benchmark's limit)
 
 Q6_SQL = ("select ss_item_sk, count(*) as cnt, sum(ss_quantity) as qty, "
           "avg(ss_ext_sales_price) as aesp from store_sales "
@@ -86,16 +86,32 @@ def device_info() -> dict:
 # data
 # ---------------------------------------------------------------------------
 
+def _gen_store_sales(n: int, seed: int):
+    """q6-class fact slice: sold date fk, item fk, price, qty."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(seed)
+    return pa.table({
+        "ss_sold_date_sk": pa.array(
+            rng.integers(1, 1827, n).astype(np.int64)),
+        "ss_item_sk": pa.array(
+            rng.integers(1, 18001, n).astype(np.int64)),
+        "ss_quantity": pa.array(rng.integers(1, 101, n).astype(np.int32)),
+        "ss_list_price": np.round(rng.uniform(1.0, 200.0, n), 2),
+        "ss_sales_price": np.round(rng.uniform(0.2, 200.0, n), 2),
+        "ss_ext_sales_price": np.round(rng.uniform(1.0, 20000.0, n), 2),
+    })
+
+
 def make_data(root: str, rows: int, seed: int) -> dict:
-    """The ``store_sales`` slice ``bench.py`` generates (six columns,
-    dictionary-encoded keys) in files of one scan batch each, plus
+    """A ``store_sales`` slice (six columns, dictionary-encoded keys)
+    in files of one scan batch each, plus
     ``item`` and ``date_dim`` from the TPC-DS generator sized to cover
     the fact's key ranges (18,000 items, 1,826 days)."""
     from concurrent.futures import ThreadPoolExecutor
 
     import pyarrow.parquet as papq
 
-    import bench
     from spark_rapids_tpu.bench import tpcds
 
     files = max(1, rows // ROWS_PER_FILE)
@@ -106,7 +122,7 @@ def make_data(root: str, rows: int, seed: int) -> dict:
 
     def write(i: int) -> int:
         path = os.path.join(root, "store_sales", f"part-{i:04d}.parquet")
-        papq.write_table(bench._gen_store_sales(sizes[i], seed + 1 + i),
+        papq.write_table(_gen_store_sales(sizes[i], seed + 1 + i),
                          path, use_dictionary=DICT_COLUMNS)
         return os.path.getsize(path)
 
@@ -267,7 +283,6 @@ def serve_phase(root: str) -> None:
     spark = start_session(root)
     say(phase="session",
         shuffle_transport=spark.conf.get(cfg.SHUFFLE_TRANSPORT),
-        kernel_backend=spark.conf.get(cfg.KERNEL_BACKEND),
         host_arena="native/arena.cpp" if host_arena.native_available()
         else "python shim")
     client = ServeClient("127.0.0.1", spark.serve_server.port)
@@ -322,11 +337,10 @@ def donation_reload_check() -> None:
 
 
 def observations() -> None:
-    """Process totals after the statements: compile tiers, the
-    kernel.backend.* selection counters, peak device memory."""
+    """Process totals after the statements: compile tiers, peak
+    device memory."""
     import jax
 
-    from spark_rapids_tpu.kernels import backend as kb
     from spark_rapids_tpu.obs import registry as obsreg
     c = obsreg.get_registry().snapshot()["counters"]
     say(phase="compile_totals",
@@ -336,7 +350,6 @@ def observations() -> None:
         persistent=int(c.get("kernel.cache.persistentHits", 0)),
         compile_wall_s=c.get("kernel.compile.wallNs", 0) / 1e9,
         kernel_dispatches=int(c.get("kernel.dispatches", 0)))
-    say(phase="kernel_backend_selection", **kb.selection_snapshot())
     for d in jax.devices():
         stats = d.memory_stats() or {}
         say(phase="device_memory", device=d.id,

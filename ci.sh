@@ -5,9 +5,9 @@ set -euo pipefail
 cd "$(dirname "$0")"
 
 echo "== lint (syntax + import sanity) =="
-python -m compileall -q spark_rapids_tpu tests bench.py __graft_entry__.py
+python -m compileall -q spark_rapids_tpu tests __graft_entry__.py
 if python -c "import pyflakes" 2>/dev/null; then
-    python -m pyflakes spark_rapids_tpu tests bench.py __graft_entry__.py \
+    python -m pyflakes spark_rapids_tpu tests __graft_entry__.py \
         || exit 1
 fi
 
@@ -74,158 +74,6 @@ assert fused_t.equals(plain_t), (
 assert fused_d < plain_d, (
     f"fusion saved no dispatches ({fused_d} vs {plain_d})")
 print(f"fusion parity OK; dispatches {plain_d} -> {fused_d}")
-EOF
-
-echo "== kernel-backend parity (explicit pallas bit-identical to the no-conf xla default) =="
-timeout 300 python - <<'EOF'
-# the XLA composed-array-op paths are the Pallas kernels' correctness
-# oracle (the sql.fusion.enabled pattern): one real q6-class query —
-# dict-encoded parquet scan -> filter -> grouped aggregate — runs under
-# a session with NO backend conf at all (the default must resolve to
-# xla, the path the TPU compiler accepts) AND an explicit
-# kernel.backend=pallas session and must be BIT-IDENTICAL.  On CPU the
-# Pallas kernels execute under interpret=True (real kernel bodies, not
-# a skip), and the registry must show actual pallas selections: a
-# silently-all-fallback run would make this gate vacuous.
-import os, tempfile
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import numpy as np
-import pyarrow as pa, pyarrow.parquet as papq
-from spark_rapids_tpu import TpuSparkSession, col, functions as F
-from spark_rapids_tpu.kernels import backend as kbk
-from spark_rapids_tpu.obs import registry as obsreg
-
-root = tempfile.mkdtemp(prefix="kernel_parity_")
-n = 8000
-rng = np.random.default_rng(23)
-papq.write_table(pa.table({
-    "k": pa.array(rng.integers(1, 40, n).astype(np.int64)),
-    "q": pa.array(rng.integers(1, 101, n).astype(np.int32)),
-    "p": np.round(rng.uniform(0.2, 200.0, n), 2)}),
-    os.path.join(root, "t.parquet"),
-    use_dictionary=["k", "q"], data_page_size=8192)
-
-def run(backend):
-    conf = {"spark.rapids.tpu.sql.variableFloatAgg.enabled": True}
-    if backend is not None:
-        conf["spark.rapids.tpu.kernel.backend"] = backend
-    s = TpuSparkSession(conf)
-    view = obsreg.get_registry().view()
-    out = (s.read.parquet(root)
-           .filter(col("p") > 150.0)
-           .group_by("k")
-           .agg(F.count("*").alias("cnt"), F.sum("q").alias("qty"),
-                F.avg("p").alias("ap"))
-           .sort("k")).collect()
-    return out, view.delta()["counters"]
-
-xla_t, _ = run(None)          # NO backend conf: the xla default
-assert kbk.default_backend() == "xla", (
-    f"fresh no-conf session resolved {kbk.default_backend()!r}, "
-    "expected the 'xla' default")
-pal_t, d = run("pallas")
-assert xla_t.equals(pal_t), (
-    "pallas diverges from the default xla oracle:\n"
-    f"xla={xla_t.to_pydict()}\npallas={pal_t.to_pydict()}")
-hits = d.get("kernel.backend.pallas.hits", 0)
-assert hits > 0, f"no pallas kernel selected — gate is vacuous: {d}"
-agg_pallas = d.get("kernel.dispatches.agg_update.pallas", 0)
-assert agg_pallas > 0, f"aggregate never dispatched on pallas: {d}"
-fams = {k for k in d if k.startswith("kernel.backend.pallas.hits.")}
-print(f"kernel backend parity OK: bit-identical, {int(hits)} "
-      f"pallas selections across {len(fams)} families, "
-      f"{int(agg_pallas)} pallas agg dispatches")
-EOF
-
-echo "== streamed-kernel large-buffer parity (probes past the old 64 MiB residency gates) =="
-timeout 580 python - <<'EOF'
-# PR 14 retired the whole-buffer VMEM residency gates (decode
-# dense_too_large 64 MiB / segreduce src_too_large 64 MiB /
-# filter-decode dict_too_large 16 MiB) in favor of HBM->VMEM tile
-# streaming.  This gate EXECUTES a decode probe whose dense-value
-# buffer (128 MiB) and a segreduce probe whose source (64.25 MiB) both
-# exceed the old gates: they must run on the Pallas path (hits
-# counted, ZERO size-reason fallbacks — the reasons no longer exist)
-# and diff bit-identical against the XLA oracle.
-import os, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import numpy as np
-import jax.numpy as jnp
-from spark_rapids_tpu import TpuSparkSession
-from spark_rapids_tpu.exec import scans
-from spark_rapids_tpu.io.device_parquet import RunTable
-from spark_rapids_tpu.kernels import backend as kb
-from spark_rapids_tpu.kernels import decode as kdec
-from spark_rapids_tpu.kernels import segreduce as kseg
-from spark_rapids_tpu.obs import registry as obsreg
-
-# tierStride 1 keeps the decode dense cap at the legacy pow2 ladder
-# (2^25 -> 128 MiB) instead of the default stride-2 jump to 256 MiB,
-# which the CPU interpreter cannot stream in CI time
-TpuSparkSession({"spark.rapids.tpu.kernel.abi.tierStride": 1})
-view = obsreg.get_registry().view()
-rng = np.random.default_rng(7)
-
-# -- segreduce probe: 64.25 MiB f64 source, blocked float carry ------
-cap = (1 << 23) + (1 << 15)
-order = jnp.asarray(rng.permutation(cap).astype(np.int32))
-flags = np.zeros(cap, bool); flags[0] = True
-flags[rng.integers(0, cap, 1000)] = True
-vals = jnp.asarray(rng.uniform(-1e6, 1e6, cap))
-with kb.tile_bytes_override(16 << 20):
-    t0 = time.time()
-    got = np.asarray(kseg.gather_seg_scan(
-        vals, order, jnp.asarray(flags), "add", 0.0))
-    seg_s = time.time() - t0
-ref = np.asarray(scans.seg_scan(
-    jnp.add, jnp.asarray(flags), jnp.take(vals, order), 0.0))
-assert np.array_equal(ref, got), "segreduce large-buffer parity FAILED"
-del vals, order, ref, got
-
-# -- decode probe: >16M packed values -> 128 MiB dense buffer --------
-# w=16 bit-packing IS little-endian u16 layout, so the packer is a
-# plain astype round-trip (a python per-bit packer would dwarf the
-# probe itself at 17M values)
-w = 16
-n1, n2 = (1 << 24) + (1 << 20), (1 << 19)
-v1 = rng.integers(0, 1 << w, n1, dtype=np.uint64)
-v2 = rng.integers(0, 1 << w, n2, dtype=np.uint64)
-runs = RunTable.empty()
-packed = v1.astype("<u2").tobytes()
-runs.counts += [n1, 997, n2]            # bp, RLE, bp
-runs.is_rle += [False, True, False]
-runs.values += [0, 54321, 0]
-runs.bit_bases += [0, 0, len(packed) * 8]
-runs.widths += [w, w, w]
-packed += v2.astype("<u2").tobytes()
-dcap = 1 << 25
-total = n1 + 997 + n2
-# 32 MiB tiles: the dense buffer still streams (4 tiles > 1), but the
-# CPU interpreter's per-grid-cell overhead stays within CI time — the
-# traffic (n_blocks x dense bytes) is tile-size-invariant anyway
-with kb.tile_bytes_override(32 << 20):
-    with kb.backend_override("pallas"):
-        t0 = time.time()
-        p = np.asarray(kdec.expand_stream(runs, packed, dcap))
-        dec_s = time.time() - t0
-with kb.backend_override("xla"):
-    x = np.asarray(kdec.expand_stream(runs, packed, dcap))
-assert np.array_equal(p[:total], x[:total]), \
-    "decode large-buffer parity FAILED"
-expect = np.concatenate([v1, np.full(997, 54321, np.uint64), v2])
-assert np.array_equal(p[:total].astype(np.uint64), expect)
-
-d = view.delta()["counters"]
-assert d.get("kernel.backend.pallas.hits.decode.expand", 0) >= 1, d
-size_reasons = {k: v for k, v in d.items()
-                if "too_large" in k}
-assert not size_reasons, (
-    f"retired size-reason fallbacks fired: {size_reasons}")
-tiles = d.get("kernel.pallas.tiles", 0)
-assert tiles >= 8, f"streaming never tiled: {dict(d)}"
-print(f"large-buffer parity OK: segreduce 64.25MiB {seg_s:.0f}s, "
-      f"decode dense 128MiB {dec_s:.0f}s, {int(tiles)} tiles, "
-      f"zero size-reason fallbacks")
 EOF
 
 echo "== concurrency smoke (8 async queries, sched.maxConcurrent=3, live /metrics + /queries scrape) =="
@@ -1499,28 +1347,6 @@ print(f"fleet chaos gate OK: 3 clients x{ROUNDS} rounds bit-identical "
       f"zero, replacement warmed "
       f"{rnew.ready_info['precompile']['warmed']} programs, "
       f"zero fresh compiles")
-EOF
-
-echo "== smoke bench (tracing enabled) =="
-python bench.py --smoke --fleet=3 --profile-out=/tmp/bench_profile.json
-
-echo "== emitted profile/trace JSON validates =="
-python - <<'EOF'
-import json
-prof = json.load(open("/tmp/bench_profile.json"))
-for k in ("query_id", "status", "plan", "metrics", "wall_breakdown",
-          "spans", "phases"):
-    assert k in prof, f"profile missing top-level key {k!r}"
-assert prof["status"] == "success", prof.get("error")
-assert prof["spans"], "no spans recorded despite obs.trace.enabled=true"
-for sec in ("scan", "shuffle", "semaphore", "spill"):
-    assert sec in prof["metrics"], f"profile missing {sec} section"
-trace = json.load(open("/tmp/bench_profile.json.trace.json"))
-evs = trace["traceEvents"]
-assert evs, "empty chrome trace"
-b = sum(1 for e in evs if e["ph"] == "B")
-e = sum(1 for e in evs if e["ph"] == "E")
-assert b == e and b > 0, f"unmatched B/E events: {b} vs {e}"
 EOF
 
 echo "CI GREEN"

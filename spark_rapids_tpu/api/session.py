@@ -78,8 +78,6 @@ class TpuSparkSession:
         scan_cache.configure(
             self.conf.get(cfg.SCAN_METADATA_CACHE_ENABLED),
             self.conf.get(cfg.SCAN_METADATA_CACHE_MAX_BYTES))
-        from spark_rapids_tpu.kernels import backend as kernel_backend
-        kernel_backend.configure(self.conf)
         from spark_rapids_tpu.exec import kernel_abi
         kernel_abi.configure(self.conf)
         from spark_rapids_tpu.pyworker import pool as pyworker_pool

@@ -556,47 +556,6 @@ JOIN_SKEW_BROADCAST_THRESHOLD = conf(
     "replicated by reference but counted as a replication so the "
     "memory cost is observable.", int)
 
-KERNEL_BACKEND = conf(
-    "spark.rapids.tpu.kernel.backend", "xla",
-    "Kernel backend for the gather-bound decode/aggregate hot paths: "
-    "'xla' (default — the composed array-op formulations, every "
-    "program of which the TPU compiler accepts; "
-    "tests/test_chip_compile.py) or 'pallas' (hand-written Pallas "
-    "kernels: dense phase-decomposed RLE/bit-unpack, fused dictionary-"
-    "decode+filter, single-pass segmented reduction — "
-    "spark_rapids_tpu/kernels/, streaming arbitrarily large buffers "
-    "through VMEM in double-buffered tiles of kernel.pallas.tileBytes; "
-    "docs/kernels.md lists which families the TPU compiler accepts). "
-    "Selection is per call site: a shape/dtype a Pallas kernel does "
-    "not cover is deselected STATICALLY, before dispatch, for that "
-    "kernel only (counted in kernel.backend.pallas.hits/.fallbacks "
-    "with reason tags); a selected kernel the compiler refuses raises "
-    "— there is no reroute at run time. CI diffs the two backends "
-    "bit-for-bit.")
-
-KERNEL_PALLAS_INTERPRET = conf(
-    "spark.rapids.tpu.kernel.pallas.interpret", "auto",
-    "Run Pallas kernels in interpreter mode: 'auto' (interpret on a "
-    "jax backend that is not a TPU — so CPU CI executes the real "
-    "kernel bodies and parity gates are genuine, not skips — and "
-    "always compile on a TPU), 'true' (always interpret, for "
-    "debugging off the chip), 'false' (always compile via Mosaic).")
-
-KERNEL_PALLAS_TILE_BYTES = conf(
-    "spark.rapids.tpu.kernel.pallas.tileBytes", 4 << 20,
-    "Per-tile byte budget for the HBM->VMEM streaming tiler "
-    "(kernels/tiling.py): gather-source buffers (dense decoded values, "
-    "dictionaries, segmented-reduction sources) larger than one tile "
-    "stream through the Pallas kernels as a second grid dimension of "
-    "fixed-size tiles (double-buffered by the Pallas pipeline emitter) "
-    "instead of requiring whole-buffer VMEM residency — this replaced "
-    "the retired dense_too_large/dict_too_large/src_too_large fallback "
-    "gates. Tile counts/bytes are observable as "
-    "kernel.pallas.tiles[.family] / kernel.pallas.tileBytes[.family]; "
-    "tile plans memoize per (kernel, shape) in the kernel cache "
-    "(kernel.tilePlan.hits/misses). Must leave room for two resident "
-    "tiles plus the element blocks in ~16 MiB VMEM/core.", int)
-
 KERNEL_ABI_ENABLED = conf(
     "spark.rapids.tpu.kernel.abi.enabled", True,
     "Shape-erased kernel ABI (exec/kernel_abi.py): batches are renamed "
@@ -1165,7 +1124,7 @@ OBS_COMPILE_ENABLED = conf(
     "Record a CompileEvent for every first (kernel, arg-shape) call "
     "through the process kernel cache — the compile observatory "
     "(obs/compile.py): kernel family, canonical shape/dtype signature, "
-    "backend, compile wall, cache tier (in-memory hit / persistent-"
+    "compile wall, cache tier (in-memory hit / persistent-"
     "XLA-cache reload / fresh compile), and the triggering query id + "
     "plan digest. Events land in a bounded ring with process-lifetime "
     "per-family aggregates, surface as kernel.compile spans in the "
@@ -1193,7 +1152,7 @@ OBS_COMPILE_CORPUS_PATH = conf(
     "Append-mode JSONL file for the precompile corpus: on the first "
     "completion of each distinct plan digest that compiled at least "
     "one program, one record {plan_digest, query_id, programs: "
-    "[{family, key, signature, backend}]} is appended — exactly the "
+    "[{family, key, signature}]} is appended — exactly the "
     "replay artifact an AOT precompile service needs to warm the "
     "persistent XLA cache off the serving path (ROADMAP item 2). "
     "Empty (default) disables corpus emission.")

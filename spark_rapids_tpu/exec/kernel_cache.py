@@ -190,13 +190,13 @@ def jit_replayed(spec: dict) -> Callable:
                      **(spec.get("jit") or {}))
 
 
-def _observe_compiles(key: Any, fn: Callable, backend: str = None,
+def _observe_compiles(key: Any, fn: Callable,
                       replay_src=None) -> Callable:
     """Compile-observatory wrapper (obs/compile.py): the first call of
     each (key, arg-shape) program is where jax.jit traces + compiles
     (or reloads from the persistent XLA cache), so that call is timed
-    and recorded as a CompileEvent with its cache tier, backend, and
-    the triggering query's id + plan digest.  Wraps the jitted callable
+    and recorded as a CompileEvent with its cache tier and the
+    triggering query's id + plan digest.  Wraps the jitted callable
     DIRECTLY (inside the OOM/dispatch-counter wrappers) so the measured
     wall is the compile, not the counters; an OOM-retry replay of the
     same shape is by definition not a first call and never re-records.
@@ -210,7 +210,6 @@ def _observe_compiles(key: Any, fn: Callable, backend: str = None,
     disabled stay unobserved for their lifetime."""
     from spark_rapids_tpu.obs import compile as obscompile
     fam = _family(key)
-    bk = backend or ("pallas" if "pallas" in str(key) else "xla")
     seen = _ShapeSeen()
 
     def wrapped(*args, **kwargs):
@@ -233,7 +232,7 @@ def _observe_compiles(key: Any, fn: Callable, backend: str = None,
                 replay = _replay_payload(replay_src[0], replay_src[1],
                                          args, kwargs, family=fam)
             obscompile.record_compile(
-                key=key, family=fam, backend=bk, leaves=sig[1],
+                key=key, family=fam, leaves=sig[1],
                 t0_ns=t0, dur_ns=dur,
                 tier=obscompile.classify_tier(probe),
                 replay=replay, build=obscompile.build_split(probe))
@@ -291,29 +290,16 @@ def jit_named(inner: Callable, family: str, **jit_kwargs) -> Callable:
     return jax.jit(named, **jit_kwargs)
 
 
-def _count_dispatches(key: Any, fn: Callable,
-                      backend: str = None) -> Callable:
+def _count_dispatches(key: Any, fn: Callable) -> Callable:
     """Per-call registry counters: ``kernel.dispatches`` is the ground
     truth the fusion layer's dispatch-reduction claims are measured
-    against (bench.py / tests assert the fused-vs-unfused delta on it;
-    one lock bump per ~72 ms dispatch is noise).
-
-    Backend-aware call sites additionally tag the family counter with
-    the backend this executable was BUILT under
-    (``kernel.dispatches.<family>.<pallas|xla>``).  Note the exact
-    semantics: a ``.pallas``-tagged dispatch ran an executable built
-    with the pallas backend REQUESTED — individual reductions inside
-    it may still have fallen back per kernel; read it together with
-    the selection counters ``kernel.backend.pallas.hits/.fallbacks``
-    (kernels/backend.py) to see whether pallas kernels actually
-    engaged inside."""
+    against (the benchmark's ``dispatches_per_query`` reads it and
+    tests assert the fused-vs-unfused delta on it; one lock bump per
+    ~72 ms dispatch is noise)."""
     from spark_rapids_tpu.obs import accounting as _acct
     from spark_rapids_tpu.obs import registry as _obsreg
     fam = _family(key)
-    pairs = [("kernel.dispatches", 1), (f"kernel.dispatches.{fam}", 1)]
-    if backend:
-        pairs.append((f"kernel.dispatches.{fam}.{backend}", 1))
-    pairs = tuple(pairs)
+    pairs = (("kernel.dispatches", 1), (f"kernel.dispatches.{fam}", 1))
 
     def wrapped(*args, **kwargs):
         _obsreg.get_registry().inc_many(*pairs)
@@ -325,8 +311,7 @@ def _count_dispatches(key: Any, fn: Callable,
 
 
 def get_kernel(key: Any, builder: Callable[[], Callable],
-               oom_retry: bool = True, backend: str = None,
-               **jit_kwargs) -> Callable:
+               oom_retry: bool = True, **jit_kwargs) -> Callable:
     """Return the cached jitted kernel for ``key``, building+jitting via
     ``builder`` on first use (LRU-bounded).
 
@@ -335,11 +320,6 @@ def get_kernel(key: Any, builder: Callable[[], Callable],
     the failed dispatch may already have consumed).  Call sites that
     donate must fold the donation into ``key``: the same signature
     jitted with and without ``donate_argnums`` is two executables.
-
-    ``backend`` tags this kernel's per-dispatch family counter with the
-    kernel backend ('pallas'/'xla') at backend-aware call sites; the
-    backend must already be folded into ``key`` by the caller (two
-    backends are two executables).
 
     Cache-tier counters (the compile-observatory split): an in-memory
     hit here bumps ``kernel.cache.memHits`` (``kernel.cache.hits`` is
@@ -369,11 +349,10 @@ def get_kernel(key: Any, builder: Callable[[], Callable],
     observed = _obscompile.is_enabled()
     if observed:
         fn = _observe_compiles(
-            key, fn, backend,
-            replay_src=(inner, jit_kwargs))
+            key, fn, replay_src=(inner, jit_kwargs))
     if oom_retry:
         fn = _with_oom_recovery(fn)
-    fn = _count_dispatches(key, fn, backend)
+    fn = _count_dispatches(key, fn)
     with _LOCK:
         cur = _CACHE.setdefault(key, fn)
         if len(_CACHE) > _MAX_ENTRIES:
@@ -381,41 +360,8 @@ def get_kernel(key: Any, builder: Callable[[], Callable],
     return cur
 
 
-# -- tile-plan memo (kernels/tiling.py) -------------------------------------
-# Grid shapes of the streaming Pallas tiler are pure functions of
-# (kernel family, buffer shapes, tileBytes, block caps) but computing
-# one walks the pow2 ladders and reads config — per-dispatch host cost
-# the hot path should not re-pay.  Plans memoize here, alongside the
-# kernels they shape, with their own hit/miss counters
-# (kernel.tilePlan.hits/misses).  Bounded like _CACHE; a plan is a tiny
-# frozen dataclass so the bound is about key hygiene, not memory.
-_TILE_PLANS: "OrderedDict[Any, Any]" = OrderedDict()
-
-
-def tile_plan(key: Any, builder: Callable[[], Any]) -> Any:
-    """Return the memoized tile plan for ``key``, computing it via
-    ``builder`` on first use.  ``key`` must capture everything the plan
-    depends on (family, shapes, block caps, tileBytes, interpret) —
-    kernels/tiling.py owns that contract."""
-    from spark_rapids_tpu.obs import registry as _obsreg
-    with _LOCK:
-        plan = _TILE_PLANS.get(key)
-        if plan is not None:
-            _TILE_PLANS.move_to_end(key)
-            _obsreg.get_registry().inc("kernel.tilePlan.hits")
-            return plan
-    _obsreg.get_registry().inc("kernel.tilePlan.misses")
-    plan = builder()
-    with _LOCK:
-        cur = _TILE_PLANS.setdefault(key, plan)
-        if len(_TILE_PLANS) > _MAX_ENTRIES:
-            _TILE_PLANS.popitem(last=False)
-    return cur
-
-
 def clear() -> None:
     _CACHE.clear()
-    _TILE_PLANS.clear()
     _ID_PINNED.clear()
 
 

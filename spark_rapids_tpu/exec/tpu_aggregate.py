@@ -76,15 +76,6 @@ class _SortedCtx:
     # arithmetically instead of through original-row gathers
     sorted_key: Optional[jnp.ndarray] = None
     key_inverse: Optional[Tuple] = None
-    # kernel backend for the segment reductions ('xla' | 'pallas'):
-    # per-REDUCTION selection with fallback — see kernels/segreduce.py
-    backend: str = "xla"
-    # tile budget pinned by the enclosing kernel's cache key (None =
-    # the live kernel.pallas.tileBytes knob): the segreduce gather
-    # plans its source tiles from THIS value, so a concurrent session
-    # reconfiguring the knob between key computation and trace cannot
-    # cache a kernel whose geometry disagrees with its key
-    tile_bytes: "Optional[int]" = None
 
     # -- scatter-free segment reductions -------------------------------
     #
@@ -94,27 +85,8 @@ class _SortedCtx:
     # in ORIGINAL row space (dense elementwise, ~1 ms per 4M) and pays
     # exactly ONE value gather into sorted space; i64 end-position
     # gathers are narrowed to i32 whenever a vbits hint bounds the sum.
-    #
-    # Under ``kernel.backend=pallas`` the gather and the segmented scan
-    # fuse into ONE single-pass Pallas kernel (kernels/segreduce.py):
-    # the sorted copy and the standalone scan array never materialize.
-    # Each reduction selects independently; unsupported shapes/dtypes
-    # keep the XLA chain below (per-kernel fallback, never the whole
-    # aggregate).
     def take_sorted(self, x: jnp.ndarray) -> jnp.ndarray:
         return jnp.take(x, self.order, axis=0)
-
-    def _pallas_op(self, op, dtype, ndim: int = 1) -> Optional[str]:
-        """op-key when this reduction runs the Pallas kernel, else
-        None (selection + hit/fallback accounting happen here, at
-        trace time of the enclosing cached aggregate kernel)."""
-        from spark_rapids_tpu.kernels import backend as kb
-        from spark_rapids_tpu.kernels import segreduce as kseg
-        name = kseg.op_name(op)
-        ok, reason = kseg.supported(self.cap, dtype, name, ndim)
-        bk = kb.choose("agg.segreduce", self.backend, ok,
-                       reason or "unsupported")
-        return name if bk == kb.PALLAS else None
 
     def seg_sum(self, x: jnp.ndarray, mask: jnp.ndarray,
                 out_np=None, narrow_bits: Optional[int] = None
@@ -128,12 +100,7 @@ class _SortedCtx:
         ``narrow_bits`` hint with narrow_bits+log2(cap) <= 31 keeps the
         whole chain in native i32.  Floats use the segmented scan: a
         global float cumsum would leak +/-inf and rounding error across
-        group boundaries through the differences.  (The Pallas path
-        computes every variant as a fused gather+segmented-add — equal
-        to the cumsum-difference formulation exactly, ints being exact
-        under wraparound, and bit-identical for floats by the shared
-        block structure.)"""
-        from spark_rapids_tpu.kernels import segreduce as kseg
+        group boundaries through the differences."""
         out_np = out_np or x.dtype
         if jnp.issubdtype(jnp.dtype(out_np), jnp.floating):
             # cast before the gather: f64 gathers are native-cheap while
@@ -141,11 +108,6 @@ class _SortedCtx:
             # commute with the gather)
             xm = jnp.where(mask, x.astype(out_np),
                            jnp.zeros((), out_np))
-            if self._pallas_op(jnp.add, out_np):
-                s = kseg.gather_seg_scan(xm, self.order, self.new,
-                                         "add", 0,
-                                         tile_bytes=self.tile_bytes)
-                return jnp.take(s, self.end_pos)
             return jnp.take(
                 scans.seg_scan(jnp.add, self.new,
                                self.take_sorted(xm), 0), self.end_pos)
@@ -154,19 +116,9 @@ class _SortedCtx:
         if narrow:
             xm = jnp.where(mask, x, jnp.zeros((), x.dtype)
                            ).astype(jnp.int32)
-            if self._pallas_op(jnp.add, jnp.int32):
-                s = kseg.gather_seg_scan(xm, self.order, self.new,
-                                         "add", 0,
-                                         tile_bytes=self.tile_bytes)
-                return jnp.take(s, self.end_pos).astype(out_np)
             c = jnp.cumsum(self.take_sorted(xm))
         else:
             xm = jnp.where(mask, x, jnp.zeros((), x.dtype))
-            if self._pallas_op(jnp.add, out_np):
-                s = kseg.gather_seg_scan(xm, self.order, self.new,
-                                         "add", 0, scan_np=out_np,
-                                         tile_bytes=self.tile_bytes)
-                return jnp.take(s, self.end_pos)
             c = scans.cumsum(self.take_sorted(xm).astype(out_np))
         ce = jnp.take(c, self.end_pos)
         return (ce - jnp.concatenate([ce[:1] * 0, ce[:-1]])
@@ -175,18 +127,9 @@ class _SortedCtx:
     def seg_count(self, mask: jnp.ndarray) -> jnp.ndarray:
         # counts fit int32 (cap < 2^31): the native 32-bit cumsum skips
         # the blocked 64-bit scan entirely; widen at the end
-        from spark_rapids_tpu.kernels import segreduce as kseg
         if mask is self.row_mask:   # COUNT(*): already have it sorted
             xs = self.sorted_mask.astype(jnp.int32)
-            if self._pallas_op(jnp.add, jnp.int32):
-                s = kseg.seg_scan_sorted(self.new, xs, "add", 0)
-                return jnp.take(s, self.end_pos).astype(jnp.int64)
         else:
-            if self._pallas_op(jnp.add, jnp.int32):
-                s = kseg.gather_seg_scan(mask, self.order, self.new,
-                                         "add", 0, scan_np=jnp.int32,
-                                         tile_bytes=self.tile_bytes)
-                return jnp.take(s, self.end_pos).astype(jnp.int64)
             xs = self.take_sorted(mask).astype(jnp.int32)
         c = jnp.cumsum(xs)
         ce = jnp.take(c, self.end_pos)
@@ -198,29 +141,20 @@ class _SortedCtx:
         """Segmented reduce via associative scan over sorted rows; the
         caller pre-fills excluded rows with op's identity (also passed
         here so the capacity-blocked scan can pad with it)."""
-        from spark_rapids_tpu.kernels import segreduce as kseg
-        name = self._pallas_op(op, x_sorted.dtype, x_sorted.ndim)
-        if name:
-            s = kseg.seg_scan_sorted(self.new, x_sorted, name, identity)
-        else:
-            s = scans.seg_scan(op, self.new, x_sorted, identity)
-        return jnp.take(s, self.end_pos)
+        return jnp.take(
+            scans.seg_scan(op, self.new, x_sorted, identity),
+            self.end_pos)
 
     def seg_min_of(self, x: jnp.ndarray, mask: jnp.ndarray,
                    fill) -> jnp.ndarray:
-        return self._seg_extreme(x, mask, fill, jnp.minimum, "min")
+        return self._seg_extreme(x, mask, fill, jnp.minimum)
 
     def seg_max_of(self, x: jnp.ndarray, mask: jnp.ndarray,
                    fill) -> jnp.ndarray:
-        return self._seg_extreme(x, mask, fill, jnp.maximum, "max")
+        return self._seg_extreme(x, mask, fill, jnp.maximum)
 
-    def _seg_extreme(self, x, mask, fill, op, name) -> jnp.ndarray:
-        from spark_rapids_tpu.kernels import segreduce as kseg
+    def _seg_extreme(self, x, mask, fill, op) -> jnp.ndarray:
         xm = jnp.where(mask, x, jnp.asarray(fill, dtype=x.dtype))
-        if self._pallas_op(op, x.dtype, xm.ndim):
-            s = kseg.gather_seg_scan(xm, self.order, self.new, name,
-                                     fill, tile_bytes=self.tile_bytes)
-            return jnp.take(s, self.end_pos)
         return jnp.take(
             scans.seg_scan(op, self.new, self.take_sorted(xm), fill),
             self.end_pos)
@@ -503,17 +437,14 @@ def normalize_key(v: ColVal) -> ColVal:
 
 
 def sorted_group_ctx(key_vals: List[ColVal],
-                     batch: DeviceBatch,
-                     backend: str = "xla",
-                     tile_bytes=None) -> _SortedCtx:
+                     batch: DeviceBatch) -> _SortedCtx:
     """Batch-shaped wrapper over _group_ctx (rows are prefix-dense:
     row i exists iff i < num_rows)."""
-    return _group_ctx(key_vals, batch.capacity, batch.num_rows,
-                      backend=backend, tile_bytes=tile_bytes)
+    return _group_ctx(key_vals, batch.capacity, batch.num_rows)
 
 
-def _group_ctx(key_vals: List[ColVal], cap: int, n_rows,
-               backend: str = "xla", tile_bytes=None) -> _SortedCtx:
+def _group_ctx(key_vals: List[ColVal], cap: int,
+               n_rows) -> _SortedCtx:
     """Group rows by key: stable LSD radix sort over bit-packed key
     digits brings equal keys adjacent, boundaries mark group starts, and
     every downstream reduction is scan+gather (see _SortedCtx).
@@ -534,8 +465,7 @@ def _group_ctx(key_vals: List[ColVal], cap: int, n_rows,
             order=i32, new=(i32 == 0), gid_sorted=jnp.zeros_like(i32),
             start_pos=jnp.zeros((cap,), jnp.int32), end_pos=end,
             sorted_mask=row_mask, cap=cap, row_mask=row_mask,
-            n_groups=jnp.int32(1), backend=backend,
-            tile_bytes=tile_bytes)
+            n_groups=jnp.int32(1))
 
     fields = [(1, (~row_mask).astype(jnp.uint64))]  # padding sorts last
     total_bits = 1
@@ -594,13 +524,11 @@ def _group_ctx(key_vals: List[ColVal], cap: int, n_rows,
         vb = sortkeys.narrow_int_bits(v0)
         if vb is not None:
             key_inverse = (vb, eff_nullables[0], v0.dtype, v0.vbits)
-    return _SortedCtx(tile_bytes=tile_bytes,
-                      order=order, new=new, gid_sorted=gid_sorted,
+    return _SortedCtx(order=order, new=new, gid_sorted=gid_sorted,
                       start_pos=start_pos, end_pos=end_pos,
                       sorted_mask=sorted_mask, cap=cap,
                       row_mask=row_mask, n_groups=n_groups,
-                      sorted_key=sorted_key_u32, key_inverse=key_inverse,
-                      backend=backend)
+                      sorted_key=sorted_key_u32, key_inverse=key_inverse)
 
 
 def gather_group_keys(key_vals: List[ColVal],
@@ -731,9 +659,8 @@ def update_aggregate(batch: DeviceBatch,
                      groupings: Sequence[ir.Expression],
                      aggregates: Sequence[ir.AggregateExpression],
                      specs: Sequence[_AggSpec],
-                     condition: Optional[ir.Expression] = None,
-                     backend: str = "xla",
-                     tile_bytes=None) -> DeviceBatch:
+                     condition: Optional[ir.Expression] = None
+                     ) -> DeviceBatch:
     """Per-batch update phase: groupBy().aggregate(updateAggs) analog.
 
     ``condition`` is a fused pre-filter (Filter directly under the
@@ -751,8 +678,7 @@ def update_aggregate(batch: DeviceBatch,
         rung-sized gather total instead of a rung compact + a sorted
         gather."""
         from dataclasses import replace as _dc_replace
-        ctx = _group_ctx(kv, cap2, nr, backend=backend,
-                         tile_bytes=tile_bytes)
+        ctx = _group_ctx(kv, cap2, nr)
         cols = gather_group_keys(kv, ctx)
         names = [f"__k{i}" for i in range(len(cols))]
         vctx = ctx
@@ -811,17 +737,14 @@ def update_aggregate(batch: DeviceBatch,
 
 
 def merge_aggregate(batch: DeviceBatch, n_keys: int,
-                    specs: Sequence[_AggSpec],
-                    backend: str = "xla",
-                    tile_bytes=None) -> DeviceBatch:
+                    specs: Sequence[_AggSpec]) -> DeviceBatch:
     """Merge phase over concatenated partials: mergeAggs analog."""
     def run(b: DeviceBatch) -> DeviceBatch:
         key_cols = b.columns[:n_keys]
         key_vals = [ColVal(c.dtype, c.data, c.validity, c.lengths,
                             vbits=c.vbits, nonnull=c.nonnull)
                     for c in key_cols]
-        ctx = sorted_group_ctx(key_vals, b, backend=backend,
-                               tile_bytes=tile_bytes)
+        ctx = sorted_group_ctx(key_vals, b)
         cols = gather_group_keys(key_vals, ctx)
         names = list(b.names[:n_keys])
         bufs_per_spec = []
@@ -891,16 +814,10 @@ class TpuHashAggregateExec(TpuExec):
 
     def _update_impl(self, batch: DeviceBatch) -> DeviceBatch:
         return update_aggregate(batch, self.groupings, self.aggregates,
-                                self.specs, self.fused_condition,
-                                backend=getattr(self, "backend", "xla"),
-                                tile_bytes=getattr(self, "tile_bytes",
-                                                   None))
+                                self.specs, self.fused_condition)
 
     def _merge_impl(self, batch: DeviceBatch) -> DeviceBatch:
-        return merge_aggregate(batch, len(self.groupings), self.specs,
-                               backend=getattr(self, "backend", "xla"),
-                               tile_bytes=getattr(self, "tile_bytes",
-                                                  None))
+        return merge_aggregate(batch, len(self.groupings), self.specs)
 
     def _final_impl(self, batch: DeviceBatch) -> DeviceBatch:
         return finalize_aggregate(batch, len(self.groupings), self.specs,
@@ -912,31 +829,13 @@ class TpuHashAggregateExec(TpuExec):
             import functools
             import types
             from spark_rapids_tpu.exec import kernel_cache as kc
-            from spark_rapids_tpu.kernels import backend as kb
-            # segment-reduction kernel backend: the plan-stamped
-            # kernel.backend (falling back to the process default for
-            # hand-built plans).  Folded into the cache keys — the two
-            # backends are two executables — and passed to get_kernel
-            # so dispatches attribute as kernel.dispatches.agg_*.<bk>
-            bk = kb.resolve(getattr(self, "_kernel_backend", None))
-            # interpret mode rides the key for pallas-built kernels so
-            # flipping kernel.pallas.interpret can't serve stale
-            # interpreter-mode executables from the process cache
             # update/merge kernels never read the output schema names
             # (they emit static __k*/__a* buffer names); only agg_final
             # bakes the real names in — so names ride ONLY its key, and
             # the same aggregation under different output aliases
             # shares the expensive update/merge sorts (shape-erased ABI)
-            # the tile budget rides the key too: it shapes the grids of
-            # the embedded segreduce kernels (kernels/tiling.py).  Read
-            # ONCE here and threaded through the shim to trace time, so
-            # a concurrent session reconfiguring the knob between key
-            # computation and first trace cannot cache a kernel whose
-            # tile geometry disagrees with its key.
-            tb = kb.tile_bytes() if bk == kb.PALLAS else None
             sig = (kc.exprs_sig(self.groupings),
-                   kc.exprs_sig(self.aggregates), bk,
-                   kb.interpret() if bk == kb.PALLAS else None, tb)
+                   kc.exprs_sig(self.aggregates))
             # only the UPDATE kernel evaluates the fused condition;
             # merge/final kernels are identical across filters and must
             # share one compile (aggregate sorts cost ~17-20 s each)
@@ -945,17 +844,14 @@ class TpuHashAggregateExec(TpuExec):
             shim = types.SimpleNamespace(
                 groupings=self.groupings, aggregates=self.aggregates,
                 specs=self.specs, _schema=self._schema,
-                fused_condition=self.fused_condition, backend=bk,
-                tile_bytes=tb)
+                fused_condition=self.fused_condition)
             cls = type(self)
             self._update_kernel = kc.get_kernel(
                 ("agg_update", usig),
-                lambda: functools.partial(cls._update_impl, shim),
-                backend=bk)
+                lambda: functools.partial(cls._update_impl, shim))
             self._merge_kernel = kc.get_kernel(
                 ("agg_merge", sig),
-                lambda: functools.partial(cls._merge_impl, shim),
-                backend=bk)
+                lambda: functools.partial(cls._merge_impl, shim))
             self._final_kernel = kc.get_kernel(
                 ("agg_final", sig, tuple(self._schema.names)),
                 lambda: functools.partial(cls._final_impl, shim))

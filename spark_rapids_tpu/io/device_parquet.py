@@ -45,10 +45,10 @@ from spark_rapids_tpu.io import parquet_meta as pm
 from spark_rapids_tpu.plan.logical import Schema
 
 _MAX_W = 24  # 4-byte gather window supports shift(<=7) + w bits
-# the dense phase-decomposed paths (io/parquet_fused.py and the Pallas
-# kernel backend, kernels/decode.py) unpack any width up to a full
-# 32-bit index word; plan_chunk admits those and the per-column XLA
-# expansion falls back per column at decode time when w > _MAX_W
+# the dense phase-decomposed fused decode (io/parquet_fused.py) unpacks
+# any width up to a full 32-bit index word; plan_chunk admits those and
+# the per-column expansion falls back per column at decode time when
+# w > _MAX_W
 _MAX_W_DENSE = 32
 
 
@@ -486,36 +486,39 @@ def decode_chunk(chunk: pm.ChunkPages, out_dtype: dt.DType,
     return decode_plan(plan_chunk(chunk, out_dtype, allow_mixed=True), cap)
 
 
-def decode_plan(p: "ChunkPlan", cap: int,
-                backend: Optional[str] = None) -> DeviceColumn:
+def _expand_stream(runs: RunTable, packed: bytes,
+                   cap: int) -> jnp.ndarray:
+    """Expand one hybrid RLE/bit-packed stream to [cap] uint32: the
+    ``expand_runs_matrix`` window-gather formulation, which REQUIRES
+    w <= ``_MAX_W`` (24) — wider streams raise ``UnsupportedChunk`` so
+    the column takes the host-Arrow fallback."""
+    wmax = max((int(x) for x, r in zip(runs.widths, runs.is_rle)
+                if not r), default=0)
+    if wmax > _MAX_W:
+        raise UnsupportedChunk(f"dict bit width {wmax}")
+    dev = _upload_runs(runs, packed)
+    return _expand_runs_packed(dev["runs_mat"], dev["packed"], cap=cap)
+
+
+def decode_plan(p: "ChunkPlan", cap: int) -> DeviceColumn:
     """Decode one host-walked ChunkPlan (possibly served by the scan
     -plan cache — io/scan_cache.py) into a DeviceColumn of capacity
     cap.  Treats the plan as immutable: plans are shared across
-    queries and threads.
-
-    ``backend`` selects the stream-expansion kernel per stream
-    (``kernel.backend``): 'pallas' runs the dense phase-decomposed
-    unpack (kernels/decode.py, ~1 gather/element, widths to 32),
-    'xla'/None the window-gather path (~9 gathers/element, widths to
-    ``_MAX_W``) — with per-stream fallback between them and the
-    existing per-column host-Arrow fallback beneath both."""
-    from spark_rapids_tpu.kernels import decode as kdec
+    queries and threads."""
     out_dtype = p.out_dtype
     n_rows = p.n_rows
 
     # -- device expansion ---------------------------------------------------
     vcap = bucket_rows(max(n_rows, 1))
     if p.nullable:
-        levels = kdec.expand_stream(p.def_runs, p.def_packed, vcap,
-                                    backend=backend)
+        levels = _expand_stream(p.def_runs, p.def_packed, vcap)
     else:
         levels = None
 
     np_t = out_dtype.to_np() if not out_dtype.is_string else None
 
     if p.mode in ("dict", "dict_str"):
-        indices = kdec.expand_stream(p.val_runs, p.val_packed, vcap,
-                                     backend=backend)
+        indices = _expand_stream(p.val_runs, p.val_packed, vcap)
         if p.nullable:
             indices, valid = _def_expand(levels, indices, n_rows, cap=vcap)
         else:
@@ -532,14 +535,12 @@ def decode_plan(p: "ChunkPlan", cap: int,
         return _to_cap(DeviceColumn(out_dtype, data, valid), cap)
 
     if p.mode == "bool":
-        bits = kdec.expand_stream(p.val_runs, p.val_packed, vcap,
-                                  backend=backend)
+        bits = _expand_stream(p.val_runs, p.val_packed, vcap)
         vals = bits.astype(jnp.bool_)
     elif p.mode == "mixed":
         # merge dict-coded and PLAIN page segments in page order:
         # per-value source selectors built with vectorized numpy repeat
-        indices = kdec.expand_stream(p.val_runs, p.val_packed, vcap,
-                                     backend=backend)
+        indices = _expand_stream(p.val_runs, p.val_packed, vcap)
         d_vals = jnp.take(
             jnp.asarray(p.dict_np.astype(np_t, copy=False)),
             jnp.clip(indices.astype(jnp.int32), 0,
@@ -625,8 +626,7 @@ def decode_row_group(path: str, row_group: int, schema: Schema,
                      columns: Optional[List[str]] = None,
                      parquet_file: Optional[papq.ParquetFile] = None,
                      source_key: Optional[tuple] = None,
-                     metrics=None,
-                     backend: Optional[str] = None
+                     metrics=None
                      ) -> Tuple[DeviceBatch, List[str]]:
     """Decode one row group to a DeviceBatch.
 
@@ -700,7 +700,7 @@ def decode_row_group(path: str, row_group: int, schema: Schema,
                 plan = sc.get_chunk_plan(source_key, path, row_group,
                                          ci, f.dtype, True, pf,
                                          metrics=metrics)
-                col = decode_plan(plan, cap, backend=backend)
+                col = decode_plan(plan, cap)
         except Exception:
             # UnsupportedChunk or any malformed-page surprise: this column
             # decodes on host; the rest of the batch stays on device

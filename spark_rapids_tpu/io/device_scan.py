@@ -149,13 +149,11 @@ class TpuParquetScanExec(TpuExec):
     def _decode_chunk(self, fctx, idx: int, file_schema: Schema,
                       file_cols):
         from spark_rapids_tpu.io import scan_cache as sc
-        from spark_rapids_tpu.kernels import backend as kb
         path, pf = fctx
         return devpq.decode_row_group(
             path, idx, file_schema, columns=file_cols,
             parquet_file=pf, source_key=sc.handle_key(pf, path),
-            metrics=self.metrics,
-            backend=kb.resolve(getattr(self, "_kernel_backend", None)))
+            metrics=self.metrics)
 
     def execute(self) -> List[Iterator[DeviceBatch]]:
         if (self.fmt == "parquet" and self.allow_fused and
@@ -214,7 +212,6 @@ class TpuParquetScanExec(TpuExec):
         from spark_rapids_tpu.exec.scans import ScanPrefetcher
         from spark_rapids_tpu.io import parquet_fused as pqf
         from spark_rapids_tpu.io import scan_cache as sc
-        from spark_rapids_tpu.kernels import backend as kb
 
         wanted = [f.name for f in self._schema.fields]
         part_cols = [c for c in wanted if c in self.part_fields]
@@ -223,27 +220,20 @@ class TpuParquetScanExec(TpuExec):
         host_threads = max(1, int(self.conf.get(
             cfg.SCAN_HOST_PREP_THREADS)))
         depth = max(0, int(self.conf.get(cfg.SCAN_PREFETCH_DEPTH)))
-        backend = kb.resolve(getattr(self, "_kernel_backend", None))
-        # kernel 2: the consumer's condition the planner pushed down
-        # (plan/overrides._push_scan_filters); ordinals index `wanted`
-        pushed = getattr(self, "_pushed_filter", None)
         groups = self._fused_groups()
 
         # shared-scan multicast (io/scan_share): concurrent queries
-        # decoding the same (stamps, row-groups, columns, filter)
-        # group share ONE host prep + device decode
+        # decoding the same (stamps, row-groups, columns) group share
+        # ONE host prep + device decode
         share = None
         share_keys: List = []
         if bool(self.conf.get(cfg.SCAN_SHARED_ENABLED)):
-            from spark_rapids_tpu.exec import kernel_cache as kc
             from spark_rapids_tpu.io import scan_share
             share = scan_share.get_share(
                 int(self.conf.get(cfg.SCAN_SHARED_WINDOW_BYTES)))
             schema_sig = tuple((f.name, f.dtype.name)
                                for f in self._schema.fields)
-            pushed_sig = kc.expr_sig(pushed)
-            share_keys = [scan_share.share_key(srcs, pv, schema_sig,
-                                               pushed_sig, backend)
+            share_keys = [scan_share.share_key(srcs, pv, schema_sig)
                           for srcs, pv in groups]
 
         def prepare(path_rgs):
@@ -256,9 +246,7 @@ class TpuParquetScanExec(TpuExec):
                 return pqf.prepare_fused(
                     sources, file_schema, columns=file_cols,
                     host_threads=host_threads,
-                    metrics=self.metrics, backend=backend,
-                    pushed_filter=pushed,
-                    scan_names=wanted), handles
+                    metrics=self.metrics), handles
             except BaseException:
                 for h in handles.values():
                     h.close()

@@ -101,10 +101,6 @@ class _SegSpec:
     m_dict_off: int = -1
     m_dict_size: int = -1
     m_dlen_off: int = -1
-    # kernel-2 deferral: keep this dict segment as CODES through
-    # stitching; the dictionary gather runs predicated on the pushed
-    # filter's mask AFTER condition evaluation (kernels/filter_decode)
-    defer: bool = False
 
 
 @dataclass
@@ -125,18 +121,6 @@ class _FusedPlan:
     # per-column static value-range hint (DeviceColumn.vbits) computed
     # from host-known dictionary pages / PLAIN buffers; None = unknown
     col_vbits: Tuple[Optional[int], ...] = ()
-    # kernel backend for phase 0 (dense unpack) and the kernel-2
-    # deferred dictionary gather; folded into ``key``
-    backend: str = "xla"
-    # tile budget stamped at assemble time (pallas only; also in
-    # ``key``): _make_kernel's tiled gathers read THIS value, never
-    # the live process knob, so a concurrent session reconfiguring
-    # kernel.pallas.tileBytes between assemble and first trace cannot
-    # build a kernel that disagrees with the eligibility gate or key
-    tile_bytes: Optional[int] = None
-    # kernel 2: (condition expr, scan output-name order, deferred
-    # column names) when the pushed filter is active, else None
-    pushed: Optional[Tuple] = None
 
 
 def _column_vbits(out_dtype: dt.DType,
@@ -243,59 +227,15 @@ def _stream_layout(runs: RunTable, packed_len: int) -> _StreamLayout:
 
 def assemble(plans: List[List[Optional[ChunkPlan]]],
              out_dtypes: List[dt.DType], names: List[str],
-             n_rows: List[int], backend: str = "xla",
-             pushed_filter=None,
-             scan_names: Optional[List[str]] = None) -> _FusedPlan:
+             n_rows: List[int]) -> _FusedPlan:
     """Pack every segment's host structures into the fused upload set.
 
     plans[col][rg] is a ChunkPlan, or None for a column missing from
-    that file (emitted as all-null rows for that segment).
-
-    ``backend`` selects the phase-0 unpack kernel (kernels/decode.py).
-    ``pushed_filter`` (with ``scan_names``, the scan's full output-name
-    order the condition's ordinals index) arms kernel 2: int-dictionary
-    columns NOT referenced by the condition defer their dictionary
-    gather until after the mask is known — per-column fallback reasons
-    land in ``kernel.backend.pallas.fallbacks.scan.filterDecode.*``."""
-    from spark_rapids_tpu.kernels import backend as kb
-    from spark_rapids_tpu.kernels import filter_decode as kfd
+    that file (emitted as all-null rows for that segment)."""
     K = len(n_rows)
     vcap = bucket_rows(max(max(n_rows, default=1), 1))
     total = sum(n_rows)
     cap = bucket_rows(max(total, 1))
-
-    # -- kernel-2 deferral candidates (decided before specs build) ----
-    defer_cols: set = set()
-    if pushed_filter is not None and backend == kb.PALLAS:
-        from spark_rapids_tpu.expr import ir as _ir
-        ref_names = {scan_names[b.ordinal] for b in _ir.collect(
-            pushed_filter, lambda e: isinstance(e, _ir.BoundReference))}
-        for ci, col_plans in enumerate(plans):
-            modes = {p.mode for p in col_plans if p is not None}
-            # int ('dict') and STRING ('dict_str') dictionary columns
-            # both defer; mixed-mode columns decode eagerly
-            if modes not in ({"dict"}, {"dict_str"}):
-                continue
-            if names[ci] in ref_names:
-                kb.fallback("scan.filterDecode", "condition_column")
-                continue
-            if modes == {"dict"}:
-                # every segment's dictionary must live in the SAME
-                # wire-dtype buffer: phase 5 runs ONE gather over one
-                # buffer, and doff offsets from a different buffer
-                # would silently read the wrong dictionary (schema-
-                # evolved multi-file groups can mix int32/int64 dict
-                # pages per column).  String dictionaries are immune:
-                # all of them share the one u8 matrix buffer and the
-                # per-segment stride is static in the stitched codes.
-                pkeys = {str(p.dict_np.dtype) for p in col_plans
-                         if p is not None}
-                if len(pkeys) != 1:
-                    kb.fallback("scan.filterDecode", "mixed_dict_dtypes")
-                    continue
-            defer_cols.add(ci)
-        if not defer_cols:
-            kb.fallback("scan.filterDecode", "no_dict_columns")
 
     width_bytes: Dict[int, List[bytes]] = {}
     width_vals: Dict[int, int] = {}
@@ -325,16 +265,14 @@ def assemble(plans: List[List[Optional[ChunkPlan]]],
     dict_parts: Dict[str, List[np.ndarray]] = {}
     dict_sizes: Dict[str, int] = {}
 
-    for ci, col_plans in enumerate(plans):
+    for col_plans in plans:
         col_specs: List[_SegSpec] = []
         for r, p in enumerate(col_plans):
             if p is None:
                 col_specs.append(_SegSpec(mode="null", nullable=True))
                 continue
             nullable = p.nullable and not _all_valid(p.def_runs)
-            s = _SegSpec(mode=p.mode, nullable=nullable,
-                         defer=(ci in defer_cols and
-                                p.mode in ("dict", "dict_str")))
+            s = _SegSpec(mode=p.mode, nullable=nullable)
             if nullable:
                 s.def_stream = add_stream(p.def_runs, p.def_packed)
             if p.mode in ("dict", "dict_str", "bool"):
@@ -465,64 +403,22 @@ def assemble(plans: List[List[Optional[ChunkPlan]]],
         arrays["dict_" + key] = _pad_np(
             buf, bucket_rows(buf.shape[0] + pad, 64))
 
-    # -- kernel-2 shape gate (the old 16 MiB dict_too_large residency
-    # -- gate is gone — oversized dictionaries stream tile-wise
-    # -- instead of falling back).  ``tileb`` below is the one
-    # -- tile-budget read this plan ever makes: gate, cache key, and
-    # -- trace-time kernels all share it.
-    tileb = kb.tile_bytes() if backend == kb.PALLAS else None
-    for ci in sorted(defer_cols):
-        s0 = next(s for s in specs[ci] if s.mode in ("dict", "dict_str"))
-        if s0.mode == "dict":
-            ok, reason = kfd.supported(cap)
-        else:
-            col_L = max(s.dlen for s in specs[ci]
-                        if s.mode == "dict_str")
-            ok, reason = kfd.str_supported(cap, col_L,
-                                           tile_bytes=tileb)
-            if ok:
-                # the post-filter lengths recover via the 1-D gather
-                ok, reason = kfd.supported(cap)
-        if not ok:
-            kb.fallback("scan.filterDecode", reason)
-            for s in specs[ci]:
-                s.defer = False
-    defer_names = tuple(
-        names[ci] for ci in range(len(specs))
-        if any(s.defer for s in specs[ci]))
-    pushed = None
-    pushed_sig = None
-    if defer_names:
-        from spark_rapids_tpu.exec import kernel_cache as kc
-        pushed = (pushed_filter, tuple(scan_names), defer_names)
-        pushed_sig = (kc.expr_sig(pushed_filter), tuple(scan_names),
-                      defer_names)
-
     col_vbits = tuple(_column_vbits(out_dtypes[ci], plans[ci])
                       for ci in range(len(plans)))
-    # interpret mode is part of the executable's identity whenever the
-    # backend embeds pallas calls: flipping kernel.pallas.interpret
-    # in-process must not serve a stale interpreter-mode kernel — and
-    # so is the tile budget (``tileb``, read ONCE above), which shapes
-    # every embedded kernel's grid
-    interp = kb.interpret() if backend == kb.PALLAS else None
     key = ("pq_fused6", tuple(names),
            tuple(d.name for d in out_dtypes), K, vcap, cap,
            nslcap, rcap, tuple(stream_path), tuple(w_caps), col_vbits,
-           backend, interp, tileb, pushed_sig,
            tuple((a, arrays[a].shape, str(arrays[a].dtype))
                  for a in sorted(arrays)),
            tuple(tuple((s.mode, s.nullable, s.def_stream, s.val_stream,
                         s.plain_key, s.dcap, s.dlen, s.m_plain_off,
-                        s.m_dict_off, s.m_dict_size, s.m_dlen_off,
-                        s.defer)
+                        s.m_dict_off, s.m_dict_size, s.m_dlen_off)
                        for s in row) for row in specs))
     return _FusedPlan(key=key, specs=specs, out_dtypes=out_dtypes,
                       names=names, arrays=arrays, n_rows=list(n_rows),
                       cap=cap, vcap=vcap, stream_path=stream_path,
                       nslcap=nslcap, widths=tuple(w_caps),
-                      col_vbits=col_vbits, backend=backend,
-                      tile_bytes=tileb, pushed=pushed)
+                      col_vbits=col_vbits)
 
 
 # ---------------------------------------------------------------------------
@@ -541,12 +437,30 @@ def _unpack_width(bytes_arr: jnp.ndarray, w: int, ncap: int) -> jnp.ndarray:
     words, so reshaping the words to [ncap/32, w] makes every value j
     in a group a STATIC (word, shift) slot — w vectorized shift/or ops
     over [ncap/32] lanes, ~10x less memory traffic than expanding to
-    one byte per bit.
-
-    (Implementation moved to kernels/decode.py so the Pallas backend
-    shares one definition; this alias is the XLA path.)"""
-    from spark_rapids_tpu.kernels.decode import _unpack_xla
-    return _unpack_xla(bytes_arr, w, ncap)
+    one byte per bit."""
+    if w == 1:
+        bits = ((bytes_arr[:, None] >>
+                 jnp.arange(8, dtype=jnp.uint8)) & 1)      # [B, 8]
+        return bits.reshape(-1).astype(jnp.uint32)
+    if ncap % 32 == 0 and bytes_arr.shape[0] % 4 == 0:
+        words = (bytes_arr.reshape(-1, 4).astype(jnp.uint32) <<
+                 jnp.arange(0, 32, 8, dtype=jnp.uint32)[None, :]
+                 ).sum(axis=1, dtype=jnp.uint32)           # LE u32 words
+        W = words.reshape(ncap // 32, w)
+        mask = jnp.uint32((1 << w) - 1)
+        outs = []
+        for j in range(32):
+            a, s = (j * w) >> 5, (j * w) & 31
+            v = W[:, a] >> jnp.uint32(s)
+            if s + w > 32:
+                v = v | (W[:, a + 1] << jnp.uint32(32 - s))
+            outs.append(v & mask)
+        return jnp.stack(outs, axis=1).reshape(-1)
+    bits = ((bytes_arr[:, None] >>
+             jnp.arange(8, dtype=jnp.uint8)) & 1)          # [B, 8]
+    vals = bits.reshape(ncap, w).astype(jnp.uint32)
+    return jnp.sum(vals << jnp.arange(w, dtype=jnp.uint32)[None, :],
+                   axis=1)
 
 
 def _expand_slice_stream(sruns_row: jnp.ndarray, dense_all: jnp.ndarray,
@@ -626,11 +540,10 @@ def _make_kernel(fp: _FusedPlan):
         for r, s in enumerate(col_specs):
             if s.mode == "null":
                 continue
-            sig = (s.mode, s.nullable, s.plain_key, s.dlen, s.defer)
+            sig = (s.mode, s.nullable, s.plain_key, s.dlen)
             groups.setdefault(sig, []).append((ci, r))
 
     def kernel(arrays: Dict[str, jnp.ndarray]):
-        from spark_rapids_tpu.kernels import decode as kdec
         nrows = arrays["nrows"]
         meta = arrays["meta"]
 
@@ -638,8 +551,7 @@ def _make_kernel(fp: _FusedPlan):
         dense_parts = [jnp.zeros((vcap,), jnp.uint32)]   # front pad
         for w, ncap in w_caps:
             dense_parts.append(
-                kdec.unpack_bits(arrays[f"bits_{w}"], w, ncap,
-                                 backend=fp.backend))
+                _unpack_width(arrays[f"bits_{w}"], w, ncap))
         dense_parts.append(jnp.zeros((vcap,), jnp.uint32))  # tail pad
         dense_all = jnp.concatenate(dense_parts)
 
@@ -664,7 +576,7 @@ def _make_kernel(fp: _FusedPlan):
         # -- phases 2-3: one vmapped subgraph per group ----------------
         seg_out: Dict[Tuple[int, int], Tuple] = {}
         for sig, members in groups.items():
-            mode, nullable, pkey, dlen, defer = sig
+            mode, nullable, pkey, dlen = sig
             specs_m = [specs[ci][r] for ci, r in members]
             n_m = nrows[jnp.asarray([r for _, r in members])]
             if nullable:
@@ -682,52 +594,7 @@ def _make_kernel(fp: _FusedPlan):
                     [s.m_dict_off for s in specs_m])]
                 dsize_m = meta[jnp.asarray(
                     [s.m_dict_size for s in specs_m])]
-                if mode == "dict" and defer:
-                    # kernel 2: keep CODES (global dictionary index);
-                    # the gather runs predicated on the pushed mask in
-                    # phase 5 — filtered-out rows never decode
-                    def one_codes(idx, lv, n_r, doff, dsize):
-                        idx, valid = _def_apply(lv, idx, n_r, vcap)
-                        idx = jnp.clip(idx, 0,
-                                       jnp.maximum(dsize - 1, 0))
-                        return doff + idx, valid
-
-                    in_ax = (0, 0 if nullable else None, 0, 0, 0)
-                    codes_m, valid_m = jax.vmap(
-                        one_codes, in_axes=in_ax)(idx_m, lv_m, n_m,
-                                                  doff_m, dsize_m)
-                    for (ci, r), d, v in zip(members, codes_m, valid_m):
-                        seg_out[(ci, r)] = (d, v)
-                elif mode == "dict_str" and defer:
-                    # kernel 2, strings: stitch three int32 code
-                    # streams — byte base into the shared u8 matrix
-                    # buffer, index into the lengths buffer, and the
-                    # segment's static row stride — and gather bytes +
-                    # lengths tile-wise in phase 5 once the pushed
-                    # mask is known (kernels/filter_decode)
-                    L = int(pkey)
-                    loff_m = meta[jnp.asarray(
-                        [s.m_dlen_off for s in specs_m])]
-
-                    def one_str_codes(idx, lv, n_r, doff, dsize, loff):
-                        idx, valid = _def_apply(lv, idx, n_r, vcap)
-                        idx = jnp.clip(idx, 0,
-                                       jnp.maximum(dsize - 1, 0))
-                        bb = doff + idx * L
-                        li = loff + idx
-                        lw = jnp.where(valid, jnp.int32(L),
-                                       jnp.int32(0))
-                        return bb, li, lw, valid
-
-                    in_ax = (0, 0 if nullable else None, 0, 0, 0, 0)
-                    bb_m, li_m, lw_m, valid_m = jax.vmap(
-                        one_str_codes, in_axes=in_ax)(idx_m, lv_m, n_m,
-                                                      doff_m, dsize_m,
-                                                      loff_m)
-                    for (ci, r), b3, l3, w3, v in zip(
-                            members, bb_m, li_m, lw_m, valid_m):
-                        seg_out[(ci, r)] = (b3, l3, w3, v)
-                elif mode == "dict":
+                if mode == "dict":
                     dbuf = arrays["dict_" + pkey]
 
                     def one_dict(idx, lv, n_r, doff, dsize):
@@ -821,29 +688,16 @@ def _make_kernel(fp: _FusedPlan):
                 out = jax.lax.dynamic_update_slice(out, parts[k], start)
             return out[:cap]
 
-        cols: List[Optional[DeviceColumn]] = []
-        # ci -> ('int', codes, valid) | ('str', bb, li, lw, valid, L)
-        deferred_info: Dict[int, Tuple] = {}
+        cols: List[DeviceColumn] = []
         for ci, col_specs in enumerate(specs):
             odt = out_dtypes[ci]
             np_t = odt.to_np() if not odt.is_string else None
-            col_defer = any(s.defer for s in col_specs)
-            str_defer = col_defer and odt.is_string
             col_L = max((s.dlen for s in col_specs), default=1) \
                 if odt.is_string else 0
             seg_data, seg_valid, seg_lens = [], [], []
-            seg_li, seg_lw = [], []   # string-defer code streams
             for r, s in enumerate(col_specs):
                 if s.mode == "null":
-                    if col_defer:
-                        seg_data.append(jnp.zeros((vcap,),
-                                                  dtype=jnp.int32))
-                        if str_defer:
-                            seg_li.append(jnp.zeros((vcap,),
-                                                    dtype=jnp.int32))
-                            seg_lw.append(jnp.zeros((vcap,),
-                                                    dtype=jnp.int32))
-                    elif odt.is_string:
+                    if odt.is_string:
                         seg_data.append(jnp.zeros((vcap, col_L),
                                                   dtype=jnp.uint8))
                         seg_lens.append(jnp.zeros((vcap,),
@@ -854,15 +708,7 @@ def _make_kernel(fp: _FusedPlan):
                                                dtype=jnp.bool_))
                     continue
                 out = seg_out[(ci, r)]
-                if str_defer:
-                    seg_data.append(out[0].astype(jnp.int32))  # bytebase
-                    seg_li.append(out[1].astype(jnp.int32))
-                    seg_lw.append(out[2].astype(jnp.int32))
-                    seg_valid.append(out[3])
-                elif col_defer:
-                    seg_data.append(out[0].astype(jnp.int32))
-                    seg_valid.append(out[1])
-                elif odt.is_string:
+                if odt.is_string:
                     d = out[0]
                     if d.shape[1] < col_L:
                         d = jnp.pad(d, ((0, 0), (0, col_L - d.shape[1])))
@@ -877,19 +723,7 @@ def _make_kernel(fp: _FusedPlan):
             vb = fp.col_vbits[ci] if fp.col_vbits else None
             nn = all(not s.nullable and s.mode != "null"
                      for s in col_specs)
-            if str_defer:
-                deferred_info[ci] = ("str", stitch(seg_data, np.int32(0)),
-                                     stitch(seg_li, np.int32(0)),
-                                     stitch(seg_lw, np.int32(0)),
-                                     valid, col_L)
-                cols.append(None)
-            elif col_defer:
-                # kernel 2: hold global dictionary codes; decoded in
-                # phase 5 once the pushed filter's mask is known
-                deferred_info[ci] = ("int", stitch(seg_data, np.int32(0)),
-                                     valid)
-                cols.append(None)
-            elif odt.is_string:
+            if odt.is_string:
                 data = stitch(seg_data, np.uint8(0))
                 lens = stitch(seg_lens, np.int32(0))
                 cols.append(DeviceColumn(odt, data, valid, lens,
@@ -898,53 +732,6 @@ def _make_kernel(fp: _FusedPlan):
                 data = stitch(seg_data, np.zeros((), np_t)[()])
                 cols.append(DeviceColumn(odt, data, valid, vbits=vb,
                                          nonnull=nn))
-
-        # -- phase 5 (kernel 2): pushed-filter mask, then PREDICATED
-        # -- dictionary gathers for the deferred columns --------------
-        if deferred_info:
-            from spark_rapids_tpu.expr import eval_tpu
-            from spark_rapids_tpu.kernels import filter_decode as kfd
-            cond, scan_names_t, _dn = fp.pushed
-            by_name = {nm: c for nm, c in zip(fp.names, cols)
-                       if c is not None}
-            # placeholder for names the condition can't reference
-            # (deferred / partition / fallback columns — barred by the
-            # prepare-time eligibility gates)
-            ph = DeviceColumn(dt.INT32, jnp.zeros((cap,), jnp.int32),
-                              jnp.zeros((cap,), jnp.bool_))
-            eval_batch = DeviceBatch(
-                list(scan_names_t),
-                [by_name.get(nm, ph) for nm in scan_names_t], total)
-            cv = eval_tpu.evaluate(cond, eval_batch)
-            keep = cv.data.astype(jnp.bool_) & cv.validity & \
-                (jnp.arange(cap) < total)
-            for ci, dinfo in deferred_info.items():
-                odt = out_dtypes[ci]
-                nn = all(not s.nullable and s.mode != "null"
-                         for s in specs[ci])
-                if dinfo[0] == "str":
-                    _k, bb, li, lw, valid, col_L = dinfo
-                    keepv = keep & valid
-                    mat = kfd.decode_str_pallas(
-                        arrays["dict_u8str"], bb, lw, keepv, col_L,
-                        tile_bytes=fp.tile_bytes)
-                    lens = kfd.decode_pallas(
-                        arrays["dict_strlens"], li, keepv,
-                        tile_bytes=fp.tile_bytes)
-                    cols[ci] = DeviceColumn(
-                        odt, mat, valid, lens.astype(jnp.int32),
-                        nonnull=nn)
-                    continue
-                _k, codes, valid = dinfo
-                np_t = odt.to_np()
-                s0 = next(s for s in specs[ci] if s.defer)
-                dbuf = arrays["dict_" + s0.plain_key]
-                vals = kfd.decode_pallas(dbuf, codes, keep & valid,
-                                         tile_bytes=fp.tile_bytes)
-                cols[ci] = DeviceColumn(
-                    odt, vals.astype(np_t), valid,
-                    vbits=fp.col_vbits[ci] if fp.col_vbits else None,
-                    nonnull=nn)
         return tuple(cols), total
 
     return kernel
@@ -1073,25 +860,14 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
                   schema: Schema,
                   columns: Optional[List[str]] = None,
                   host_threads: int = 1,
-                  metrics=None,
-                  backend: Optional[str] = None,
-                  pushed_filter=None,
-                  scan_names: Optional[List[str]] = None
-                  ) -> PreparedScan:
+                  metrics=None) -> PreparedScan:
     """Host half of the fused decode: footer/page walks (through the
     scan-plan cache when enabled), fused-plan assembly, packed-page
     upload, and the host-Arrow fallback decode.  Safe to run on a
-    prefetch thread: it never reads device memory.
-
-    ``backend`` picks the kernel backend (``kernel.backend``) for the
-    decode program; ``pushed_filter``/``scan_names`` arm the kernel-2
-    deferred dictionary-decode+filter (see ``assemble``) — an
-    optimization hint with per-batch eligibility checks here, never a
-    contract: any ineligibility simply decodes everything as before."""
+    prefetch thread: it never reads device memory."""
     import contextlib
     from spark_rapids_tpu.columnar.batch import from_arrow as _fa
     from spark_rapids_tpu.exec.base import timed_extra
-    from spark_rapids_tpu.kernels import backend as kb
 
     def phase(key):
         return timed_extra(metrics, key) if metrics is not None \
@@ -1101,22 +877,20 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
     out_dtypes = [schema.field(c).dtype for c in wanted]
     n_rows = [pf.metadata.row_group(rg).num_rows
               for pf, _, rg in sources]
-    bk = kb.resolve(backend)
 
     with phase("scan.hostPrepTime"):
         from spark_rapids_tpu.io import scan_cache as sc
         total = sum(n_rows)
         cap = bucket_rows(max(total, 1))
 
-        # off the pallas backend a batch's upload set is a function of
-        # its files' chunks alone (no pushed filter, no process knob),
-        # so a batch of unchanged files is walked, packed and uploaded
-        # once, not once a query
+        # a batch's upload set is a function of its files' chunks
+        # alone, so a batch of unchanged files is walked, packed and
+        # uploaded once, not once a query
         stamps = tuple((sc.handle_key(pf, path), rg)
                        for pf, path, rg in sources)
-        akey = (stamps, tuple(wanted), tuple(d.name for d in out_dtypes),
-                bk) if bk != kb.PALLAS and \
-            all(s is not None for s, _ in stamps) else None
+        akey = (stamps, tuple(wanted),
+                tuple(d.name for d in out_dtypes)) \
+            if all(s is not None for s, _ in stamps) else None
         kept = sc.get_assembled(akey)
         if kept is not None:
             fp, dev_arrays = kept
@@ -1135,27 +909,8 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
             if len(dev_cols) != len(wanted):
                 akey = None     # only a wholly fused batch is kept
 
-            pushed = None
-            if pushed_filter is not None and bk == kb.PALLAS:
-                # every column the condition reads must be device-decoded
-                # in THIS batch (a fallback/list/partition operand would
-                # evaluate against a placeholder) — ineligible batches keep
-                # the ordinary decode, per-kernel-fallback style
-                from spark_rapids_tpu.expr import ir as _ir
-                ref_names = {scan_names[b.ordinal] for b in _ir.collect(
-                    pushed_filter,
-                    lambda e: isinstance(e, _ir.BoundReference))}
-                if ref_names <= set(dev_cols):
-                    pushed = pushed_filter
-                else:
-                    kb.fallback("scan.filterDecode", "condition_columns")
-
             if dev_plans:
-                fp = assemble(dev_plans, dev_dtypes, dev_cols, n_rows,
-                              backend=bk, pushed_filter=pushed,
-                              scan_names=scan_names)
-        if fp is not None and fp.pushed is not None:
-            kb.hit("scan.filterDecode")
+                fp = assemble(dev_plans, dev_dtypes, dev_cols, n_rows)
 
     with phase("scan.uploadTime"):
         if fp is not None and dev_arrays is None:
@@ -1229,8 +984,7 @@ def finish_fused(prep: PreparedScan) -> Tuple[DeviceBatch, List[str]]:
     if prep.fp is not None:
         from spark_rapids_tpu.exec import kernel_cache as kc
         fp = prep.fp
-        kern = kc.get_kernel(fp.key, lambda: _make_kernel(fp),
-                             backend=fp.backend)
+        kern = kc.get_kernel(fp.key, lambda: _make_kernel(fp))
         out_cols, _ = kern(prep.dev_arrays)
         for name, col in zip(prep.dev_cols, out_cols):
             cols_by_name[name] = col

@@ -10,7 +10,7 @@ GpuParquetScan.scala:824,1145; RapidsConf.scala:513,540):
   * MULTITHREADED— thread-pool prefetch for high-latency (cloud) stores
 
 Here decode happens on host via Arrow C++ behind the same reader interface,
-exactly the fallback position SURVEY.md §7 phase 3 prescribes; a Pallas
+exactly the fallback position SURVEY.md §7 phase 3 prescribes; a
 device decoder can swap in behind ``_read_one`` without touching callers.
 The strategy selection and row-group batching structure is preserved.
 """

@@ -14,7 +14,7 @@ both prove it.
 Identity is content-addressed, not connection-addressed::
 
     (sorted file_key stamps, (path, row-group) tuple, output schema
-     signature, pushed-filter signature, partition values, backend)
+     signature, partition values)
 
 ``file_key`` is the scan-plan cache's (path, mtime_ns, size) stamp, so
 a rewritten file can never serve another query's stale bytes — its key
@@ -298,8 +298,7 @@ def peek_share() -> Optional[ScanShare]:
     return _SHARE
 
 
-def share_key(path_rgs, pv, schema_sig, pushed_sig,
-              backend: str) -> Optional[Tuple]:
+def share_key(path_rgs, pv, schema_sig) -> Optional[Tuple]:
     """Content identity of one fused scan group, or None when any
     source can't be stamped (unstampable work is never shared)."""
     from spark_rapids_tpu.io import scan_cache as sc
@@ -310,4 +309,4 @@ def share_key(path_rgs, pv, schema_sig, pushed_sig,
             return None
         stamps.append(k)
     return (tuple(stamps), tuple(path_rgs), tuple(schema_sig),
-            pushed_sig, tuple(sorted(pv.items())), str(backend))
+            tuple(sorted(pv.items())))

@@ -13,7 +13,6 @@ Every first call of a (kernel-cache key, arg-shape) program through
 ``exec/kernel_cache.get_kernel`` records one **CompileEvent**:
 
   * kernel family + cache-key repr + canonical shape/dtype signature
-  * backend the executable was built under (``pallas``/``xla``)
   * first-call wall, ``kernel.compile.wallNs``: everything the first
     call took, which is NOT compile time (tracing, lowering, the
     backend compile or the read-back of a cached executable, and the
@@ -467,7 +466,7 @@ def _bucket_key(key: Any) -> Any:
 # the ledger
 # ---------------------------------------------------------------------------
 
-def record_compile(key: Any, family: str, backend: str,
+def record_compile(key: Any, family: str,
                    leaves: Sequence[Any], t0_ns: int, dur_ns: int,
                    tier: str, replay: Optional[str] = None,
                    build: Optional[Dict[str, int]] = None) -> None:
@@ -497,7 +496,7 @@ def record_compile(key: Any, family: str, backend: str,
         digest = q["digest"] if q is not None else None
         evt = {"seq": _seq, "ts_unix": time.time(),
                "family": family, "key": key_repr,
-               "signature": sig, "backend": backend, "tier": tier,
+               "signature": sig, "tier": tier,
                "wall_ms": round(dur_ns / 1e6, 3),
                "query_id": qid, "plan_digest": digest}
         if build:
@@ -538,7 +537,7 @@ def record_compile(key: Any, family: str, backend: str,
             q["wall_ns"] += int(dur_ns)
             if len(q["programs"]) < _MAX_PROGRAMS_PER_QUERY:
                 prog = {"family": family, "key": key_repr,
-                        "signature": sig, "backend": backend}
+                        "signature": sig}
                 if replay is not None:
                     prog["replay"] = replay
                 q["programs"].append(prog)
@@ -561,8 +560,7 @@ def record_compile(key: Any, family: str, backend: str,
     from spark_rapids_tpu.obs import accounting as _acct
     _acct.charge_qid(qid, "kernel.compile.wallNs", int(dur_ns))
     obstrace.record("kernel.compile", t0_ns, dur_ns, cat="kernel",
-                    args={"family": family, "tier": tier,
-                          "backend": backend, "query": qid,
+                    args={"family": family, "tier": tier, "query": qid,
                           "signature": sig, **(build or {})})
     if storm_fired is not None:
         obsreg.get_registry().inc("kernel.compile.storms")

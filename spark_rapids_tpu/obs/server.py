@@ -24,9 +24,8 @@ run.  ``ObsHttpServer`` serves, from a background daemon thread:
                             plan digest), per-query attribution, the
                             shape-churn report ranked by signature
                             cardinality with width-bucketing collapse
-                            estimates, and the kernel-backend
-                            selection counters.  ``?n=`` bounds the
-                            event count (default 256).
+                            estimates.  ``?n=`` bounds the event count
+                            (default 256).
   ``GET /resultcache``      JSON: per-entry inspection of the serving
                             result cache (serve/result_cache.py) —
                             digest prefix, output names, bytes, age,
@@ -368,11 +367,9 @@ class ObsHttpServer:
         # function-level imports (the serve.result_cache idiom in
         # _metrics_text): the handler reaches sideways only when the
         # route is actually hit, so the module stays load-order safe
-        from spark_rapids_tpu.kernels import backend as kernel_backend
         from spark_rapids_tpu.obs import compile as obscompile
-        payload = obscompile.snapshot(max_events=max_events)
-        payload["selection"] = kernel_backend.selection_snapshot()
-        return json.dumps(payload, default=str)
+        return json.dumps(obscompile.snapshot(max_events=max_events),
+                          default=str)
 
     @staticmethod
     def _tenants_json() -> str:

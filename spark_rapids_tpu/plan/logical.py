@@ -612,7 +612,7 @@ class CachedRelation(LogicalPlan):
     ``compressColumnarBatchWithParquet`` at :333) + GpuInMemoryTableScanExec.
     Delta: blob encode happens on host via Arrow (the reference encodes on
     device via Table.writeParquetChunked); decode runs on device through
-    the same pallas/XLA parquet decoder as file scans.
+    the same device parquet decoder as file scans.
     """
 
     def __init__(self, child: LogicalPlan):

@@ -723,22 +723,10 @@ class TpuOverrides:
         # independent and fragments shipped to executor processes carry
         # the driver's conf through pickle
         donate = bool(conf.get(cfg.FUSION_DONATE))
-        # kernel backend rides the same per-plan stamp: the aggregate /
-        # scan execs read their OWN plan's backend (kernels.resolve),
-        # so concurrent sessions with different kernel.backend settings
-        # stay independent (the donation-stamp lesson, PR 4 review r3)
-        kbackend = str(conf.get(cfg.KERNEL_BACKEND) or "pallas")
 
         def _stamp(n):
             n._donate_enabled = donate
-            n._kernel_backend = kbackend
         plan.foreach(_stamp)
-        if kbackend == "pallas":
-            # kernel 2 (fused dictionary-decode+filter): push eligible
-            # filter conditions into a directly-below fused parquet
-            # scan so filtered-out rows never materialize decoded
-            # dictionary values (kernels/filter_decode.py)
-            _push_scan_filters(plan)
         if _plan_uses_input_file(cpu_plan):
             # fused multi-file batches can't answer input_file_name();
             # reference: GpuParquetScan falls back from the coalescing
@@ -780,75 +768,6 @@ def _fuse_filters_into_aggregates(plan: PhysicalPlan) -> None:
             f = n.children[0]
             n.fused_condition = f.condition
             n.children = (f.children[0],)
-        for c in n.children:
-            rec(c)
-
-    rec(plan)
-
-
-def _push_scan_filters(plan: PhysicalPlan) -> None:
-    """Kernel-2 planner hook (``kernel.backend=pallas`` only): when a
-    filtering consumer sits DIRECTLY on a fused parquet scan, stamp the
-    combined condition onto the scan (``_pushed_filter``) so the fused
-    decode can defer dictionary gathers until after the mask is known —
-    rows the consumer will drop never materialize decoded values
-    (kernels/filter_decode.py).
-
-    Soundness: the stamp only ZEROES deferred dictionary values on
-    mask-false rows; the consumer re-evaluates the same deterministic
-    row-wise condition over the same (never-deferred) operand columns
-    and drops/masks exactly those rows, so downstream never observes a
-    zeroed value.  Gates:
-
-      * consumer is a ``TpuFusedStageExec`` with a condition, a
-        ``TpuHashAggregateExec`` with a fused_condition, or a plain
-        ``TpuFilterExec`` — each one's condition is already bound over
-        the scan's output schema;
-      * the condition carries no barrier expression (the R2 set:
-        position/partition-dependent or non-deterministic nodes whose
-        re-evaluation inside the scan kernel could diverge);
-      * the scan has exactly ONE consumer (parent-edge refcounts — a
-        shared scan feeding a second consumer must keep real values);
-      * per-kernel fallback stays downstream: the scan ignores the
-        stamp whenever the Pallas filter-decode can't cover the batch
-        (prepare-time checks in io/parquet_fused.py), which is always
-        correct — the stamp is an optimization hint, never a contract.
-    """
-    from spark_rapids_tpu.exec.fused_stage import TpuFusedStageExec
-    from spark_rapids_tpu.exec.tpu_aggregate import TpuHashAggregateExec
-    from spark_rapids_tpu.exec.tpu_basic import TpuFilterExec
-    from spark_rapids_tpu.io.device_scan import (TpuOrcScanExec,
-                                                 TpuParquetScanExec)
-    from spark_rapids_tpu.plan.fusion import (_AGG_BARRIERS, _has_barrier,
-                                              _refcounts)
-
-    refs = _refcounts(plan)
-
-    def cond_of(n):
-        if isinstance(n, TpuFusedStageExec):
-            return n.condition
-        if isinstance(n, TpuHashAggregateExec):
-            return n.fused_condition
-        if isinstance(n, TpuFilterExec):
-            return n.condition
-        return None
-
-    seen = set()
-
-    def rec(n: PhysicalPlan) -> None:
-        if id(n) in seen:
-            return
-        seen.add(id(n))
-        cond = cond_of(n)
-        if cond is not None and n.children:
-            scan = n.children[0]
-            if (isinstance(scan, TpuParquetScanExec) and
-                    not isinstance(scan, TpuOrcScanExec) and
-                    scan.allow_fused and
-                    refs.get(id(scan), 1) <= 1 and
-                    getattr(scan, "_pushed_filter", None) is None and
-                    not _has_barrier([cond], _AGG_BARRIERS)):
-                scan._pushed_filter = cond
         for c in n.children:
             rec(c)
 

@@ -169,20 +169,10 @@ def make_distributed_agg_step(mesh: Mesh, axis: str,
     names = schema.names
     dtypes = schema.dtypes
 
-    # the distributed aggregate stays on the XLA segment reductions:
-    # Pallas kernels under shard_map are unvalidated on this runtime.
-    # Make the stand-down OBSERVABLE when pallas was requested (the
-    # every-selection-is-counted contract, kernels/backend.py) — one
-    # tagged fallback per plan build, host-side, outside the trace.
-    from spark_rapids_tpu.kernels import backend as _kb
-    if _kb.default_backend() == _kb.PALLAS:
-        _kb.fallback("agg.segreduce", "ici_distributed")
-
     def local_step(cols_leaves, local_rows):
         cols = _leaves_to_cols(cols_leaves, dtypes)
         batch = DeviceBatch(names, cols, local_rows[0])
-        partial = update_aggregate(batch, groupings, aggregates, specs,
-                                   backend="xla")
+        partial = update_aggregate(batch, groupings, aggregates, specs)
         key_vals = [ColVal(c.dtype, c.data, c.validity, c.lengths)
                     for c in partial.columns[:nk]]
         target = partition_targets(key_vals, n_dev) if nk else \
@@ -190,7 +180,7 @@ def make_distributed_agg_step(mesh: Mesh, axis: str,
         stacked, counts = bucketize(partial, target, n_dev)
         stacked, counts_recv = exchange(stacked, counts, axis)
         received = reassemble(partial.names, stacked, counts_recv)
-        merged = merge_aggregate(received, nk, specs, backend="xla")
+        merged = merge_aggregate(received, nk, specs)
         final = finalize_aggregate(merged, nk, specs, out_names)
         out_leaves = _cols_to_leaves(final.columns)
         return out_leaves, jnp.reshape(

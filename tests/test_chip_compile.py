@@ -1,19 +1,12 @@
 """Compile for the chip, without the chip.
 
 The TPU's compiler is installed here and compiles for a v5e that is
-described, not attached (``JAX_PLATFORMS=cpu`` stays set).  Two groups:
-
-* the XLA programs ``chip_smoke.py``'s statements dispatch, at the
-  smoke's own batch shapes (files of ``ROWS_PER_FILE`` rows): captured
-  from one CPU run of those statements — every first (kernel, shapes)
-  call through the kernel cache hands over its traceable, jit kwargs
-  and abstract arguments — and lowered for the described device.  These
-  are the default ``kernel.backend=xla`` path: all of them must compile.
-* one case per Pallas entry point (``kernel.backend=pallas``), at
-  1M-element shapes.  Those the compiler accepts assert the Mosaic call
-  is in the executable; those it refuses are ``xfail(strict=True)``
-  carrying its message, so the PR that repairs a kernel is told to
-  remove the mark (docs/kernels.md keeps the same table).
+described, not attached (``JAX_PLATFORMS=cpu`` stays set): the programs
+``chip_smoke.py``'s statements dispatch, at the smoke's own batch shapes
+(files of ``ROWS_PER_FILE`` rows), captured from one CPU run of those
+statements — every first (kernel, shapes) call through the kernel cache
+hands over its traceable, jit kwargs and abstract arguments — and
+lowered for the described device.  All of them must compile.
 
 Nothing runs on a device here: a compile that passes is not a chip run.
 All of it lives in this one file and describes the topology inside a
@@ -27,9 +20,6 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
-
-_N = 1 << 20        # Pallas entry points: 1M-element shapes
 
 
 @pytest.fixture(scope="module")
@@ -67,7 +57,7 @@ def smoke_programs(tmp_path_factory):
             return jax.ShapeDtypeStruct(x.shape, x.dtype)
         return x
 
-    def spy(key, fn, backend=None, replay_src=None):
+    def spy(key, fn, replay_src=None):
         inner, jit_kwargs = replay_src
         seen = set()
 
@@ -83,7 +73,7 @@ def smoke_programs(tmp_path_factory):
                 programs.setdefault(key[0], []).append(
                     (inner, jit_kwargs) + spec)
             return fn(*args, **kwargs)
-        return observe(key, first_calls, backend, replay_src)
+        return observe(key, first_calls, replay_src)
 
     root = str(tmp_path_factory.mktemp("smoke_data"))
     chip_smoke.make_data(root, 2 * chip_smoke.ROWS_PER_FILE, seed=22)
@@ -138,7 +128,7 @@ def for_chip(one_chip, smoke_programs):
         jax.clear_caches()
 
 
-# -- the default path: every XLA program of the smoke's statements ----------
+# -- every program of the smoke's statements --------------------------------
 
 _XLA_CASES = {
     "decode": ("pq_fused6",),
@@ -162,96 +152,3 @@ def test_smoke_xla_programs_compile_for_v5e(case, smoke_programs,
             f"{sorted(smoke_programs)}"
         for fn, jit_kwargs, args, kwargs in smoke_programs[fam]:
             for_chip(fn, jit_kwargs, args, kwargs)
-
-
-# -- kernel.backend=pallas: one case per entry point ------------------------
-
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _unpack():
-    from spark_rapids_tpu.kernels import decode
-    return (lambda b: decode._unpack_pallas(b, 12, _N),
-            [_sds((_N * 12 // 8,), jnp.uint8)])
-
-
-def _expand():
-    from spark_rapids_tpu.kernels import decode
-    return (lambda d, a, c: decode._expand_pallas(d, a, c, _N),
-            [_sds((_N,), jnp.uint32), _sds((_N,), jnp.int32),
-             _sds((_N,), jnp.int32)])
-
-
-def _filter_decode(dict_dtype):
-    def make():
-        from spark_rapids_tpu.kernels import filter_decode as fd
-        return (fd.decode_pallas,
-                [_sds((4096,), dict_dtype), _sds((_N,), jnp.int32),
-                 _sds((_N,), jnp.bool_)])
-    return make
-
-
-def _seg_sorted(dtype, identity):
-    def make():
-        from spark_rapids_tpu.kernels import segreduce as sr
-        return (lambda n, x: sr.seg_scan_sorted(n, x, "add", identity),
-                [_sds((_N,), jnp.bool_), _sds((_N,), dtype)])
-    return make
-
-
-def _seg_gather():
-    from spark_rapids_tpu.kernels import segreduce as sr
-    return (lambda x, o, n: sr.gather_seg_scan(x, o, n, "add", 0.0),
-            [_sds((_N,), jnp.float32), _sds((_N,), jnp.int32),
-             _sds((_N,), jnp.bool_)])
-
-
-def _refused(raises, message):
-    return pytest.mark.xfail(strict=True, raises=raises, reason=message)
-
-
-def _mlir_error():
-    from jaxlib.mlir import ir
-    return ir.MLIRError
-
-
-@pytest.mark.parametrize("make", [
-    pytest.param(_unpack, id="decode.unpack"),
-    pytest.param(_expand, id="decode.expand", marks=_refused(
-        RecursionError, "lowering recurses without end on the "
-        "in-kernel jnp.take(d_ref[:], local) (decode.py _expand_body)")),
-    pytest.param(_filter_decode(jnp.int32), id="scan.filterDecode-int32",
-                 marks=_refused(
-        RecursionError, "same in-kernel 1-D gather (filter_decode.py "
-        "decode_pallas)")),
-    pytest.param(_filter_decode(jnp.float64),
-                 id="scan.filterDecode-float64", marks=_refused(
-        ZeroDivisionError, "integer modulo by zero: 64-bit element "
-        "types have no vector layout in the kernel compiler")),
-    pytest.param(_seg_sorted(jnp.float64, 0.0),
-                 id="agg.segreduce-sorted-float64", marks=_refused(
-        ZeroDivisionError, "integer modulo by zero: 64-bit element "
-        "types have no vector layout in the kernel compiler")),
-    pytest.param(_seg_sorted(jnp.int32, 0),
-                 id="agg.segreduce-sorted-int32", marks=_refused(
-        _mlir_error(), "vector types must have positive constant "
-        "sizes but got 0 (the in-kernel associative_scan's strided "
-        "1-D slices, segreduce.py _seg_kernel)")),
-    pytest.param(_seg_gather, id="agg.segreduce-gather-float32",
-                 marks=_refused(
-        ValueError, "Only arrays with 32-bit element types can be "
-        "converted to scalars, but got: float64 (the identity reaches "
-        "SMEM as a 64-bit scalar; the gather behind it is the "
-        "in-kernel jnp.take again)")),
-])
-def test_pallas_entry_point_compiles_for_v5e(make, for_chip):
-    from spark_rapids_tpu import TpuSparkSession
-    # compile through Mosaic, never the interpreter
-    TpuSparkSession({"spark.rapids.tpu.kernel.pallas.interpret": "false"})
-    try:
-        fn, args = make()
-        compiled = for_chip(fn, None, args, {})
-        assert "tpu_custom_call" in compiled.as_text()
-    finally:
-        TpuSparkSession({})     # re-assert the default (auto)

@@ -36,9 +36,9 @@ def _fresh_ledger():
 _LEAVES = ((((4096,), "int64")), (((4096,), "float64")))
 
 
-def _fake_compile(key, family="fam", backend="xla", leaves=_LEAVES,
+def _fake_compile(key, family="fam", leaves=_LEAVES,
                   dur_ns=1_000_000, tier=obscompile.TIER_FRESH):
-    obscompile.record_compile(key=key, family=family, backend=backend,
+    obscompile.record_compile(key=key, family=family,
                               leaves=leaves, t0_ns=0, dur_ns=dur_ns,
                               tier=tier)
 
@@ -124,8 +124,7 @@ def test_observed_compile_via_get_kernel():
            if e["family"] == "tobs_real"]
     assert len(evs) == 2
     assert evs[0]["signature"] != evs[1]["signature"]
-    assert all(e["wall_ms"] >= 0 and e["backend"] == "xla"
-               for e in evs)
+    assert all(e["wall_ms"] >= 0 for e in evs)
 
 
 def _scaled(factor, x):
@@ -292,7 +291,7 @@ def test_corpus_jsonl_roundtrip(tmp_path):
         assert rec["programs"], rec
         for prog in rec["programs"]:
             assert prog["family"] and prog["signature"] and prog["key"]
-            assert prog["backend"] in ("xla", "pallas")
+            assert "backend" not in prog
     # round-trip: the first record's digest is the profile's digest
     prof = s.query_profile(lines[0]["query_id"])
     assert prof is not None and prof.plan_digest == digests[0]
@@ -433,7 +432,7 @@ def test_compiles_endpoint(tmp_path):
     assert payload["totals"]["events"] > 0
     assert len(payload["events"]) <= 5
     assert payload["churn"] and payload["per_query"]
-    assert isinstance(payload["selection"], dict)
+    assert "selection" not in payload
     for e in payload["events"]:
         assert e["query_id"] and e["plan_digest"], e
     # the route is advertised

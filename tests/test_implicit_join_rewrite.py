@@ -79,18 +79,20 @@ def test_comma_join_plans_as_equi_joins(session):
 def test_one_sided_conjuncts_reach_the_scans(session, tmp_path):
     from spark_rapids_tpu import TpuSparkSession
     s = _views(TpuSparkSession({
-        "spark.rapids.tpu.sql.variableFloatAgg.enabled": True,
-        "spark.rapids.tpu.kernel.backend": "pallas"}), tmp_path)
+        "spark.rapids.tpu.sql.variableFloatAgg.enabled": True}), tmp_path)
     _, physical = _physical_names(s, COMMA)
-    scans = []
-    physical.foreach(lambda n: scans.append(n)
-                     if type(n).__name__ == "TpuParquetScanExec" else None)
-    pushed = {tuple(sc.schema.names): getattr(sc, "_pushed_filter", None)
-              for sc in scans}
-    # a and c each carry their conjunct as the scan's pushed filter
-    assert pushed[("ak", "ax")] is not None
-    assert pushed[("ck", "cy")] is not None
-    assert pushed[("bk", "bc", "bv")] is None
+    filtered = {}
+
+    def over_scan(n):
+        for ch in n.children:
+            if type(ch).__name__ == "TpuParquetScanExec":
+                filtered[tuple(ch.schema.names)] = \
+                    getattr(n, "condition", None) is not None
+    physical.foreach(over_scan)
+    # a and c each have their conjunct directly over their scan
+    assert filtered[("ak", "ax")]
+    assert filtered[("ck", "cy")]
+    assert not filtered[("bk", "bc", "bv")]
 
 
 def test_comma_join_without_an_equality_stays_cross(session):

@@ -119,6 +119,22 @@ def test_replay_counts_skipped_and_dedup(tmp_path):
     assert stats["warmed"] == 0
 
 
+def test_replay_ignores_a_backend_field(tmp_path):
+    # a corpus written before the engine had one kernel backend names
+    # one on every program; such a file replays as any other
+    s, corpus = _corpus_session(tmp_path)
+    _query(s, mark=4.5).collect()
+    recs = [json.loads(line) for line in open(corpus)]
+    for r in recs:
+        for p in r["programs"]:
+            p["backend"] = "xla"
+    with open(corpus, "w") as f:
+        f.write("".join(json.dumps(r) + "\n" for r in recs))
+    kc.clear_compile_state()
+    stats = PrecompileService(s, corpus, idle_wait_ms=0).replay()
+    assert stats["warmed"] > 0 and stats["failed"] == 0, stats
+
+
 def test_background_start_and_wait(tmp_path):
     s, corpus = _corpus_session(tmp_path)
     _query(s, mark=5.125).collect()
