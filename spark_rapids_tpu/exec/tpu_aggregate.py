@@ -17,6 +17,36 @@ Aggregate functions follow the reference's update/merge pair structure
 (reference: AggregateFunctions.scala:531 — each ``CudfAggregate`` declares
 updateAggregate and mergeAggregate).  NaN/-0.0 key canonicalization matches
 Spark's NormalizeFloatingNumbers semantics (parity-critical).
+
+Two forms of the update's segment reduction, one chosen a batch ON THE
+DEVICE by the group count the key sort has just produced
+(``update_aggregate``: one ``lax.cond`` on ``ctx.n_groups``; no conf):
+
+  * sorted (_SortedCtx): each value vector is gathered into key order,
+    scanned, and read at the groups' end positions.  Its cost does not
+    depend on the group count, so it is the form for many groups, and
+    the only form of the merge, of string ``min``/``max`` and of
+    ``first``/``last``.
+  * dense (_DenseCtx), for a batch of at most ``_DENSE_MAX_GROUPS``
+    groups: the keys are still sorted, the VALUES NEVER MOVE.  One
+    set-scatter carries the sorted group ids back to original row
+    space, and every buffer is one masked dense reduce a group slot
+    over the vector where it is; the group keys' representatives are
+    gathered at slot count, not at capacity.  Low-cardinality GROUP BY
+    (flags, status, category) is the commonest reporting shape, and
+    TPC-H Q1's four groups paid a sort's worth of gathers: 12 value and
+    mask gathers into key order and 12 capacity-long end-position
+    gathers, 0.71 s of each 1.49-s update, which now takes 0.45 s (one
+    v5e chip; PERF.md §5 and §6, PR 31).
+
+The dense branch is built only for a grouped update at the capacity
+ladder's scale (``_dense_built``): below it a program's text is what it
+was, so small suites and small batches compile nothing further.
+``_shrink_partials`` counts which form each such update took
+(``agg.update.dense`` / ``agg.update.sorted``) from the read it makes
+anyway.  A float sum's association differs between the forms (a tree
+over rows against a left fold of blocks): its last bits, not its
+precision; integer sums, counts, ``min`` and ``max`` are exact in both.
 """
 
 from __future__ import annotations
@@ -45,6 +75,20 @@ _BIG = np.int64(1 << 62)
 # it the extra branches' compile time would dominate small-batch suites
 # (tests may lower it to cover every branch)
 _LADDER_MIN_RUNG = 1 << 18
+
+# an update whose batch holds at most this many groups reduces in
+# original row space (_DenseCtx); the branch is built only where the
+# ladder engages, so programs below that gate keep their text.  Set from
+# one chip reading (PERF.md §6, PR 31: the update of a Q1-shaped batch
+# of n groups, as built and with the branch left out, one v5e chip): the
+# largest power of two at which the dense form costs at most half the
+# sorted one, 261.5 against 707.8 ms at 1024 and 445.2 against 703.7 at
+# 2048 (81.6 against 818.4 at Q1's own four groups)
+_DENSE_MAX_GROUPS = 1024
+# group slots a dense pass reduces at once: the six reductions of a
+# Q1 batch cost the same for 4, 8 and 16 slots (4.2 ms) and more from
+# 32 on (6.5), so 16 is the widest block that is free for few groups
+_DENSE_BLOCK = 16
 
 
 @dataclass
@@ -85,6 +129,12 @@ class _SortedCtx:
     # in ORIGINAL row space (dense elementwise, ~1 ms per 4M) and pays
     # exactly ONE value gather into sorted space; i64 end-position
     # gathers are narrowed to i32 whenever a vbits hint bounds the sum.
+    # Even so a reduction costs two capacity-long gathers a 32-bit
+    # half (in, and ``end_pos`` out) whatever the group count: for a
+    # handful of groups _DenseCtx below reduces without either, and
+    # update_aggregate takes it when ``n_groups`` allows.  This form
+    # stays for every batch of more groups, where a pass a group would
+    # cost more than the gathers.
     def take_sorted(self, x: jnp.ndarray) -> jnp.ndarray:
         return jnp.take(x, self.order, axis=0)
 
@@ -160,10 +210,90 @@ class _SortedCtx:
             self.end_pos)
 
 
+@dataclass
+class _DenseCtx:
+    """Original-row-space grouping context for a batch of FEW groups:
+    the same reductions as _SortedCtx (the specs cannot tell them
+    apart), with the values left where they are.
+
+    ``gid`` names each value row's group (key order, as
+    ``gid_sorted``), ``cap`` where the row is in none; a reduction is
+    a masked dense reduce over the whole vector, ``_DENSE_BLOCK`` group
+    slots a pass, and its result is ``[cap]`` long, ``cap`` being the
+    slot count (``_DENSE_MAX_GROUPS``) and not a row capacity.  No
+    value is gathered into key order, nothing is scanned, no
+    ``[capacity]`` end-position gather fetches a handful of numbers.
+    The cost grows with groups x rows, which is why the sorted form
+    stays for every batch of more groups (update_aggregate picks by
+    ``n_groups``)."""
+
+    gid: jnp.ndarray          # [rows] original-space group id
+    cap: int                  # group slots
+    row_mask: jnp.ndarray     # original-space "row exists"
+    n_groups: jnp.ndarray     # scalar, <= cap
+
+    def _reduce(self, x, mask, fill, red) -> jnp.ndarray:
+        """[cap]: ``red`` over the rows of each group slot, ``fill``
+        standing in for rows outside it or not under ``mask`` (rows
+        outside ``row_mask`` carry no group id).  One pass over ``x`` a
+        block of ``_DENSE_BLOCK`` slots, and only the blocks the
+        batch's groups reach: four groups cost one pass whatever
+        ``cap`` is; slots past the last block keep ``fill``."""
+        fill = jnp.asarray(fill, dtype=x.dtype)
+        blk = min(_DENSE_BLOCK, self.cap)
+        lanes = jnp.arange(blk, dtype=jnp.int32)[:, None]
+
+        def block(b, out):
+            member = (self.gid[None, :] - b * blk) == lanes
+            if mask is not self.row_mask:
+                member = member & mask[None, :]
+            return jax.lax.dynamic_update_slice(
+                out, red(jnp.where(member, x[None, :], fill), axis=1),
+                (b * blk,))
+
+        return jax.lax.fori_loop(
+            0, (self.n_groups + blk - 1) // blk, block,
+            jnp.full((self.cap,), fill))
+
+    def seg_sum(self, x: jnp.ndarray, mask: jnp.ndarray,
+                out_np=None, narrow_bits: Optional[int] = None
+                ) -> jnp.ndarray:
+        """As _SortedCtx.seg_sum, in ``out_np`` throughout (integers
+        exact under wraparound, native i32 under the same
+        ``narrow_bits`` bound); a float sum is a tree over the rows
+        where the sorted form folds blocks left to right, so its last
+        bits differ and its precision does not."""
+        out_np = out_np or x.dtype
+        rows = self.gid.shape[0]
+        narrow = (not jnp.issubdtype(jnp.dtype(out_np), jnp.floating) and
+                  narrow_bits is not None and
+                  narrow_bits + max(rows - 1, 1).bit_length() <= 31)
+        acc = jnp.int32 if narrow else out_np
+        return self._reduce(x.astype(acc), mask, 0,
+                            functools.partial(jnp.sum, dtype=acc)
+                            ).astype(out_np)
+
+    def seg_count(self, mask: jnp.ndarray) -> jnp.ndarray:
+        return self._reduce(jnp.ones(self.gid.shape, jnp.int32), mask, 0,
+                            functools.partial(jnp.sum, dtype=jnp.int32)
+                            ).astype(jnp.int64)
+
+    def seg_min_of(self, x: jnp.ndarray, mask: jnp.ndarray,
+                   fill) -> jnp.ndarray:
+        return self._reduce(x, mask, fill, jnp.min)
+
+    def seg_max_of(self, x: jnp.ndarray, mask: jnp.ndarray,
+                   fill) -> jnp.ndarray:
+        return self._reduce(x, mask, fill, jnp.max)
+
+
 class _AggSpec:
     """update/merge/finalize triple for one aggregate function."""
 
     n_buffers = 1
+    # update() reads its ctx through seg_sum / seg_count / seg_min_of /
+    # seg_max_of, row_mask and cap alone, so a _DenseCtx can stand in
+    dense_ok = False
 
     def __init__(self, agg: ir.AggregateExpression):
         self.agg = agg
@@ -184,6 +314,8 @@ class _AggSpec:
 
 
 class _CountSpec(_AggSpec):
+    dense_ok = True
+
     def buffer_dtypes(self):
         return [dt.INT64]
 
@@ -206,6 +338,7 @@ class _CountSpec(_AggSpec):
 
 class _SumSpec(_AggSpec):
     n_buffers = 2  # sum, valid-input count
+    dense_ok = True
 
     def buffer_dtypes(self):
         return [self.agg.dtype, dt.INT64]
@@ -238,6 +371,8 @@ class _MinMaxSpec(_AggSpec):
     def __init__(self, agg, is_min: bool):
         super().__init__(agg)
         self.is_min = is_min
+        # string extremes tie-break word by word in sorted space
+        self.dense_ok = not agg.dtype.is_string
 
     def buffer_dtypes(self):
         return [self.agg.dtype]
@@ -320,6 +455,7 @@ class _MinMaxSpec(_AggSpec):
 
 class _AverageSpec(_AggSpec):
     n_buffers = 2  # sum f64, count i64
+    dense_ok = True
 
     def buffer_dtypes(self):
         return [dt.FLOAT64, dt.INT64]
@@ -581,17 +717,27 @@ def _slice_batch(batch: DeviceBatch, n2: int) -> DeviceBatch:
     return DeviceBatch(batch.names, cols, batch.num_rows)
 
 
+def _pad_rows(a, cap: int):
+    if a is None or a.shape[0] >= cap:
+        return a
+    return jnp.concatenate(
+        [a, jnp.zeros((cap - a.shape[0],) + a.shape[1:], a.dtype)])
+
+
 def _pad_batch(batch: DeviceBatch, cap: int) -> DeviceBatch:
-    def pad(a):
-        if a is None or a.shape[0] >= cap:
-            return a
-        return jnp.concatenate(
-            [a, jnp.zeros((cap - a.shape[0],) + a.shape[1:], a.dtype)])
+    pad = functools.partial(_pad_rows, cap=cap)
     cols = [DeviceColumn(c.dtype, pad(c.data), pad(c.validity),
                          pad(c.lengths), pad(c.elem_validity),
                          c.vbits, c.nonnull)
             for c in batch.columns]
     return DeviceBatch(batch.names, cols, batch.num_rows)
+
+
+def _ladder_engages(cap: int) -> bool:
+    """Only at real-workload scale: each further branch adds the
+    kernel's compile time again, which would dominate small-batch
+    suites."""
+    return cap // 4 >= _LADDER_MIN_RUNG
 
 
 def _on_ladder(cap: int, nr, at):
@@ -604,10 +750,7 @@ def _on_ladder(cap: int, nr, at):
     in Python; traced counts pick via one lax.switch (every branch
     compiles once; safe since exec/scans.py keeps 64-bit scans out of
     the pathological in-control-flow cumsum lowering)."""
-    # engage only at real-workload scale: each further branch adds the
-    # kernel's compile time again, which would dominate small-batch
-    # suites
-    if cap // 4 < _LADDER_MIN_RUNG:
+    if not _ladder_engages(cap):
         return at(cap)
     rungs = (cap // 4, cap // 2, cap)
     if isinstance(nr, (int, np.integer)):
@@ -627,6 +770,33 @@ def _laddered(batch: DeviceBatch, fn):
         return _pad_batch(fn(_slice_batch(batch, cap2)), cap)
 
     return _on_ladder(cap, batch.num_rows, at)
+
+
+def _dense_built(cap: int, grouped: bool,
+                 specs: Sequence[_AggSpec]) -> bool:
+    """Whether the update of a batch at capacity ``cap`` carries the
+    dense branch: a grouped update whose every spec can reduce through
+    a _DenseCtx, at or over the capacity ladder's own gate.  Below the
+    gate the program's text stays what it was (one more branch is one
+    more compile of the kernel, and a small batch's gathers are
+    cheap)."""
+    return (grouped and _ladder_engages(cap) and
+            all(s.dense_ok for s in specs))
+
+
+def _dense_ctx(ctx: _SortedCtx, slots: int) -> _DenseCtx:
+    """The sorted context's groups as ids in ORIGINAL row space:
+    ``ctx.order`` (composed with the fused filter's selection where
+    there is one) says which row each sorted position came from, so one
+    unique-index set-scatter carries ``gid_sorted`` back; rows the sort
+    never saw, filtered or padding, keep ``slots`` and match no
+    group."""
+    rows = ctx.row_mask.shape[0]
+    gid = jnp.full((rows,), slots, jnp.int32).at[
+        jnp.where(ctx.sorted_mask, ctx.order, rows)].set(
+            ctx.gid_sorted, mode="drop")
+    return _DenseCtx(gid=gid, cap=slots, row_mask=ctx.row_mask,
+                     n_groups=ctx.n_groups)
 
 
 def _gather_val(v: ColVal, sel: jnp.ndarray,
@@ -679,16 +849,43 @@ def update_aggregate(batch: DeviceBatch,
         gather."""
         from dataclasses import replace as _dc_replace
         ctx = _group_ctx(kv, cap2, nr)
-        cols = gather_group_keys(kv, ctx)
-        names = [f"__k{i}" for i in range(len(cols))]
+        # (here, and not beside the update below, so that a program
+        # without the dense branch keeps the text it always had)
+        cols = None if dense else gather_group_keys(kv, ctx)
         vctx = ctx
         if sel_s is not None:
             vctx = _dc_replace(ctx, order=jnp.take(sel_s, ctx.order),
                                row_mask=full_mask)
-        bufs_per_spec = [spec.update(v, vctx)
-                         for v, spec in zip(av, specs)]
+
+        def update(uctx):
+            return [spec.update(v, uctx) for v, spec in zip(av, specs)]
+
+        if dense:
+            # few groups: one algorithm (segment reduction) whose
+            # cheapest form depends on the segment count, so the
+            # choice is made where the count is, on the device.  The
+            # dense side works at ``slots`` rows throughout, the group
+            # keys' gathers included, and pads to the rung
+            slots = min(_DENSE_MAX_GROUPS, cap2)
+
+            def few_groups():
+                few = _dc_replace(ctx, start_pos=ctx.start_pos[:slots],
+                                  cap=slots)
+                return jax.tree_util.tree_map(
+                    lambda a: _pad_rows(a, cap2),
+                    (gather_group_keys(kv, few),
+                     update(_dense_ctx(vctx, slots))))
+
+            cols, bufs_per_spec = jax.lax.cond(
+                ctx.n_groups <= slots, few_groups,
+                lambda: (gather_group_keys(kv, ctx), update(vctx)))
+        else:
+            bufs_per_spec = update(vctx)
+        names = [f"__k{i}" for i in range(len(cols))]
         _append_buffers(cols, names, bufs_per_spec, specs, ctx)
         return DeviceBatch(names, cols, ctx.n_groups)
+
+    dense = _dense_built(batch.capacity, bool(groupings), specs)
 
     def eval_vals(b: DeviceBatch):
         kv = [normalize_key(eval_tpu.evaluate(g, b))
@@ -931,7 +1128,8 @@ class TpuHashAggregateExec(TpuExec):
                         inc["sink"].update_batches = n_updates
                     yield self._final_kernel(empty)
                     return
-                _shrink_partials(partials, bool(self.groupings))
+                _shrink_partials(partials, bool(self.groupings),
+                                 self.specs, n_updates)
                 if len(partials) == 1:
                     merged = partials[0].get()
                 else:
@@ -961,7 +1159,9 @@ class TpuHashAggregateExec(TpuExec):
         return [run(self.children[0].execute())]
 
 
-def _shrink_partials(partials: List, grouped: bool) -> None:
+def _shrink_partials(partials: List, grouped: bool,
+                     specs: Sequence[_AggSpec] = (),
+                     n_updates: int = 0) -> None:
     """Size the buffered partials by what they hold.  An update emits
     its partial at the input batch's capacity with its group count on
     the device, so four groups out of a 4M-row batch sit in a 4M-row
@@ -981,7 +1181,15 @@ def _shrink_partials(partials: List, grouped: bool) -> None:
     ``concat_batches`` keeps its no-sync path, keyed by capacities.
     Where the groups fill their tier nothing is cut and the read is the
     only cost.  A global aggregate's partial holds one row by
-    construction and needs no read."""
+    construction and needs no read.
+
+    The same read says which form each of the last ``n_updates``
+    partials (the updates' own; a retained one comes first) reduced in:
+    the update carried the dense branch or not by its batch's capacity
+    and ``specs`` (_dense_built), and took it by its group count.
+    Counted here as ``agg.update.dense`` / ``agg.update.sorted``, for
+    batches at the ladder's scale; below it no update has a choice and
+    neither counter moves."""
     from spark_rapids_tpu.columnar.batch import read_row_counts
     from spark_rapids_tpu.exec import kernel_abi, kernel_cache as kc
     from spark_rapids_tpu.mem.spill import register_or_hold
@@ -999,6 +1207,13 @@ def _shrink_partials(partials: List, grouped: bool) -> None:
                    for b in batches):
                 reg.inc("agg.partials.read")
             counts = read_row_counts(batches, wait_span="agg.countWait")
+            first = len(batches) - n_updates
+            for b, n in zip(batches[first:], counts[first:]):
+                if _ladder_engages(b.capacity):   # else: no choice built
+                    dense = n <= _DENSE_MAX_GROUPS and \
+                        _dense_built(b.capacity, True, specs)
+                    reg.inc("agg.update.dense" if dense
+                            else "agg.update.sorted")
         shrunk = rows_cut = 0
         for i, (b, n) in enumerate(zip(batches, counts)):
             tier = bucket_rows(n)
