@@ -214,10 +214,14 @@ SCAN_METADATA_CACHE_ENABLED = conf(
     "compiled-kernel cache).", bool)
 
 SCAN_METADATA_CACHE_MAX_BYTES = conf(
-    "spark.rapids.tpu.sql.scan.metadataCache.maxBytes", 256 << 20,
+    "spark.rapids.tpu.sql.scan.metadataCache.maxBytes", 4 << 30,
     "Byte budget for the scan metadata/plan cache; least-recently-used "
     "files evict (whole-file granularity) when cached run tables and "
-    "packed page buffers exceed it.", int)
+    "packed page buffers exceed it.  What the plans leave of it holds "
+    "the assembled upload sets of whole scan batches in device memory "
+    "(dropped first, and all of them under memory pressure): a fact "
+    "table whose sets do not fit is walked, packed and uploaded again "
+    "every query (1.3 GB of pages a scan at TPC-DS SF10).", int)
 
 SCAN_HOST_PREP_THREADS = conf(
     "spark.rapids.tpu.sql.scan.hostPrep.threads", 4,

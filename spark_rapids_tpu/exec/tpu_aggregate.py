@@ -1128,14 +1128,15 @@ class TpuHashAggregateExec(TpuExec):
                         inc["sink"].update_batches = n_updates
                     yield self._final_kernel(empty)
                     return
-                _shrink_partials(partials, bool(self.groupings),
-                                 self.specs, n_updates)
+                held = _shrink_partials(partials, bool(self.groupings),
+                                        self.specs, n_updates)
                 if len(partials) == 1:
                     merged = partials[0].get()
                 else:
                     whole = concat_batches([p.get() for p in partials])
                     with timed(self.metrics, "agg.merge"):
                         merged = self._merge_kernel(whole)
+                    reg.inc("agg.merge.rowsIn", held)
                 if inc is not None and inc.get("sink") is not None:
                     # freeze the pre-finalize merged state host-side:
                     # the next append-only drift merges forward from
@@ -1150,6 +1151,10 @@ class TpuHashAggregateExec(TpuExec):
                 out = self._final_kernel(merged)
                 self.metrics.add_rows(out.num_rows)
                 yield out
+                if len(partials) > 1:
+                    # the consumer is done with the batch, so the count
+                    # is long computed: no wait, no dispatch
+                    reg.inc("agg.merge.groupsOut", int(out.num_rows))
             finally:
                 for p in partials:
                     p.close()
@@ -1161,7 +1166,7 @@ class TpuHashAggregateExec(TpuExec):
 
 def _shrink_partials(partials: List, grouped: bool,
                      specs: Sequence[_AggSpec] = (),
-                     n_updates: int = 0) -> None:
+                     n_updates: int = 0) -> int:
     """Size the buffered partials by what they hold.  An update emits
     its partial at the input batch's capacity with its group count on
     the device, so four groups out of a 4M-row batch sit in a 4M-row
@@ -1189,7 +1194,11 @@ def _shrink_partials(partials: List, grouped: bool,
     and ``specs`` (_dense_built), and took it by its group count.
     Counted here as ``agg.update.dense`` / ``agg.update.sorted``, for
     batches at the ladder's scale; below it no update has a choice and
-    neither counter moves."""
+    neither counter moves.
+
+    Returns the rows the partials hold together (counted as
+    ``agg.partials.groups`` where they were read): what a merge of
+    them takes in."""
     from spark_rapids_tpu.columnar.batch import read_row_counts
     from spark_rapids_tpu.exec import kernel_abi, kernel_cache as kc
     from spark_rapids_tpu.mem.spill import register_or_hold
@@ -1231,6 +1240,10 @@ def _shrink_partials(partials: List, grouped: bool,
     if shrunk:
         reg.inc("agg.partials.shrunk", shrunk)
         reg.inc("agg.partials.rowsCut", rows_cut)
+    held = int(sum(counts))
+    if grouped:
+        reg.inc("agg.partials.groups", held)
+    return held
 
 
 def _make_empty_buffer_batch(exec_: TpuHashAggregateExec) -> DeviceBatch:

@@ -1159,6 +1159,8 @@ class _NestedLoopBase(TpuExec):
         if nl == 0 or nr == 0:
             return
         from spark_rapids_tpu.exec import kernel_cache as kc
+        from spark_rapids_tpu.obs import registry as obsreg
+        obsreg.get_registry().inc("join.path.product")
         # same dispatch-boundary canonicalization as the hash joins:
         # the kernel builds its output with positional names (the
         # condition reads by ordinal), the real schema restamps after

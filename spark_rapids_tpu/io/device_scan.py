@@ -280,21 +280,28 @@ class TpuParquetScanExec(TpuExec):
                 for h in handles.values():
                     h.close()
 
-        def _prep(idx, path_rgs):
-            """Prepare with a sharing claim: markers are ("solo"/"lead"/
-            "join", entry, prepared).  A joined claim skips the host
-            prep (and so the page walks) entirely."""
-            if share is None or share_keys[idx] is None:
-                return ("solo", None, prepare(path_rgs))
-            role, entry = share.claim(share_keys[idx])
-            if role == "join":
-                return ("join", entry, None)
+        def _lead(kind, entry, path_rgs):
             try:
-                return ("lead", entry, prepare(path_rgs))
+                return (kind, entry, prepare(path_rgs))
             except BaseException as e:
                 share.fail(entry, e)
                 share.release(entry)
                 raise
+
+        def _prep(idx, path_rgs, ahead=True):
+            """Prepare with a sharing claim: markers are ("solo"/"lead"/
+            "ahead"/"join", entry, prepared).  A joined claim skips the
+            host prep (and so the page walks) entirely.  A leader that
+            decodes at once takes the right to with its claim
+            ("lead"); one that prepares ahead of its consumer (the
+            look-ahead threads) leaves it open ("ahead") until
+            ``_resolve``."""
+            if share is None or share_keys[idx] is None:
+                return ("solo", None, prepare(path_rgs))
+            role, entry = share.claim(share_keys[idx], not ahead)
+            if role == "join":
+                return ("join", entry, None)
+            return _lead("ahead" if ahead else "lead", entry, path_rgs)
 
         def _finish_marker(marker, pv) -> DeviceBatch:
             """Dispatch one non-join marker's decode (caller holds the
@@ -321,8 +328,22 @@ class TpuParquetScanExec(TpuExec):
             real decode work — never while waiting on another query's
             flight (the leader's decode needs a slot)."""
             while True:
-                kind, entry, _prepared = marker
-                if kind != "join":
+                kind, entry, prepared = marker
+                if kind == "join" and share.begin(entry):
+                    # the leader prepared ahead and its consumer has
+                    # not come; it may be waiting on this very scan
+                    # (two scans of one table in one query: the build
+                    # side's join the flights the stream side's
+                    # look-ahead leads).  Decode in its place.
+                    marker = _lead("lead", entry, path_rgs)
+                elif kind == "ahead" and share.begin(entry):
+                    marker = ("lead", entry, prepared)
+                elif kind == "ahead":
+                    # a subscriber decoded in this leader's place
+                    for h in prepared[1].values():
+                        h.close()
+                    marker = ("join", entry, None)
+                if marker[0] != "join":
                     with tpu_semaphore(self.metrics):
                         return _finish_marker(marker, pv)
                 try:
@@ -341,16 +362,17 @@ class TpuParquetScanExec(TpuExec):
                 # the leader failed or abandoned its flight: decode
                 # locally under a FRESH claim, so a later subscriber
                 # can still share this decode
-                marker = _prep(idx, path_rgs)
+                marker = _prep(idx, path_rgs, ahead=False)
 
         def _cleanup(marker) -> None:
             kind, entry, prepared = marker
             if prepared is not None:
                 for h in prepared[1].values():
                     h.close()
-            if kind == "lead":
-                share.fail(entry,
-                           RuntimeError("scan flight abandoned"))
+            if kind == "ahead":
+                if share.begin(entry):     # else a subscriber decodes it
+                    share.fail(entry,
+                               RuntimeError("scan flight abandoned"))
                 share.release(entry)
             elif kind == "join":
                 share.release(entry)
@@ -379,7 +401,7 @@ class TpuParquetScanExec(TpuExec):
                     # OUTSIDE the semaphore instead)
                     out = None
                     with tpu_semaphore(self.metrics):
-                        marker = _prep(idx, path_rgs)
+                        marker = _prep(idx, path_rgs, ahead=False)
                         if marker[0] != "join":
                             out = _finish_marker(marker, pv)
                     if out is None:

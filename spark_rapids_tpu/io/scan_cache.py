@@ -55,7 +55,7 @@ from spark_rapids_tpu.obs import registry as _obsreg
 
 _LOCK = threading.RLock()
 _ENABLED = True
-_MAX_BYTES = 256 << 20
+_MAX_BYTES = 4 << 30
 
 # skey -> _FileEntry, LRU order (oldest first)
 _FILES: "OrderedDict[Tuple, _FileEntry]" = OrderedDict()

@@ -31,6 +31,16 @@ Lifecycle of one key::
                               the subscriber then decodes locally under
                               a FRESH claim (so a third query can still
                               share ITS decode)
+    any:   begin(e)           the right to decode.  A leader that claimed
+                              from a look-ahead thread takes it when its
+                              consumer arrives; a subscriber that finds
+                              it untaken decodes in the leader's place
+                              (the leader's consumer may be waiting on
+                              this very subscriber: two scans of one
+                              table in one query, the build side's
+                              joining flights the stream side's
+                              look-ahead leads), and the leader then
+                              takes the published batch like a joiner
     all:   release(e)         refcounted; the batch's HBM frees when the
                               last reference drops AND the retention
                               window has let go
@@ -75,7 +85,7 @@ class _Entry:
 
     __slots__ = ("key", "event", "batch", "error", "nbytes", "refs",
                  "joined", "served", "settled", "in_window",
-                 "multicast_counted", "released")
+                 "multicast_counted", "released", "finishing")
 
     def __init__(self, key: Tuple):
         self.key = key
@@ -90,6 +100,7 @@ class _Entry:
         self.in_window = False
         self.multicast_counted = False
         self.released = False
+        self.finishing = False   # some claimant is decoding (begin)
 
 
 class ScanShare:
@@ -110,11 +121,13 @@ class ScanShare:
             self._evict_locked()
 
     # -- claim / settle ----------------------------------------------------
-    def claim(self, key: Tuple):
+    def claim(self, key: Tuple, finishing: bool = False):
         """("lead", entry) for the first claimant of an open key,
         ("join", entry) for everyone arriving while the flight is open
         or the batch is retained.  Every claim (either role) owns one
-        reference and MUST release it."""
+        reference and MUST release it.  ``finishing``: a leader that
+        decodes at once takes the right to with its claim; one that
+        claims ahead of its consumer takes it later (:meth:`begin`)."""
         with self._lock:
             e = self._inflight.get(key)
             if e is None:
@@ -123,12 +136,22 @@ class ScanShare:
                     self._window.move_to_end(key)
             if e is None:
                 e = _Entry(key)
+                e.finishing = finishing
                 self._inflight[key] = e
                 return "lead", e
             e.refs += 1
             e.joined += 1
         obsreg.get_registry().inc("scan.shared.subscribers")
         return "join", e
+
+    def begin(self, e: _Entry) -> bool:
+        """Take the right to decode an open flight.  False: another
+        claimant holds it or the flight is settled; wait for it."""
+        with self._lock:
+            if e.settled or e.finishing:
+                return False
+            e.finishing = True
+            return True
 
     def publish(self, e: _Entry, batch) -> None:
         """Leader settle: the decoded batch enters the retention window

@@ -737,9 +737,13 @@ def size_estimate(node: LogicalPlan) -> int:
     (the role of Spark's plan statistics feeding
     spark.sql.autoBroadcastJoinThreshold)."""
     import os
+    from spark_rapids_tpu.plan import stats
     if isinstance(node, InMemoryScan):
         return node.table.nbytes
     if isinstance(node, FileScan):
+        read = stats.scan_bytes(node)
+        if read is not None:
+            return read            # the chunks a pruned scan reads
         total = 0
         for p in node.paths:
             try:
@@ -754,7 +758,14 @@ def size_estimate(node: LogicalPlan) -> int:
         return max(0, n) * 8
     if isinstance(node, Filter):
         return size_estimate(node.children[0]) // 2
-    if isinstance(node, (Aggregate, Limit)):
+    if isinstance(node, Aggregate):
+        half = size_estimate(node.children[0]) // 2
+        if not node.groupings:
+            return half
+        # its keys' ranges in the files' footers bound the groups
+        bound = stats.aggregate_bytes(node)
+        return half if bound is None else min(half, bound)
+    if isinstance(node, Limit):
         return size_estimate(node.children[0]) // 2
     if isinstance(node, Join):
         return sum(size_estimate(c) for c in node.children)

@@ -124,18 +124,116 @@ def _key_pair(c: ir.Expression, n_l: int, lschema: Schema,
         a, b = b, a
     lf = lschema.fields[a.ordinal]
     rf = rschema.fields[b.ordinal - n_l]
-    # a join key is found by name on its side; float keys are left
-    # to the filter (the join normalizes NaN and -0.0, ``=`` need not)
+    # a join key is found by name on its side
     if lschema.names.count(lf.name) != 1 or \
             rschema.names.count(rf.name) != 1:
         return None
-    for d in (lf.dtype, rf.dtype):
-        if d.is_nested or d.is_floating:
-            return None
-    if lf.dtype != rf.dtype and not (lf.dtype.is_numeric
-                                     and rf.dtype.is_numeric):
+    if not _key_dtypes_ok(lf.dtype, rf.dtype):
         return None
     return lf.name, rf.name
+
+
+def _key_dtypes_ok(ld, rd) -> bool:
+    """Whether a join can take an equality of these two as a key:
+    float keys are left to the filter (the join normalizes NaN and
+    -0.0, ``=`` need not)."""
+    for d in (ld, rd):
+        if d.is_nested or d.is_floating:
+            return False
+    return ld == rd or (ld.is_numeric and rd.is_numeric)
+
+
+def _product_inputs(node: lp.LogicalPlan, out: List[lp.LogicalPlan]
+                    ) -> List[lp.LogicalPlan]:
+    """The inputs of a tree of bare products (``FROM a, b, c``), in the
+    text's order; a join with keys, a condition or a hint is an input,
+    not part of the tree."""
+    if isinstance(node, lp.Join) and node.how == "cross" and \
+            not node.left_keys and node.condition is None and \
+            node.hint is None:
+        for c in node.children:
+            _product_inputs(c, out)
+    else:
+        out.append(node)
+    return out
+
+
+def _connected_order(n: int, edges: Set[Tuple[int, int]],
+                     residuals: List[Set[int]]) -> List[int]:
+    """An order of ``n`` relations in which each, where the equalities
+    allow it, meets one already placed.  No statistics: the relation
+    with the most equality partners comes first (the hub of a star is
+    its fact side, and the first input is the one the joins stream);
+    then, of the relations an equality ties to those placed, one that
+    completes a conjunct over several relations (that join filters, so
+    it goes before the ones that only widen), else the first in the
+    text.  Relations no equality reaches follow in the text's order:
+    their joins stay products."""
+    partners = [len({b if a == i else a for a, b in edges if i in (a, b)})
+                for i in range(n)]
+    order = [max(range(n), key=lambda i: (partners[i], -i))]
+    while len(order) < n:
+        placed = set(order)
+        rest = [i for i in range(n) if i not in placed]
+        tied = [i for i in rest
+                if any((min(i, j), max(i, j)) in edges for j in placed)]
+        filtering = [i for i in tied
+                     if any(i in r and r <= placed | {i}
+                            for r in residuals)]
+        order.append((filtering or tied or rest)[0])
+    return order
+
+
+def _order_products(f: lp.Filter, counts: List[int]
+                    ) -> Optional[lp.LogicalPlan]:
+    """``Filter`` over a tree of three or more bare products whose
+    text order leaves a join without a key though the conjuncts'
+    equalities tie its inputs together: the same inputs in a connected
+    order, under a projection that puts the columns back where the
+    text had them, so no ancestor sees a change.  ``None`` where the
+    text's order already has a key at every join (those plans, and
+    their programs, stay as they are) or nothing ties the inputs."""
+    rels = _product_inputs(f.children[0], [])
+    if len(rels) < 3:
+        return None
+    widths = [len(r.schema.names) for r in rels]
+    starts = [sum(widths[:i]) for i in range(len(rels))]
+    fields = [fl for r in rels for fl in r.schema.fields]
+    rel_of = [i for i, w in enumerate(widths) for _ in range(w)]
+    edges: Set[Tuple[int, int]] = set()
+    residuals: List[Set[int]] = []
+    for c in _conjuncts(f.condition):
+        touched = {rel_of[o] for o in _refs([c])}
+        if len(touched) < 2:
+            continue
+        if isinstance(c, ir.EqualTo) and len(touched) == 2 and all(
+                isinstance(x, ir.BoundReference) for x in c.children) \
+                and _key_dtypes_ok(*(fields[x.ordinal].dtype
+                                     for x in c.children)):
+            edges.add((min(touched), max(touched)))
+        else:
+            residuals.append(touched)
+    if all(any((j, k) in edges for j in range(k))
+           for k in range(1, len(rels))):
+        return None
+    order = _connected_order(len(rels), edges, residuals)
+    if order == list(range(len(rels))):
+        return None
+    tree = rels[order[0]]
+    for i in order[1:]:
+        tree = lp.Join(tree, rels[i], [], [], "cross")
+    new_starts, at = {}, 0
+    for i in order:
+        new_starts[i] = at
+        at += widths[i]
+    moved = {o: new_starts[rel_of[o]] + o - starts[rel_of[o]]
+             for o in range(len(fields))}
+    counts[2] += sum(1 for k in range(1, len(order))
+                     if order[k] != k or set(order[:k]) != set(range(k)))
+    back = [ir.BoundReference(moved[o], fl.dtype, fl.nullable, fl.name)
+            for o, fl in enumerate(fields)]
+    return lp.Project(lp.Filter(tree, _remap_expr(f.condition, moved)),
+                      [ir.Alias(b, fl.name) for b, fl in zip(back, fields)])
 
 
 def _push_through_join(f: lp.Filter, counts: List[int]
@@ -186,6 +284,11 @@ def _push_through_join(f: lp.Filter, counts: List[int]
 
 def _rewrite_joins(node: lp.LogicalPlan, counts: List[int]
                    ) -> lp.LogicalPlan:
+    if isinstance(node, lp.Filter) and \
+            isinstance(node.children[0], lp.Join):
+        ordered = _order_products(node, counts)
+        if ordered is not None:
+            node = ordered
     while isinstance(node, lp.Filter) and \
             isinstance(node.children[0], lp.Join):
         pushed = _push_through_join(node, counts)
@@ -209,17 +312,24 @@ def rewrite_implicit_joins(plan: lp.LogicalPlan) -> lp.LogicalPlan:
     it again if that side is a join, and a scan's own filter push if it
     is a scan; what is left stays above.  An outer join passes a
     conjunct only to its preserved side, a semi or anti join to its
-    left.  Joins keep the order the text gives them.  Counted under
+    left.  Joins keep the order the text gives them wherever that
+    order has a key at every join; a ``FROM a, b, c`` of three or more
+    relations whose text order is no join order (``a`` and ``b`` share
+    no equality, both meet ``c``) is put into a connected one first
+    (``_order_products``, docs/joins.md).  Counted under
     ``plan.rewrite.implicitJoins`` (joins that went from a product to
-    keys) and ``plan.rewrite.pushedConjuncts`` (conjuncts moved under
-    a join, once a join passed)."""
-    counts = [0, 0]
+    keys), ``plan.rewrite.pushedConjuncts`` (conjuncts moved under
+    a join, once a join passed) and ``plan.rewrite.reorderedJoins``
+    (joins whose inputs are not the ones the text's order gave
+    them)."""
+    counts = [0, 0, 0]
     new = _rewrite_joins(plan, counts)
-    if counts[0] or counts[1]:
+    if any(counts):
         from spark_rapids_tpu.obs import registry as obsreg
         obsreg.get_registry().inc_many(
             ("plan.rewrite.implicitJoins", counts[0]),
-            ("plan.rewrite.pushedConjuncts", counts[1]))
+            ("plan.rewrite.pushedConjuncts", counts[1]),
+            ("plan.rewrite.reorderedJoins", counts[2]))
     return new
 
 
@@ -272,13 +382,23 @@ def _rewrite(node: lp.LogicalPlan, needed: Optional[Set[int]]
 
     # ---- single-child nodes ----------------------------------------------
     if isinstance(node, lp.Project):
-        child, m = _rewrite(node.children[0], _refs(node.exprs))
-        if m is None:
-            if child is node.children[0]:
+        # a projection computes only what its parent reads (the
+        # projection that puts a reordered join's columns back where
+        # the text had them names every column of every input)
+        keep = None if needed is None else (sorted(needed) or [0])
+        exprs = node.exprs if keep is None \
+            else [node.exprs[o] for o in keep]
+        child, m = _rewrite(node.children[0], _refs(exprs))
+        if m is not None:
+            exprs = _remap_all(exprs, m)
+        if keep is None:
+            if m is None and child is node.children[0]:
                 return node, None
-            return _shallow(node, children=(child,)), None
-        return _shallow(node, children=(child,),
-                        exprs=_remap_all(node.exprs, m)), None
+            return _shallow(node, children=(child,), exprs=exprs), None
+        return _shallow(
+            node, children=(child,), exprs=exprs,
+            _schema=Schema([node.schema.fields[o] for o in keep])), \
+            {o: i for i, o in enumerate(keep)}
     if isinstance(node, lp.Aggregate):
         child, m = _rewrite(node.children[0],
                             _refs(node.groupings) |

@@ -280,12 +280,30 @@ _register_functions()
 
 
 class _Scope:
-    """Column resolution scope: output column names + alias→names map."""
+    """Column resolution scope: output column names + alias→names map.
+
+    The engine keeps flat output schemas, so where two join inputs
+    share a column name the right input's copy is carried under an
+    internal name (``Parser.rename_apart``).  ``by_alias`` keeps the
+    names the text uses; ``renamed`` maps ``(alias, name)`` to the
+    internal name a qualified reference resolves to, ``visible`` maps
+    it back for a select item's output name, and a bare reference to
+    a name in ``ambiguous`` is an error, as in SQL."""
 
     def __init__(self, names: List[str],
-                 by_alias: Optional[Dict[str, List[str]]] = None):
+                 by_alias: Optional[Dict[str, List[str]]] = None,
+                 renamed: Optional[Dict[Tuple[str, str], str]] = None,
+                 ambiguous=()):
         self.names = list(names)
         self.by_alias = dict(by_alias or {})
+        self.renamed = dict(renamed or {})
+        self.visible = {v: k[1] for k, v in self.renamed.items()}
+        self.ambiguous = set(ambiguous)
+
+    def joined(self, right: "_Scope", names: List[str]) -> "_Scope":
+        return _Scope(names, {**self.by_alias, **right.by_alias},
+                      {**self.renamed, **right.renamed},
+                      self.ambiguous | right.ambiguous)
 
 
 class Parser:
@@ -299,6 +317,7 @@ class Parser:
         # declared dtype; undeclared parameters are parse errors)
         self.param_types = dict(param_types or {})
         self.params_seen: Dict[str, object] = {}
+        self._renames = 0             # internal names handed out
 
     # -- token helpers ----------------------------------------------------
     def peek(self, k: int = 0) -> Token:
@@ -601,8 +620,10 @@ class Parser:
                 alias = it[1].lower()
                 if alias not in scope.by_alias:
                     raise SqlParseError(f"unknown table alias '{it[1]}'")
-                out.extend(ir.UnresolvedAttribute(n)
-                           for n in scope.by_alias[alias])
+                for n in scope.by_alias[alias]:
+                    new = scope.renamed.get((alias, n), n)
+                    e = ir.UnresolvedAttribute(new)
+                    out.append(e if new == n else ir.Alias(e, n))
             else:
                 _, start, end, alias = it
                 save = self.i
@@ -617,6 +638,9 @@ class Parser:
                         f"could not parse select item near "
                         f"{bad.value!r} at position {bad.pos}")
                 self.i = save
+                if not alias and isinstance(e, ir.UnresolvedAttribute):
+                    # sc.k shows as k, whatever name carries it
+                    alias = scope.visible.get(e.attr_name)
                 out.append(ir.Alias(e, alias) if alias else e)
         return out
 
@@ -713,7 +737,8 @@ class Parser:
         plan, scope = self.relation()
         while True:
             if self.accept("op", ","):
-                right, rscope = self.relation()
+                right, rscope = self.rename_apart(plan, scope,
+                                                  *self.relation())
                 plan, scope = self.join_plans(plan, scope, right, rscope,
                                               "cross", None, None)
                 continue
@@ -742,10 +767,14 @@ class Parser:
             right, rscope = self.relation()
             on = None
             using = None
+            at_using = self.peek().kind == "kw" and \
+                self.peek().value == "using"
+            if how not in ("semi", "anti") and not at_using:
+                right, rscope = self.rename_apart(plan, scope, right,
+                                                  rscope)
             if self.kw("on"):
-                joint = _Scope(scope.names + rscope.names,
-                               {**scope.by_alias, **rscope.by_alias})
-                on = self.expr(joint)
+                on = self.expr(scope.joined(rscope,
+                                            scope.names + rscope.names))
             elif self.kw("using"):
                 self.expect("op", "(")
                 using = [self.expect("name").value]
@@ -778,6 +807,35 @@ class Parser:
         scope = _Scope(plan.schema.names,
                        {alias.lower(): list(plan.schema.names)})
         return plan, scope
+
+    def rename_apart(self, left, lscope: _Scope, right, rscope: _Scope
+                     ) -> Tuple[lp.LogicalPlan, _Scope]:
+        """``right`` with every column whose name ``left`` also has
+        carried under an internal name, and the scope through which
+        ``alias.name`` still finds it.  The bare name turns ambiguous;
+        a relation with no alias to qualify it by keeps the error."""
+        overlap = [n for n in right.schema.names
+                   if n in set(left.schema.names)]
+        if not overlap:
+            return right, rscope
+        internal = {}
+        for n in overlap:
+            owners = [a for a, ns in rscope.by_alias.items()
+                      if rscope.renamed.get((a, n), n) == n and n in ns]
+            if not owners:
+                return right, rscope       # join_plans reports it
+            self._renames += 1
+            internal[n] = (f"{n}__{self._renames}", owners)
+        plan = lp.Project(right, [
+            ir.Alias(ir.UnresolvedAttribute(n), internal[n][0])
+            if n in internal else ir.UnresolvedAttribute(n)
+            for n in right.schema.names])
+        renamed = dict(rscope.renamed)
+        for n, (new, owners) in internal.items():
+            for a in owners:
+                renamed[(a, n)] = new
+        return plan, _Scope(plan.schema.names, rscope.by_alias, renamed,
+                            rscope.ambiguous | set(overlap))
 
     def lookup(self, name: str) -> lp.LogicalPlan:
         key = name.lower()
@@ -826,14 +884,10 @@ class Parser:
                                  [rename[k] for k in right_keys], how,
                                  condition=condition)
                 out = lp.Project(joined, proj)
-            scope = _Scope(out.schema.names,
-                           {**lscope.by_alias, **rscope.by_alias})
-            return out, scope
+            return out, lscope.joined(rscope, out.schema.names)
         joined = lp.Join(left, right, left_keys, right_keys, how,
                          condition=condition)
-        scope = _Scope(joined.schema.names,
-                       {**lscope.by_alias, **rscope.by_alias})
-        return joined, scope
+        return joined, lscope.joined(rscope, joined.schema.names)
 
     # -- expressions ------------------------------------------------------
     def expr(self, scope: _Scope) -> ir.Expression:
@@ -1084,7 +1138,12 @@ class Parser:
             if colname not in scope.by_alias[alias]:
                 raise SqlParseError(
                     f"column '{colname}' not found in '{name}'")
-            return ir.UnresolvedAttribute(colname)
+            return ir.UnresolvedAttribute(
+                scope.renamed.get((alias, colname), colname))
+        if name in scope.ambiguous:
+            raise SqlParseError(
+                f"column '{name}' is ambiguous: qualify it with its "
+                f"table's alias")
         return ir.UnresolvedAttribute(name)
 
 
