@@ -287,6 +287,7 @@ class TpuSparkSession:
         plan = optimizer.rewrite_implicit_joins(plan)
         if self.conf.get(cfg.COLUMN_PRUNING):
             plan = optimizer.prune_columns(plan)
+        plan = optimizer.mark_equal_aggregates(plan)
         cpu_plan = plan_cpu(plan, self.conf)
         result = TpuOverrides.apply(cpu_plan, self.conf)
         if self.conf.test_enabled:

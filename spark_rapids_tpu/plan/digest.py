@@ -235,24 +235,47 @@ def safe_plan_digest(plan) -> Optional[str]:
         return None
 
 
+def node_hashes(plan: lp.LogicalPlan) -> dict:
+    """``id(node)`` -> hash for every node of the plan, from one walk
+    (the memo of :func:`_node_hash`): equal values are equal results,
+    whatever the nodes' output names."""
+    memo: dict = {}
+    _node_hash(plan, memo)
+    return memo
+
+
+def _node_cacheable(node: lp.LogicalPlan) -> bool:
+    """May this one node's result be served a second time?  False for
+    an in-memory table too large to hash by content, an opaque user
+    function, or an expression of ``_NONDETERMINISTIC_EXPRS``."""
+    if isinstance(node, lp.InMemoryScan):
+        return node.table.nbytes <= _INMEM_HASH_CAP
+    if getattr(node, "fn", None) is not None:
+        return False               # opaque user function (pandas/UDF)
+    return not any(
+        ir.collect(e, lambda n: type(n).__name__ in _NONDETERMINISTIC_EXPRS)
+        for e in iter_node_exprs(node))
+
+
+def subtree_cacheable(node: lp.LogicalPlan, memo: dict) -> bool:
+    """:func:`_node_cacheable` of every node under (and including)
+    ``node``; ``memo`` (by node identity) is shared across calls so a
+    plan is walked once however many subtrees are asked about."""
+    hit = memo.get(id(node))
+    if hit is None:
+        hit = memo[id(node)] = _node_cacheable(node) and all(
+            subtree_cacheable(c, memo) for c in node.children)
+    return hit
+
+
 def plan_fingerprint(plan: lp.LogicalPlan) -> PlanFingerprint:
     """Digest + result-cache admissibility (module docstring)."""
     digest = plan_digest(plan)
     sources: list = []
-    cacheable = True
     for node in walk(plan):
         if isinstance(node, lp.FileScan):
             import os
             sources.extend(os.path.abspath(p) for p in node.paths)
-        elif isinstance(node, lp.InMemoryScan):
-            if node.table.nbytes > _INMEM_HASH_CAP:
-                cacheable = False
-        elif getattr(node, "fn", None) is not None:
-            cacheable = False          # opaque user function (pandas/UDF)
-        for e in iter_node_exprs(node):
-            if ir.collect(e, lambda n: type(n).__name__
-                          in _NONDETERMINISTIC_EXPRS):
-                cacheable = False
     return PlanFingerprint(digest=digest,
                            sources=tuple(sorted(set(sources))),
-                           cacheable=cacheable)
+                           cacheable=subtree_cacheable(plan, {}))

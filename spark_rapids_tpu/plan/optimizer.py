@@ -1,5 +1,6 @@
 """Logical optimizations: implicit joins to equi-joins with their
-one-sided conjuncts pushed under them, then column pruning.
+one-sided conjuncts pushed under them, then column pruning, then the
+marking of equal aggregates for in-query reuse.
 
 Reference analog: Spark's ``PushPredicateThroughJoin`` and
 ``ColumnPruning`` rules, which the reference plugin inherits from
@@ -14,6 +15,9 @@ is a top-down required-ordinal analysis over the bound logical plan,
 then a bottom-up rebuild that narrows ``FileScan``/``InMemoryScan``
 leaves and remaps every ancestor's ``BoundReference`` ordinals through
 the changed schemas.
+
+``mark_equal_aggregates`` runs last, on the pruned plan, and changes
+no schema either: it stamps copies (docs/work_sharing.md).
 
 Pruning a scan matters twice on TPU: the device parquet decode skips
 whole column chunks (the q6 bench decodes 4 of 6 columns), and
@@ -331,6 +335,86 @@ def rewrite_implicit_joins(plan: lp.LogicalPlan) -> lp.LogicalPlan:
             ("plan.rewrite.pushedConjuncts", counts[1]),
             ("plan.rewrite.reorderedJoins", counts[2]))
     return new
+
+
+# ---------------------------------------------------------------------------
+# in-query reuse
+# ---------------------------------------------------------------------------
+
+def mark_equal_aggregates(plan: lp.LogicalPlan) -> lp.LogicalPlan:
+    """Aggregates of one query that give the same result are computed
+    once (docs/work_sharing.md, in-query reuse).
+
+    Equal is ``plan/digest``'s node hash: output names and aliases do
+    not count, literals, files and join kinds do.  Runs on the pruned
+    plan, where q65's derived table, written once under ``sb`` and once
+    as ``sc``, hashes alike; a ``WITH`` name referenced twice arrives
+    as one node with two parents and is its own duplicate.  Each
+    occurrence of such an aggregate comes back as a copy stamped
+    ``_reuse`` with the hash (a private attribute, as ``_incremental``
+    is: the digest never sees it); the planner carries the stamp to the
+    exec and ``TpuOverrides.apply`` ties equal stamps to one
+    computation.  Only ``Aggregate`` roots: the result is whole when it
+    yields and small beside its input (two equal scans are
+    ``io/scan_share``'s).  Only the outermost: under a later occurrence
+    nothing is looked for, since nothing there will run.  Never a
+    subtree ``plan_fingerprint`` would not let the result cache serve
+    (``rand()`` written twice is two draws), nor an aggregate under
+    incremental maintenance.  A plan with no two equal aggregates is
+    returned as it came."""
+    def aggregates(n: lp.LogicalPlan) -> int:
+        return isinstance(n, lp.Aggregate) + \
+            sum(aggregates(c) for c in n.children)
+
+    if aggregates(plan) < 2:
+        return plan                 # nothing to compare: no hash walk
+    from spark_rapids_tpu.plan import digest
+    hashes = digest.node_hashes(plan)
+    cacheable: dict = {}
+
+    def key_of(n: lp.LogicalPlan) -> Optional[str]:
+        if isinstance(n, lp.Aggregate) and \
+                getattr(n, "_incremental", None) is None and \
+                digest.subtree_cacheable(n, cacheable):
+            return hashes[id(n)]
+        return None
+
+    # occurrences by hash, in the order the rewrite below meets them; a
+    # cached relation's child is planned apart (exec/cache.py)
+    met: Dict[str, int] = {}
+
+    def count(n: lp.LogicalPlan) -> None:
+        key = key_of(n)
+        if key is not None:
+            met[key] = met.get(key, 0) + 1
+            if met[key] > 1:
+                return
+        if not isinstance(n, lp.CachedRelation):
+            for c in n.children:
+                count(c)
+
+    count(plan)
+    if all(k < 2 for k in met.values()):
+        return plan
+    first: Set[str] = set()
+
+    def rewrite(n: lp.LogicalPlan) -> lp.LogicalPlan:
+        key = key_of(n)
+        if key is not None and met[key] < 2:
+            key = None
+        if key in first:
+            return _shallow(n, _reuse=key)
+        if isinstance(n, lp.CachedRelation):
+            return n
+        children = tuple(rewrite(c) for c in n.children)
+        if key is None:
+            if all(c is o for c, o in zip(children, n.children)):
+                return n
+            return _shallow(n, children=children)
+        first.add(key)
+        return _shallow(n, children=children, _reuse=key)
+
+    return rewrite(plan)
 
 
 # ---------------------------------------------------------------------------

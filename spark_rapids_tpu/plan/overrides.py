@@ -261,6 +261,10 @@ def _convert_hash_agg(n, ch, conf):
     inc = getattr(n, "_incremental", None)
     if inc is not None:
         out._incremental = inc
+    # in-query reuse stamp, tied in _tie_reused_subplans
+    reuse = getattr(n, "_reuse", None)
+    if reuse is not None:
+        out._reuse = reuse
     return out
 
 
@@ -715,6 +719,7 @@ class TpuOverrides:
             plan = fuse_stages(plan, conf)
         if conf.get(cfg.AGG_FUSED_FILTER):
             _fuse_filters_into_aggregates(plan)
+        plan = _tie_reused_subplans(plan)
         if plan.is_tpu:
             plan = tpub.DeviceToHostExec(plan)
         # stamp the session's donation setting on every node: execs read
@@ -772,6 +777,56 @@ def _fuse_filters_into_aggregates(plan: PhysicalPlan) -> None:
             rec(c)
 
     rec(plan)
+
+
+def _tie_reused_subplans(plan: PhysicalPlan) -> PhysicalPlan:
+    """Post-conversion pass: device aggregates that carry the same
+    ``_reuse`` stamp (optimizer.mark_equal_aggregates, through
+    plan_cpu and _convert_hash_agg) are one computation.  The first in
+    pre-order keeps its subtree, under a TpuReusedSubplanExec that owns
+    the result; each later one gives way, subtree and all, to a
+    childless TpuReusedSubplanExec that reads from the first and keeps
+    its own output schema (Spark's ReusedExchangeExec: the plan stays a
+    tree).  Equal subtrees convert alike under one conf, so a stamp
+    reaches this pass on all of its aggregates or (a CPU fallback) on
+    none.  Counted under ``plan.reuse.subplans``: occurrences
+    replaced."""
+    from spark_rapids_tpu.exec.reuse import TpuReusedSubplanExec
+    owners: Dict[str, TpuReusedSubplanExec] = {}
+
+    def rec(n: PhysicalPlan) -> PhysicalPlan:
+        key = getattr(n, "_reuse", None) \
+            if isinstance(n, TpuHashAggregateExec) else None
+        if key in owners:
+            owner = owners[key]
+            owner.consumers += 1
+            return TpuReusedSubplanExec(n.schema, key, owner.partitions,
+                                        source=owner)
+        children = tuple(rec(c) for c in n.children)
+        if any(c is not o for c, o in zip(children, n.children)):
+            n.children = children
+        if key is None:
+            return n
+        partitions = 1
+        if n.per_partition:
+            # one output partition a partition of the exchange
+            # underneath; a reader has to know how many before
+            # anything runs
+            part = getattr(n.children[0], "partitioning", None)
+            if part is None:
+                return n
+            partitions = part.num_partitions
+        owners[key] = TpuReusedSubplanExec(n.schema, key, partitions,
+                                           child=n)
+        return owners[key]
+
+    plan = rec(plan)
+    if owners:
+        from spark_rapids_tpu.obs import registry as obsreg
+        obsreg.get_registry().inc(
+            "plan.reuse.subplans",
+            sum(o.consumers - 1 for o in owners.values()))
+    return plan
 
 
 @dataclass

@@ -5,7 +5,8 @@ produces the "stock" CPU physical plan that TpuOverrides then rewrites
 (reference call stack: SURVEY.md §3.1).
 
 Planning pipeline (session ``_plan_physical``): ``prune_columns``
-(plan/optimizer.py) -> ``plan_cpu`` (here) -> ``TpuOverrides.apply``
+and ``mark_equal_aggregates`` (plan/optimizer.py) -> ``plan_cpu``
+(here) -> ``TpuOverrides.apply``
 (plan/overrides.py), which converts to Tpu execs and then runs the
 whole-stage fusion pass (plan/fusion.py) — Project/Filter chains
 collapse into single-dispatch ``TpuFusedStageExec`` nodes and
@@ -80,18 +81,24 @@ def plan_cpu(node: lp.LogicalPlan, conf: RapidsTpuConf) -> PhysicalPlan:
             child = ex.CpuShuffleExchangeExec(
                 child, ex.HashPartitioning(conf.shuffle_partitions,
                                            list(groupings)))
-            return cpux.CpuHashAggregateExec(child, groupings, aggs,
-                                             node.schema,
-                                             per_partition=True)
-        agg_exec = cpux.CpuHashAggregateExec(child, groupings, aggs,
-                                             node.schema)
-        # incremental-maintenance stamp (exec/incremental.py): ride the
-        # logical node's partial-capture/retained-state hooks through
-        # to the physical aggregate; a private attr so the plan digest
-        # and expression enumeration never see it
-        inc = getattr(node, "_incremental", None)
-        if inc is not None:
-            agg_exec._incremental = inc
+            agg_exec = cpux.CpuHashAggregateExec(child, groupings, aggs,
+                                                 node.schema,
+                                                 per_partition=True)
+        else:
+            agg_exec = cpux.CpuHashAggregateExec(child, groupings, aggs,
+                                                 node.schema)
+            # incremental-maintenance stamp (exec/incremental.py): ride
+            # the logical node's partial-capture/retained-state hooks
+            # through to the physical aggregate; a private attr so the
+            # plan digest and expression enumeration never see it
+            inc = getattr(node, "_incremental", None)
+            if inc is not None:
+                agg_exec._incremental = inc
+        # in-query reuse stamp (optimizer.mark_equal_aggregates): equal
+        # stamps are tied to one computation in TpuOverrides.apply
+        reuse = getattr(node, "_reuse", None)
+        if reuse is not None:
+            agg_exec._reuse = reuse
         return agg_exec
     if isinstance(node, lp.Limit):
         child = plan_cpu(node.children[0], conf)
