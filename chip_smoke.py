@@ -10,7 +10,14 @@ same files.  Every operator must be a ``Tpu*Exec``; a CPU fallback, a
 host-decoded scan column or a wrong answer exits non-zero.
 
     python chip_smoke.py                 # one chip, 28.8M rows
-    python chip_smoke.py --chips 4       # ONLY the ICI shuffle, 4 chips
+    python chip_smoke.py --chips 4       # ONLY the placed ICI path: q65
+
+With ``--chips 4`` it runs what the benchmark's four-chip cell runs
+(``tpcds-sf10.agg-ici4``): TPC-DS q65's text over the SF10 files of
+``benchmark/configs/tpcds-sf10-store-ici4.json``, the fact table's scan
+batches on the chips that scan them and the exchanges over ICI, against
+that configuration's pandas reference, so the smoke and the cell
+exercise one path.
 
 Lines printed before the last are observations (one JSON object each),
 not metrics.  The last line is the result.  With no TPU the script
@@ -27,7 +34,8 @@ import tempfile
 import time
 
 SF10_ROWS = 28_800_000      # TPC-DS SF10 store_sales
-SF1_ROWS = 2_880_000        # TPC-DS SF1 store_sales (--chips 4)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ICI_CONFIG = "tpcds-sf10-store-ici4"     # --chips 4: the cell's deployment
 ROWS_PER_FILE = 1_800_000   # one scan batch (reader.batchSizeRows 2^21)
 DICT_COLUMNS = ["ss_sold_date_sk", "ss_item_sk", "ss_quantity"]
 RTOL = 1e-9                 # float aggregates (the benchmark's limit)
@@ -43,13 +51,6 @@ Q3_SQL = ("select d_year, i_brand_id as brand_id, i_brand as brand, "
           "where d_moy = 11 and i_manufact_id <= 100 "
           "group by d_year, i_brand, i_brand_id "
           "order by d_year asc, sum_agg desc, brand_id asc limit 100")
-# the grouped aggregate and the shuffled join of the four-chip phase
-ICI_AGG_SQL = ("select ss_item_sk, count(*) as cnt, sum(ss_quantity) as "
-               "qty from store_sales group by ss_item_sk")
-ICI_JOIN_SQL = ("select i_category_id, count(*) as cnt, "
-                "sum(ss_quantity) as qty from store_sales join item "
-                "on ss_item_sk = i_item_sk group by i_category_id")
-
 BASE_CONF = {
     "spark.rapids.tpu.serve.enabled": True,             # port 0
     "spark.rapids.tpu.serve.resultCache.enabled": False,
@@ -359,78 +360,109 @@ def observations() -> None:
 
 
 # ---------------------------------------------------------------------------
-# four chips: the ICI shuffle against the local transport
+# four chips: q65 through the placed ICI path (the benchmark's four-chip cell)
 # ---------------------------------------------------------------------------
 
-def ici_phase(root: str) -> None:
-    """One grouped aggregate and one shuffled join with
-    ``shuffle.transport=ici`` on the mesh of every attached device,
-    compared with the same statements under ``local`` and with the
-    plain reference; prints each device's bytes after the exchange."""
-    import jax
-    import pyarrow.parquet as papq
+def _bench(*parts) -> str:
+    return os.path.join(HERE, "benchmark", *parts)
 
+
+def _bench_module(name: str, path: str):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def ici_config() -> dict:
+    with open(_bench("configs", f"{ICI_CONFIG}.json")) as f:
+        return json.load(f)
+
+
+def make_q65_data(root: str, rows, seed: int) -> dict:
+    """The configuration's four tables from its own generator;
+    ``rows`` (None: the configuration's 28,800,991) is ``store_sales``'
+    row count, the file counts are the configuration's."""
+    sys.path.insert(0, _bench())
+    try:
+        import datagen
+    finally:
+        sys.path.remove(_bench())
+    conf = ici_config()
+    tables = {t: dict(spec) for t, spec in conf["tables"].items()}
+    if rows:
+        tables["store_sales"]["rows"] = int(rows)
+    return datagen.generate(conf["datagen"], root, tables, seed)
+
+
+def ici_phase(root: str, conf: dict = None) -> None:
+    """q65's text under the four-chip configuration's conf
+    (``shuffle.transport=ici``, one shuffle partition a chip) on the
+    mesh of every attached device: the answer against the
+    configuration's pandas reference, every operator on the TPU, every
+    exchange over the whole mesh with no input moved to another chip
+    first, the fact table's scan batches spread over the chips; prints
+    each device's peak bytes."""
+    import jax
+
+    from spark_rapids_tpu import TpuSparkSession
+    from spark_rapids_tpu.mem.device import memory_peaks
+    from spark_rapids_tpu.obs import registry as obsreg
     from spark_rapids_tpu.serve.client import ServeClient
 
     n_dev = len(jax.devices())
-    conf = {"spark.rapids.tpu.sql.agg.exchange.enabled": True,
-            "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": -1,
-            "spark.rapids.tpu.sql.shuffle.partitions": n_dev}
-    answers = {}
-    for transport in ("local", "ici"):
-        spark = start_session(
-            root, {**conf, "spark.rapids.tpu.shuffle.transport": transport})
-        client = ServeClient("127.0.0.1", spark.serve_server.port)
-        try:
-            for name, sql, key in (("agg", ICI_AGG_SQL, "ss_item_sk"),
-                                   ("join", ICI_JOIN_SQL,
-                                    "i_category_id")):
-                got, wall, obs = timed(lambda: client.sql(sql))
-                nodes = assert_on_device(
-                    f"{name}[{transport}]", spark.last_query_profile(),
-                    ("ParquetScan", "HashAggregate", "Exchange") +
-                    (("Join",) if name == "join" else ()))
-                if transport == "ici":
-                    spans = [n.extra["ici_devices"] for n in nodes
-                             if "ici_devices" in n.extra]
-                    if not spans or set(spans) != {n_dev}:
-                        raise AssertionError(
-                            f"{name}[ici]: exchanges spanned {spans} "
-                            f"devices, not {n_dev}")
-                answers[name, transport] = got.sort_by(key)
-                say(phase=f"ici_{name}", transport=transport,
-                    rows=got.num_rows, wall_s=wall, **obs)
-            if transport == "ici":
-                # the CPU rehearsal's devices report no memory stats;
-                # a TPU's must, and every one must have held bytes
-                per_device = [
-                    d.memory_stats()["peak_bytes_in_use"]
-                    if d.platform == "tpu" else None
-                    for d in jax.devices()]
-                say(phase="ici_device_bytes", peak_bytes_in_use=per_device)
-                if 0 in per_device:
-                    raise AssertionError(
-                        f"a device held no bytes after the ICI "
-                        f"exchange: {per_device}")
-        finally:
-            client.close()
-            spark.serve_server.shutdown()
-    ss = papq.read_table(os.path.join(root, "store_sales"),
-                         columns=["ss_item_sk", "ss_quantity"])
-    it = papq.read_table(os.path.join(root, "item"),
-                         columns=["i_item_sk", "i_category_id"])
-    def count_and_qty(table, key):
-        return table.group_by(key).aggregate(
-            [([], "count_all"), ("ss_quantity", "sum")]).rename_columns(
-            {"count_all": "cnt", "ss_quantity_sum": "qty"}).sort_by(key)
-    want = {"agg": count_and_qty(ss, "ss_item_sk"),
-            "join": count_and_qty(ss.join(it, "ss_item_sk", "i_item_sk"),
-                                  "i_category_id")}
-    for name, key in (("agg", "ss_item_sk"), ("join", "i_category_id")):
-        for transport in ("local", "ici"):
-            assert_same(f"{name}[{transport}]", answers[name, transport],
-                        want[name], exact=(key, "cnt", "qty"), approx=())
-    say(phase="ici_vs_local", equal=True)
+    deployed = ici_config()
+    with open(_bench("sql", ICI_CONFIG, "q65.sql")) as f:
+        sql = " ".join(f.read().split())
+    reference = _bench_module("reference_q65_ici4",
+                              _bench("reference", ICI_CONFIG, "q65.py"))
+    compare = _bench_module("bench_compare", _bench("compare.py"))
+    spark = TpuSparkSession({**BASE_CONF, **deployed["conf"],
+                             **(conf or {})})
+    for name in deployed["tables"]:
+        spark.register_view(
+            name, spark.read.parquet(os.path.join(root, name)))
+    client = ServeClient("127.0.0.1", spark.serve_server.port)
+    try:
+        for execution in (1, 2):
+            view = obsreg.get_registry().view()
+            got, wall, obs = timed(lambda: client.sql(sql))
+            moved = view.delta()["counters"]
+            nodes = assert_on_device(
+                "q65[ici]", spark.last_query_profile(),
+                tuple(reference.SPEC["need_operators"]))
+            spans = [n.extra["ici_devices"] for n in nodes
+                     if "ici_devices" in n.extra]
+            if not spans or set(spans) != {n_dev}:
+                raise AssertionError(f"q65[ici]: exchanges spanned "
+                                     f"{spans} devices, not {n_dev}")
+            files = deployed["tables"]["store_sales"]["files"]
+            placed = {k: int(moved.get(k, 0)) for k in (
+                "scan.placed.chips", "scan.placed.batches",
+                "exchange.ici.exchanges", "exchange.ici.rowsIn",
+                "exchange.ici.bucketRows", "exchange.ici.movedBatches")}
+            if placed["scan.placed.chips"] < min(n_dev, files) or \
+                    placed["exchange.ici.movedBatches"]:
+                raise AssertionError(f"q65[ici]: not placed: {placed}")
+            say(phase="ici_q65", execution=execution, rows=got.num_rows,
+                wall_s=wall, **obs, **placed)
+        nums = compare.compare(got, reference.compute(root, {}),
+                               reference.SPEC, RTOL)
+        if nums["rows_diff"] or nums["key_mismatch"] or \
+                nums["float_rel_err"] > RTOL:
+            raise AssertionError(f"q65[ici]: off the reference: {nums}")
+        say(phase="ici_q65_vs_reference", **nums)
+        # the CPU rehearsal's devices report no memory stats; a TPU's
+        # must, and every one must have held bytes
+        peaks = memory_peaks()
+        say(phase="ici_device_bytes", peak_bytes_in_use=peaks)
+        if jax.devices()[0].platform == "tpu" and 0 in peaks:
+            raise AssertionError(f"a device held no bytes after the "
+                                 f"placed query: {peaks}")
+    finally:
+        client.close()
+        spark.serve_server.shutdown()
 
 
 # ---------------------------------------------------------------------------
@@ -447,11 +479,14 @@ def run(rows: int, seed: int, device: dict, ici: bool) -> None:
     root = tempfile.mkdtemp(prefix="chip_smoke_")
     try:
         t0 = time.perf_counter()
-        say(phase="data", seed=seed, **make_data(root, rows, seed),
-            wall_s=time.perf_counter() - t0)
         if ici:
+            say(phase="data", seed=seed,
+                tables=make_q65_data(root, rows, seed),
+                wall_s=time.perf_counter() - t0)
             ici_phase(root)
         else:
+            say(phase="data", seed=seed, **make_data(root, rows, seed),
+                wall_s=time.perf_counter() - t0)
             serve_phase(root)
             donation_reload_check()
         observations()
@@ -463,15 +498,16 @@ def run(rows: int, seed: int, device: dict, ici: bool) -> None:
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run ONLY the ICI-shuffle phase on four "
-                         "chips (SF1 slice)")
+                    help="4: run ONLY q65 through the placed ICI path "
+                         "on four chips (the SF10 files)")
     ap.add_argument("--rows", type=int, default=None,
-                    help="store_sales rows (default: SF10's 28.8M; "
-                         "SF1's 2.88M with --chips 4)")
+                    help="store_sales rows (default: SF10's 28.8M; the "
+                         "four-chip configuration's 28,800,991 with "
+                         "--chips 4)")
     ap.add_argument("--seed", type=int, default=22)
     args = ap.parse_args(argv)
     device = require_tpu(args.chips)
-    rows = args.rows or (SF1_ROWS if args.chips == 4 else SF10_ROWS)
+    rows = args.rows or (None if args.chips == 4 else SF10_ROWS)
     run(rows, args.seed, device, ici=args.chips == 4)
 
 

@@ -53,6 +53,8 @@ def _enable_compile_cache(cache_dir=None) -> None:
             _cc.reset_cache()
     _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from spark_rapids_tpu.exec import kernel_cache as _kc
+    _kc.share_executables_across_chips()
 
 
 from spark_rapids_tpu.api.session import TpuSparkSession  # noqa: E402,F401

@@ -37,7 +37,9 @@ class TpuSparkSession:
 
     def __init__(self, conf: Optional[Dict[str, Any]] = None):
         self.conf = RapidsTpuConf(conf)
-        devmgr.initialize(self.conf.get(cfg.CONCURRENT_TPU_TASKS))
+        from spark_rapids_tpu.exec import placement
+        devmgr.initialize(self.conf.get(cfg.CONCURRENT_TPU_TASKS),
+                          chips=len(placement.mesh_devices(self.conf)))
         # -- fleet shared cache plane (fleet/store.py): attach BEFORE
         # the compile cache and compile observatory configure, so the
         # shared compile-cache directory and corpus directory take
@@ -303,6 +305,13 @@ class TpuSparkSession:
         GpuSemaphore.scala:101-135).  Output preserves partition order.
         """
         n_tasks = int(self.conf.get(cfg.CONCURRENT_TPU_TASKS))
+        if devmgr.chips() > 1 and len(its) > 1:
+            # a mesh of several chips: partition p is chip p % n_dev's
+            # task, the slots count a chip (exec/placement)
+            from spark_rapids_tpu.exec.placement import drain_by_chip
+            parts: List[List] = [[] for _ in its]
+            drain_by_chip(its, lambda p, b: parts[p].append(b))
+            return [x for part in parts for x in part]
         if len(its) <= 1 or n_tasks <= 1:
             out: List = []
             for it in its:

@@ -26,6 +26,50 @@ import jax
 
 from spark_rapids_tpu.expr import ir
 
+def share_executables_across_chips() -> None:
+    """One persistent-cache entry for a single-device program, whichever
+    chip of the host runs it.
+
+    jax keys the persistent cache on the compile options with their
+    device assignment and on the devices' topology, and leaves the
+    assignment out on GPU only (``jax/_src/cache_key.py``,
+    ``strip_device_assignment=(backend.platform == "gpu")``).  On a mesh
+    of several TPU chips every operator's program is placed on each chip
+    in turn (``exec/placement``), so each would be built and stored once
+    a chip: four builds and four copies of a 17-MiB aggregate update,
+    past the cache's size limit.  The executable does not depend on the
+    chip: it is loaded with the assignment of the call that reads it
+    (``deserialize_executable`` takes the devices and the options of
+    that call).  So a single-device program is keyed as if it ran on the
+    host's first device; that chip's own key, and so every key of a
+    one-chip host, stays what it was.  A program over several devices
+    keeps jax's key.  Idempotent."""
+    from jax._src import cache_key
+    import numpy as np
+    if getattr(cache_key, "_spark_rapids_tpu_shared", False):
+        return
+    options, accelerator = (cache_key._hash_serialized_compile_options,
+                            cache_key._hash_accelerator_config)
+
+    def hash_options(hash_obj, compile_options,
+                     strip_device_assignment=False):
+        da = compile_options.device_assignment
+        single = da is not None and \
+            da.replica_count() * da.computation_count() == 1
+        return options(hash_obj, compile_options,
+                       strip_device_assignment or single)
+
+    def hash_accelerator(hash_obj, accelerators):
+        if accelerators.size == 1:
+            first = accelerators.flat[0].client.local_devices()[0]
+            accelerators = np.array([first])
+        return accelerator(hash_obj, accelerators)
+
+    cache_key._hash_serialized_compile_options = hash_options
+    cache_key._hash_accelerator_config = hash_accelerator
+    cache_key._spark_rapids_tpu_shared = True
+
+
 _MAX_ENTRIES = 1024
 _CACHE: "OrderedDict[Any, Any]" = OrderedDict()
 _LOCK = threading.Lock()
@@ -113,6 +157,21 @@ def _shape_sig(args, kwargs):
         return (tuple(shp), str(dty)) if shp is not None else repr(x)[:32]
     leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
     return (treedef, tuple(leaf_sig(x) for x in leaves))
+
+
+def _chip_of(args, kwargs):
+    """The device the call's first array is committed to, where a
+    session places partitions over several chips (a program is built or
+    read back once a chip there); None on one chip."""
+    from spark_rapids_tpu.mem import device as devmgr
+    if devmgr.chips() <= 1:
+        return None
+    for x in jax.tree_util.tree_leaves((args, kwargs)):
+        devs = getattr(x, "devices", None)
+        if devs is not None:
+            devs = devs()
+            return next(iter(devs)).id if len(devs) == 1 else None
+    return None
 
 
 class _ShapeSeen:
@@ -214,7 +273,7 @@ def _observe_compiles(key: Any, fn: Callable,
 
     def wrapped(*args, **kwargs):
         sig = _shape_sig(args, kwargs)
-        if not seen.claim(sig):
+        if not seen.claim((sig, _chip_of(args, kwargs))):
             return fn(*args, **kwargs)
         probe = obscompile.probe_begin()
         t0 = _time.perf_counter_ns()

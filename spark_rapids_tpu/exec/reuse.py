@@ -114,16 +114,17 @@ class TpuReusedSubplanExec(TpuExec):
 
     def _compute(self, held: _Held) -> None:
         """Caller holds ``held.lock``."""
+        from spark_rapids_tpu.exec.placement import drain_by_chip
         from spark_rapids_tpu.mem.spill import register_or_hold
-        held.parts = []
+        its = self.children[0].execute()
+        parts = held.parts = [[] for _ in its]
         try:
-            for it in self.children[0].execute():
-                part: list = []
-                held.parts.append(part)
-                for b in it:
-                    part.append(register_or_hold(b))
-            assert len(held.parts) == self.partitions, \
-                (len(held.parts), self.partitions)
+            # partitions of different chips side by side (one loop here
+            # on one chip)
+            drain_by_chip(its, lambda p, b: parts[p].append(
+                register_or_hold(b)))
+            assert len(parts) == self.partitions, \
+                (len(parts), self.partitions)
         except BaseException as e:
             held.error = e
             held.let_go()

@@ -54,11 +54,14 @@ def _gather(child: PhysicalPlan) -> Optional[DeviceBatch]:
     accumulating build side stays evictable until the concat
     (reference: build side held as LazySpillableColumnarBatch,
     GpuHashJoin.scala / SpillableColumnarBatch.scala:169)."""
+    from spark_rapids_tpu.exec.placement import drain_by_chip
     from spark_rapids_tpu.mem.spill import register_or_hold
-    handles = []
-    for it in child.execute():
-        for b in it:
-            handles.append(register_or_hold(b))
+    its = child.execute()
+    parts = [[] for _ in its]
+    # partitions of different chips side by side (one loop here on one
+    # chip); partition order is kept
+    drain_by_chip(its, lambda p, b: parts[p].append(register_or_hold(b)))
+    handles = [h for part in parts for h in part]
     if not handles:
         return None
     try:

@@ -221,6 +221,20 @@ class TpuParquetScanExec(TpuExec):
             cfg.SCAN_HOST_PREP_THREADS)))
         depth = max(0, int(self.conf.get(cfg.SCAN_PREFETCH_DEPTH)))
         groups = self._fused_groups()
+        # on a mesh of several chips partition p is chip p % n_dev's
+        # (exec/placement): its pages are uploaded there, its upload set
+        # is kept there and its decode runs there
+        from spark_rapids_tpu.exec import placement
+        chips = placement.mesh_devices(self.conf)
+        if chips:
+            from spark_rapids_tpu.obs import registry as _obsreg
+            _obsreg.get_registry().inc_many(
+                ("scan.placed.batches", len(groups)),
+                ("scan.placed.chips",
+                 min(len(groups), len(chips)) if len(groups) > 1 else 0))
+
+        def chip_of(idx):
+            return chips[idx % len(chips)] if chips else None
 
         # shared-scan multicast (io/scan_share): concurrent queries
         # decoding the same (stamps, row-groups, columns) group share
@@ -233,10 +247,13 @@ class TpuParquetScanExec(TpuExec):
                 int(self.conf.get(cfg.SCAN_SHARED_WINDOW_BYTES)))
             schema_sig = tuple((f.name, f.dtype.name)
                                for f in self._schema.fields)
-            share_keys = [scan_share.share_key(srcs, pv, schema_sig)
-                          for srcs, pv in groups]
+            # a shared decode is shared where it lies
+            share_keys = [scan_share.share_key(
+                srcs, pv, schema_sig if not chips else schema_sig + (
+                    ("device", str(chip_of(i).id)),))
+                for i, (srcs, pv) in enumerate(groups)]
 
-        def prepare(path_rgs):
+        def prepare(idx, path_rgs):
             """Host prep + packed-page upload for one batch (NO device
             read — safe on the prefetch thread)."""
             handles = {p: sc.open_source(p, metrics=self.metrics)
@@ -246,7 +263,7 @@ class TpuParquetScanExec(TpuExec):
                 return pqf.prepare_fused(
                     sources, file_schema, columns=file_cols,
                     host_threads=host_threads,
-                    metrics=self.metrics), handles
+                    metrics=self.metrics, device=chip_of(idx)), handles
             except BaseException:
                 for h in handles.values():
                     h.close()
@@ -273,6 +290,12 @@ class TpuParquetScanExec(TpuExec):
                 out = DeviceBatch([names[i] for i in order],
                                   [cols[i] for i in order],
                                   batch.num_rows)
+                if prep.device is not None and (part_cols
+                                                or prep.extra_cols):
+                    # columns made on the host join the decoded ones
+                    # on the batch's chip
+                    import jax
+                    out = jax.device_put(out, prep.device)
                 self.metrics.num_output_rows += int(out.num_rows)
                 self.metrics.add_batches()
                 return out
@@ -280,9 +303,9 @@ class TpuParquetScanExec(TpuExec):
                 for h in handles.values():
                     h.close()
 
-        def _lead(kind, entry, path_rgs):
+        def _lead(kind, entry, idx, path_rgs):
             try:
-                return (kind, entry, prepare(path_rgs))
+                return (kind, entry, prepare(idx, path_rgs))
             except BaseException as e:
                 share.fail(entry, e)
                 share.release(entry)
@@ -297,11 +320,12 @@ class TpuParquetScanExec(TpuExec):
             look-ahead threads) leaves it open ("ahead") until
             ``_resolve``."""
             if share is None or share_keys[idx] is None:
-                return ("solo", None, prepare(path_rgs))
+                return ("solo", None, prepare(idx, path_rgs))
             role, entry = share.claim(share_keys[idx], not ahead)
             if role == "join":
                 return ("join", entry, None)
-            return _lead("ahead" if ahead else "lead", entry, path_rgs)
+            return _lead("ahead" if ahead else "lead", entry, idx,
+                         path_rgs)
 
         def _finish_marker(marker, pv) -> DeviceBatch:
             """Dispatch one non-join marker's decode (caller holds the
@@ -335,7 +359,7 @@ class TpuParquetScanExec(TpuExec):
                     # (two scans of one table in one query: the build
                     # side's join the flights the stream side's
                     # look-ahead leads).  Decode in its place.
-                    marker = _lead("lead", entry, path_rgs)
+                    marker = _lead("lead", entry, idx, path_rgs)
                 elif kind == "ahead" and share.begin(entry):
                     marker = ("lead", entry, prepared)
                 elif kind == "ahead":
@@ -390,22 +414,28 @@ class TpuParquetScanExec(TpuExec):
 
         def group_part(idx, path_rgs, pv) -> Iterator[DeviceBatch]:
             from spark_rapids_tpu.exec.context import set_input_file
+            from spark_rapids_tpu.mem.device import current_chip, task_chip
+            # the decode takes a slot of the chip it runs on, whichever
+            # thread pulls the partition
+            chip = idx % len(chips) if chips else current_chip()
             try:
-                if prefetcher is not None:
-                    marker = prefetcher.get(idx)
-                    out = _resolve(marker, idx, path_rgs, pv)
-                else:
-                    # no pipelining: the whole prep+upload+dispatch runs
-                    # under the semaphore, preserving the pre-prefetch
-                    # concurrent-device-work bound (a joined claim waits
-                    # OUTSIDE the semaphore instead)
-                    out = None
-                    with tpu_semaphore(self.metrics):
-                        marker = _prep(idx, path_rgs, ahead=False)
-                        if marker[0] != "join":
-                            out = _finish_marker(marker, pv)
-                    if out is None:
+                with task_chip(chip):
+                    if prefetcher is not None:
+                        marker = prefetcher.get(idx)
                         out = _resolve(marker, idx, path_rgs, pv)
+                    else:
+                        # no pipelining: the whole prep+upload+dispatch
+                        # runs under the semaphore, preserving the
+                        # pre-prefetch concurrent-device-work bound (a
+                        # joined claim waits OUTSIDE the semaphore
+                        # instead)
+                        out = None
+                        with tpu_semaphore(self.metrics):
+                            marker = _prep(idx, path_rgs, ahead=False)
+                            if marker[0] != "join":
+                                out = _finish_marker(marker, pv)
+                        if out is None:
+                            out = _resolve(marker, idx, path_rgs, pv)
                 paths = {p for p, _ in path_rgs}
                 # set right before the yield so the consumer evaluates
                 # input_file_name() against THIS batch's file

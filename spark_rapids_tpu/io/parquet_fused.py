@@ -781,6 +781,7 @@ class PreparedScan:
     dev_cols: List[str]
     extra_cols: Dict[str, DeviceColumn]
     fallbacks: List[str]
+    device: Any = None      # the chip the batch belongs to; None: default
 
 
 def _collect_plans(sources, schema, wanted, host_threads: int,
@@ -860,11 +861,14 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
                   schema: Schema,
                   columns: Optional[List[str]] = None,
                   host_threads: int = 1,
-                  metrics=None) -> PreparedScan:
+                  metrics=None, device=None) -> PreparedScan:
     """Host half of the fused decode: footer/page walks (through the
     scan-plan cache when enabled), fused-plan assembly, packed-page
     upload, and the host-Arrow fallback decode.  Safe to run on a
-    prefetch thread: it never reads device memory."""
+    prefetch thread: it never reads device memory.  ``device`` is the
+    chip the batch belongs to (``exec/placement``): the upload set goes
+    there, is kept there, and the decode runs there because its inputs
+    are committed there; None is the default device."""
     import contextlib
     from spark_rapids_tpu.columnar.batch import from_arrow as _fa
     from spark_rapids_tpu.exec.base import timed_extra
@@ -891,6 +895,8 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
         akey = (stamps, tuple(wanted),
                 tuple(d.name for d in out_dtypes)) \
             if all(s is not None for s, _ in stamps) else None
+        if akey is not None and device is not None:
+            akey += (("device", device.id),)
         kept = sc.get_assembled(akey)
         if kept is not None:
             fp, dev_arrays = kept
@@ -914,7 +920,9 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
 
     with phase("scan.uploadTime"):
         if fp is not None and dev_arrays is None:
-            dev_arrays = {k: jnp.asarray(v) for k, v in fp.arrays.items()}
+            dev_arrays = {k: jnp.asarray(v) if device is None
+                          else jax.device_put(v, device)
+                          for k, v in fp.arrays.items()}
             # upload-byte accounting: global counter + tenant ledger,
             # same n (the exactness invariant)
             from spark_rapids_tpu.obs import accounting as _acct
@@ -928,7 +936,8 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
                 # the host copy has done its work: what is kept is the
                 # plan's static half and the arrays where they now live
                 fp = dataclasses.replace(fp, arrays={})
-                sc.put_assembled(akey, (fp, dev_arrays), up)
+                sc.put_assembled(akey, (fp, dev_arrays), up,
+                                 device=device)
 
         extra_cols: Dict[str, DeviceColumn] = dict(list_cols)
         if fallbacks:
@@ -974,7 +983,8 @@ def prepare_fused(sources: Sequence[Tuple[Any, str, int]],
 
     return PreparedScan(wanted=wanted, total=total, cap=cap, fp=fp,
                         dev_arrays=dev_arrays, dev_cols=dev_cols,
-                        extra_cols=extra_cols, fallbacks=fallbacks)
+                        extra_cols=extra_cols, fallbacks=fallbacks,
+                        device=device)
 
 
 def finish_fused(prep: PreparedScan) -> Tuple[DeviceBatch, List[str]]:

@@ -28,7 +28,10 @@ multi-file reader clips row groups without re-reading the tail):
     of cached plans is packed into its upload arrays and uploaded
     once, not once a query.  They are derived data resident in HBM, so
     they are the first to go, never push a plan out, and are all
-    dropped under memory pressure.
+    dropped under memory pressure.  A set lies in one chip's HBM, so
+    the room is counted a chip: a scan that places its partitions over
+    a mesh (``exec/placement``) names the device in the set's key and
+    to ``put_assembled``, and each chip holds what the budget leaves.
 
 Lookups stat the file every time (µs against ms-scale walks), so an
 overwritten file is never served stale plans.  All entry points are
@@ -64,9 +67,10 @@ _FILES: "OrderedDict[Tuple, _FileEntry]" = OrderedDict()
 _PATH_KEY: Dict[str, Tuple] = {}
 _TOTAL_BYTES = 0
 
-# batch key -> (assembled upload set, bytes), LRU order (oldest first)
-_ASSEMBLED: "OrderedDict[Tuple, Tuple[Any, int]]" = OrderedDict()
-_ASSEMBLED_BYTES = 0
+# batch key -> (assembled upload set, bytes, device), LRU order (oldest
+# first); the bytes held a device (None: the default device)
+_ASSEMBLED: "OrderedDict[Tuple, Tuple[Any, int, Any]]" = OrderedDict()
+_ASSEMBLED_BYTES: Dict[Any, int] = {}
 
 _HITS = 0
 _MISSES = 0
@@ -162,7 +166,8 @@ def stats() -> Dict[str, int]:
                 "invalidations": _INVALIDATIONS,
                 "entries": len(_FILES), "bytes": _TOTAL_BYTES,
                 "assembled": len(_ASSEMBLED),
-                "assembled_bytes": _ASSEMBLED_BYTES}
+                "assembled_bytes": sum(_ASSEMBLED_BYTES.values()),
+                "assembled_bytes_by_device": dict(_ASSEMBLED_BYTES)}
 
 
 def clear() -> None:
@@ -171,12 +176,12 @@ def clear() -> None:
 
 
 def _clear_locked() -> None:
-    global _TOTAL_BYTES, _ASSEMBLED_BYTES
+    global _TOTAL_BYTES
     _FILES.clear()
     _PATH_KEY.clear()
     _TOTAL_BYTES = 0
     _ASSEMBLED.clear()
-    _ASSEMBLED_BYTES = 0
+    _ASSEMBLED_BYTES.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -344,14 +349,21 @@ def _entry_locked(skey: Tuple) -> "_FileEntry":
 
 
 def _drop_assembled_locked(akey: Tuple) -> None:
-    global _ASSEMBLED_BYTES
-    _ASSEMBLED_BYTES -= _ASSEMBLED.pop(akey)[1]
+    _, nbytes, device = _ASSEMBLED.pop(akey)
+    _ASSEMBLED_BYTES[device] -= nbytes
+    if not _ASSEMBLED_BYTES[device]:
+        del _ASSEMBLED_BYTES[device]
 
 
 def _evict_locked() -> None:
     global _TOTAL_BYTES, _EVICTIONS
-    while _ASSEMBLED and _TOTAL_BYTES + _ASSEMBLED_BYTES > _MAX_BYTES:
-        _drop_assembled_locked(next(iter(_ASSEMBLED)))
+    for device in [d for d, held in _ASSEMBLED_BYTES.items()
+                   if _TOTAL_BYTES + held > _MAX_BYTES]:
+        # that chip's sets, oldest first, until it is inside the budget
+        for akey in [k for k, v in _ASSEMBLED.items() if v[2] == device]:
+            if _TOTAL_BYTES + _ASSEMBLED_BYTES.get(device, 0) <= _MAX_BYTES:
+                break
+            _drop_assembled_locked(akey)
     while _TOTAL_BYTES > _MAX_BYTES and len(_FILES) > 1:
         old_key, old = _FILES.popitem(last=False)
         _TOTAL_BYTES -= old.nbytes
@@ -493,13 +505,15 @@ def get_assembled(key: Optional[Tuple]):
     return None if hit is None else hit[0]
 
 
-def put_assembled(key: Optional[Tuple], made, nbytes: int) -> None:
-    """Keep ``made`` (device-resident, ``nbytes`` of HBM) for the next
-    scan of the same batch.  It takes only the room the plans leave in
-    the budget, pushing older sets out; one that does not fit there is
-    not kept.  A set goes with its file's stamp, and all of them under
-    memory pressure (``pressure_spill``)."""
-    global _ASSEMBLED_BYTES, _SPILLER_REGISTERED
+def put_assembled(key: Optional[Tuple], made, nbytes: int,
+                  device=None) -> None:
+    """Keep ``made`` (resident on ``device``, ``nbytes`` of its HBM; None:
+    the default device) for the next scan of the same batch.  It takes
+    only the room the plans leave in that chip's budget, pushing the
+    chip's older sets out; one that does not fit there is not kept.  A
+    set goes with its file's stamp, and all of them under memory
+    pressure (``pressure_spill``)."""
+    global _SPILLER_REGISTERED
     if key is None:
         return
     with _LOCK:
@@ -511,8 +525,9 @@ def put_assembled(key: Optional[Tuple], made, nbytes: int) -> None:
         # the last word
         if _ENABLED and key not in _ASSEMBLED and \
                 _TOTAL_BYTES + nbytes <= _MAX_BYTES:
-            _ASSEMBLED[key] = (made, int(nbytes))
-            _ASSEMBLED_BYTES += int(nbytes)
+            _ASSEMBLED[key] = (made, int(nbytes), device)
+            _ASSEMBLED_BYTES[device] = \
+                _ASSEMBLED_BYTES.get(device, 0) + int(nbytes)
             _evict_locked()
 
 

@@ -9,6 +9,13 @@ Analogs:
   * ``tpu_semaphore`` — GpuSemaphore.acquireIfNecessary
     (reference: GpuSemaphore.scala:27-161): bounds how many tasks
     concurrently build device working sets.
+
+On a mesh of several chips the slots and the budget count per chip (the
+reference runs one executor a GPU, each with its own semaphore and
+pool): a task thread says which chip it works for (``task_chip``, set
+by ``exec/placement.drain_by_chip`` and by a placed scan partition) and
+``tpu_semaphore`` takes a slot of that chip's gate.  A thread that says
+nothing works for chip 0, so on one device nothing changes.
 """
 
 from __future__ import annotations
@@ -16,7 +23,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Optional
+from typing import Dict, List, Optional
 
 from spark_rapids_tpu.obs import registry as obsreg
 from spark_rapids_tpu.obs import trace as obstrace
@@ -24,23 +31,55 @@ from spark_rapids_tpu.sched import cancel as _cancel
 from spark_rapids_tpu.sched.admission import TaskGate
 
 _LOCK = threading.Lock()
-_GATE: Optional[TaskGate] = None
+_GATES: Dict[int, TaskGate] = {}       # chip index -> its slots
 _SLOTS = 2
+_CHIPS = 1
+_TLS = threading.local()               # .chip: the chip this thread works for
 
 
-def initialize(concurrent_tasks: int) -> None:
-    global _GATE, _SLOTS
+def initialize(concurrent_tasks: int, chips: int = 1) -> None:
+    """``chips``: the mesh devices partitions are placed on (1 where
+    they are not, ``exec/placement.mesh_devices``)."""
+    global _SLOTS, _CHIPS
     with _LOCK:
         _SLOTS = max(1, int(concurrent_tasks))
-        _GATE = TaskGate(_SLOTS)
+        _CHIPS = max(1, int(chips))
+        _GATES.clear()
+
+
+def slots() -> int:
+    """Task slots a chip (``concurrentTpuTasks``)."""
+    return _SLOTS
+
+
+def chips() -> int:
+    """The chips whose tasks run side by side (1: no placement)."""
+    return _CHIPS
+
+
+def current_chip() -> int:
+    return getattr(_TLS, "chip", 0)
+
+
+@contextlib.contextmanager
+def task_chip(chip: int):
+    """This thread works for mesh device ``chip`` until the block ends:
+    its ``tpu_semaphore`` acquisitions count against that chip."""
+    prev = current_chip()
+    _TLS.chip = int(chip)
+    try:
+        yield
+    finally:
+        _TLS.chip = prev
 
 
 def _get() -> TaskGate:
-    global _GATE
+    chip = current_chip()
     with _LOCK:
-        if _GATE is None:
-            _GATE = TaskGate(_SLOTS)
-        return _GATE
+        gate = _GATES.get(chip)
+        if gate is None:
+            gate = _GATES[chip] = TaskGate(_SLOTS)
+        return gate
 
 
 @contextlib.contextmanager
@@ -113,11 +152,15 @@ class TpuDeviceManager:
             # the budget is the device's own limit or start-up fails:
             # a guessed pool would admit work against memory that may
             # not exist
-            limit = self.default_device.memory_stats()["bytes_limit"]
-            self.hbm_budget = int(limit * pool_fraction)
+            self.hbm_budgets = [
+                int(d.memory_stats()["bytes_limit"] * pool_fraction)
+                for d in self.devices]
         else:
             # host platforms (the CPU test platform) report no limit
-            self.hbm_budget = _HOST_PLATFORM_BUDGET
+            self.hbm_budgets = [_HOST_PLATFORM_BUDGET] * len(self.devices)
+        # what one chip may hold: a task's working set lies on one chip,
+        # so the smallest chip's budget bounds it wherever it runs
+        self.hbm_budget = min(self.hbm_budgets)
 
     @classmethod
     def get(cls) -> "TpuDeviceManager":
@@ -128,3 +171,12 @@ class TpuDeviceManager:
     @property
     def platform(self) -> str:
         return self.default_device.platform
+
+
+def memory_peaks() -> List[int]:
+    """``peak_bytes_in_use`` of every device, in ``jax.devices()``'s
+    order (0 where the backend reports none): on a host of several chips
+    the fullest device's peak says nothing of the others."""
+    import jax
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.devices()]

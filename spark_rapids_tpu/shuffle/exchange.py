@@ -606,6 +606,25 @@ class CpuShuffleExchangeExec(PhysicalPlan):
         return [reader(p) for p in range(n_parts)]
 
 
+# sort keys a chip hands over for the range bounds of a placed exchange
+_RANGE_SAMPLE = 1024
+
+
+def _range_bounds(samples, n_parts: int) -> np.ndarray:
+    """``[words, n_parts - 1]`` bounds from each chip's sample of sort
+    words (``(rows, [words, sample])``; a chip's sample stands for its
+    rows): the keys at the weighted quantiles, most significant word
+    first."""
+    words = np.concatenate([s for _, s in samples], axis=1)
+    weight = np.concatenate([np.full(s.shape[1], n / s.shape[1])
+                             for n, s in samples])
+    order = np.lexsort(words[::-1])
+    share = np.cumsum(weight[order]) / max(weight.sum(), 1e-300)
+    at = [min(int(np.searchsorted(share, (k + 1) / n_parts)),
+              len(order) - 1) for k in range(n_parts - 1)]
+    return words[:, order[at]]
+
+
 class TpuShuffleExchangeExec(TpuExec):
     """Device-side exchange.
 
@@ -1605,7 +1624,19 @@ class TpuShuffleExchangeExec(TpuExec):
         column, staying on that device — so downstream per-partition
         kernels (join probe, per-partition aggregate) execute distributed
         across the mesh.
+
+        The map side runs where its rows lie (exec/placement): the
+        child's partitions are drained with the chips side by side, the
+        batches are grouped by the device they are committed to, each
+        chip concatenates its own and computes their targets, and
+        ``ici.exchange_placed`` takes them from there with buckets sized
+        by the counted rows.  A child whose batches all lie on one
+        device (a host-built DataFrame, a one-partition scan) is the
+        same exchange with the other chips sending nothing.
         """
+        from spark_rapids_tpu.exec import placement
+        from spark_rapids_tpu.obs import registry as obsreg
+        from spark_rapids_tpu.obs import trace as obstrace
         from spark_rapids_tpu.shuffle import ici
         n_parts = self.partitioning.num_partitions
         state = {"done": False, "dev": None, "n_dev": 1,
@@ -1619,18 +1650,31 @@ class TpuShuffleExchangeExec(TpuExec):
         def _materialize_locked():
             if state["done"]:
                 return
-            batches = []
-            for it in self.children[0].execute():
-                batches.extend(b for b in it if int(b.num_rows))
+            devices = list(ici.get_default_mesh().devices.flat)
+            n_dev = len(devices)
+            info = {"partitioning": type(self.partitioning).__name__}
+            its = self.children[0].execute()
+            parts: List[List[DeviceBatch]] = [[] for _ in its]
+            placement.drain_by_chip(
+                its, lambda p, b: parts[p].append(b), n_dev)
+            batches = [b for part in parts for b in part
+                       if int(b.num_rows)]
             if batches:
-                g = concat_batches(batches)
-                with timed(self.metrics, "exchange.ici"):
-                    targets = self._compute_targets(g, 0)
-                    dev, mesh = ici.exchange_batch(g, targets,
-                                                   self.min_bucket)
-                state["dev"] = dev
-                state["n_dev"] = mesh.shape["shuffle"]
-                self.metrics.extra["ici_devices"] = state["n_dev"]
+                # the span carries what the exchange counted
+                with timed(self.metrics), \
+                        obstrace.span("exchange.ici", args=info):
+                    state["dev"] = self._exchange_ici(
+                        batches, devices, info)
+                state["n_dev"] = n_dev
+                self.metrics.extra["ici_devices"] = n_dev
+                obsreg.get_registry().inc_many(
+                    ("exchange.ici.exchanges", 1),
+                    ("exchange.ici.rowsIn", info["rows_in"]),
+                    ("exchange.ici.bytesIn", info["bytes_in"]),
+                    ("exchange.ici.bucketRows", info["bucket_rows"]),
+                    ("exchange.ici.movedBatches", info["moved"]))
+                for k in ("rows_in", "bucket_rows", "moved"):
+                    self.metrics.extra[f"ici_{k}"] = info[k]
             state["done"] = True
 
         def release():
@@ -1650,17 +1694,22 @@ class TpuShuffleExchangeExec(TpuExec):
             b = state["dev"][pidx % state["n_dev"]]
             if b is None:
                 return
-            from spark_rapids_tpu.exec import kernel_cache as kc
-            key = ("ici_extract", b.schema_key())
-            if key not in self._kernels:
-                def extract(batch, pid):
-                    from spark_rapids_tpu.exec.tpu_basic import compact
-                    part = batch.columns[-1].data
-                    return compact(batch, part == pid)
-                self._kernels[key] = kc.get_kernel(
-                    key, lambda: extract)
-            with timed(self.metrics, "exchange.iciExtract"):
-                out = self._kernels[key](b, jnp.int32(pidx))
+            if n_parts <= state["n_dev"]:
+                # the device owns this partition alone: every row it
+                # received is the reader's
+                out = b
+            else:
+                from spark_rapids_tpu.exec import kernel_cache as kc
+                key = ("ici_extract", b.schema_key())
+                if key not in self._kernels:
+                    def extract(batch, pid):
+                        from spark_rapids_tpu.exec.tpu_basic import compact
+                        part = batch.columns[-1].data
+                        return compact(batch, part == pid)
+                    self._kernels[key] = kc.get_kernel(
+                        key, lambda: extract)
+                with timed(self.metrics, "exchange.iciExtract"):
+                    out = self._kernels[key](b, jnp.int32(pidx))
             if int(out.num_rows) == 0:
                 return
             out = DeviceBatch(out.names[:-1], out.columns[:-1],
@@ -1671,6 +1720,107 @@ class TpuShuffleExchangeExec(TpuExec):
 
         return [_ReleasingIter(reader(p), release)
                 for p in range(n_parts)]
+
+    def _exchange_ici(self, batches: List[DeviceBatch], devices: list,
+                      info: dict) -> List[Optional[DeviceBatch]]:
+        """One ICI exchange over the drained map-side batches; fills
+        ``info`` with what was counted (the ``exchange.ici`` span's
+        arguments and the counters)."""
+        from spark_rapids_tpu.exec import placement
+        from spark_rapids_tpu.obs import trace as obstrace
+        from spark_rapids_tpu.shuffle import ici
+        index = {d: i for i, d in enumerate(devices)}
+        homes = [index.get(placement.device_of(b)) for b in batches]
+        info["rows_in"] = sum(int(b.num_rows) for b in batches)
+        info["bytes_in"] = sum(
+            int(b.num_rows) * sum(
+                a.dtype.itemsize * int(np.prod(a.shape[1:]))
+                for c in b.columns
+                for a in (c.data, c.validity, c.lengths, c.elem_validity)
+                if a is not None) for b in batches)
+        # a batch on no device of the mesh joins chip 0's
+        info["moved"] = sum(1 for h in homes if h is None)
+        per_chip: List[List[DeviceBatch]] = [[] for _ in devices]
+        for b, h in zip(batches, homes):
+            if h is None:
+                h, b = 0, jax.device_put(b, devices[0])
+            per_chip[h].append(b)
+        held = [concat_batches(bs) if bs else None for bs in per_chip]
+        targets = self._placed_targets(held)
+        dev, counted = ici.exchange_placed(
+            held, targets, self.min_bucket,
+            count_wait=lambda: obstrace.span("exchange.countWait"))
+        info["bucket_rows"] = counted["bucket_rows"]
+        info["capacities"] = counted["capacities"]
+        info["received"] = [int(n) for n in counted["rows"].sum(axis=0)]
+        return dev
+
+    def _placed_targets(self, held: List[Optional[DeviceBatch]]
+                        ) -> List[Optional[jnp.ndarray]]:
+        """Per-row target partitions of each chip's batch, computed on
+        that chip.  Hash and round-robin targets are a row's own; range
+        targets need bounds every chip agrees on, so each chip hands
+        over a sample of its sort keys, the bounds are the sample's
+        quantiles (Spark's RangePartitioner) and each chip places its
+        rows between them."""
+        p = self.partitioning
+        if not isinstance(p, RangePartitioning):
+            out, seen = [], 0
+            for g in held:
+                out.append(None if g is None
+                           else self._compute_targets(g, seen))
+                seen += 0 if g is None else int(g.num_rows)
+            return out
+        from spark_rapids_tpu.exec import kernel_cache as kc
+        from spark_rapids_tpu.obs import trace as obstrace
+        n_parts = p.num_partitions
+        orders = p.orders
+
+        def keys_impl(b):
+            groups = [sortkeys.encode_keys(
+                eval_tpu.evaluate(o.expr, b), o.ascending,
+                o.nulls_first_resolved) for o in orders]
+            return sortkeys.stack_sort_words(groups, b.row_mask())
+
+        def sample_impl(wm, num_rows):
+            # evenly spaced live rows (they lie at the front)
+            at = ((jnp.arange(_RANGE_SAMPLE, dtype=jnp.int64) * 2 + 1)
+                  * num_rows.astype(jnp.int64)) // (2 * _RANGE_SAMPLE)
+            return jnp.take(wm, at.astype(jnp.int32), axis=1)
+
+        def place_impl(wm, bounds, num_rows):
+            # a row's target: the bounds its key lies above, words
+            # compared from the least significant up
+            target = jnp.zeros((wm.shape[1],), jnp.int32)
+            for k in range(n_parts - 1):
+                above = jnp.zeros((wm.shape[1],), jnp.bool_)
+                for w in range(wm.shape[0] - 1, -1, -1):
+                    above = (wm[w] > bounds[w, k]) | (
+                        (wm[w] == bounds[w, k]) & above)
+                target = target + above.astype(jnp.int32)
+            live = jnp.arange(wm.shape[1]) < num_rows
+            return jnp.where(live, target, jnp.int32(n_parts))
+
+        words, samples = [], []
+        for g in held:
+            if g is None:
+                words.append(None)
+                continue
+            wm = kc.get_kernel(
+                ("exch_rkeys", p.cache_sig(), g.schema_key()),
+                lambda: keys_impl)(g)
+            words.append(wm)
+            samples.append((int(g.num_rows), kc.get_kernel(
+                ("exch_rsample", wm.shape), lambda: sample_impl)(
+                    wm, jnp.asarray(g.num_rows, dtype=jnp.int32))))
+        with obstrace.span("exchange.countWait"):
+            drawn = jax.device_get([s for _, s in samples])
+        bounds = _range_bounds([(n, np.asarray(s)) for (n, _), s in
+                                zip(samples, drawn)], n_parts)
+        return [None if g is None else kc.get_kernel(
+            ("exch_rplace", n_parts, wm.shape), lambda: place_impl)(
+                wm, bounds, jnp.asarray(g.num_rows, dtype=jnp.int32))
+                for g, wm in zip(held, words)]
 
     def execute(self):
         if self.transport in ("ici", "ici_ring"):

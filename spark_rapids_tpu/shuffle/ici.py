@@ -57,16 +57,21 @@ def partition_targets(key_vals: Sequence[ColVal], n_parts: int,
     return jnp.where(m < 0, m + n_parts, m)
 
 
-def bucketize(batch: DeviceBatch, target: jnp.ndarray, n_parts: int
+def bucketize(batch: DeviceBatch, target: jnp.ndarray, n_parts: int,
+              bucket_cap: Optional[int] = None
               ) -> Tuple[List[DeviceColumn], jnp.ndarray]:
     """Slice a batch into n_parts contiguous buckets (stacked on a new
     leading axis).  The XLA analog of cudf contiguous_split used by
     GpuPartitioning.sliceInternalOnGpu (reference: GpuPartitioning.scala:45).
 
-    Returns columns whose arrays have shape [n_parts, cap, ...] plus a
-    per-bucket row count [n_parts].
+    Returns columns whose arrays have shape [n_parts, bucket_cap, ...]
+    plus a per-bucket row count [n_parts].  ``bucket_cap`` is the slots
+    a bucket has (the batch's capacity where none is given: any count
+    fits); the caller that passes a smaller one has counted the rows and
+    knows that no bucket holds more.
     """
     cap = batch.capacity
+    bcap = cap if bucket_cap is None else int(bucket_cap)
     exists = batch.row_mask()
     t = jnp.where(exists, target, n_parts)  # park padding out of range
     counts = jnp.zeros((n_parts,), dtype=jnp.int32).at[t].add(
@@ -78,20 +83,20 @@ def bucketize(batch: DeviceBatch, target: jnp.ndarray, n_parts: int
     rank = jnp.arange(cap, dtype=jnp.int32) - jnp.take(
         offsets, jnp.clip(sorted_t, 0, n_parts - 1))
     flat_pos = jnp.where(sorted_t < n_parts,
-                         sorted_t * cap + jnp.clip(rank, 0, cap - 1),
-                         n_parts * cap)  # padding -> dropped
-    gather_idx = jnp.zeros((n_parts * cap,), dtype=jnp.int32).at[
+                         sorted_t * bcap + jnp.clip(rank, 0, bcap - 1),
+                         n_parts * bcap)  # padding -> dropped
+    gather_idx = jnp.zeros((n_parts * bcap,), dtype=jnp.int32).at[
         flat_pos].set(order.astype(jnp.int32), mode="drop")
-    slot = jnp.arange(n_parts * cap) % cap
-    valid = slot < jnp.repeat(counts, cap)
+    slot = jnp.arange(n_parts * bcap) % bcap
+    valid = slot < jnp.repeat(counts, bcap)
     out_cols = []
     for c in batch.columns:
         g = c.gather(gather_idx, valid)
-        data = g.data.reshape((n_parts, cap) + g.data.shape[1:])
-        validity = g.validity.reshape((n_parts, cap))
-        lengths = g.lengths.reshape((n_parts, cap)) \
+        data = g.data.reshape((n_parts, bcap) + g.data.shape[1:])
+        validity = g.validity.reshape((n_parts, bcap))
+        lengths = g.lengths.reshape((n_parts, bcap)) \
             if g.lengths is not None else None
-        ev = g.elem_validity.reshape((n_parts, cap) +
+        ev = g.elem_validity.reshape((n_parts, bcap) +
                                      g.elem_validity.shape[1:]) \
             if g.elem_validity is not None else None
         out_cols.append(DeviceColumn(c.dtype, data, validity, lengths, ev))
@@ -297,20 +302,28 @@ def with_capacity(batch: DeviceBatch, cap: int) -> DeviceBatch:
     if batch.capacity == cap:
         return batch
     assert int(batch.num_rows) <= cap
+    from spark_rapids_tpu.exec import kernel_cache as kc
     from spark_rapids_tpu.shuffle.exchange import slice_span
-    return slice_span(batch, jnp.int32(0),
-                      jnp.asarray(batch.num_rows, jnp.int32), cap)
+    fn = kc.get_kernel(
+        ("exch_slice", cap, batch.schema_key()),
+        lambda: lambda b, o, c: slice_span(b, o, c, cap))
+    return fn(batch, jnp.int32(0), jnp.asarray(batch.num_rows, jnp.int32))
 
 
-def make_exchange_step(mesh: Mesh, axis: str, names, dtypes, aux_key):
+def make_exchange_step(mesh: Mesh, axis: str, names, dtypes, aux_key,
+                       bucket_cap: Optional[int] = None):
     """Jitted shard_map step routing rows to the device owning their
     target partition.  The batch's LAST column is the int32 target
     partition id; device d owns partitions {p : p % n_dev == d}.
 
-    Returns out leaves of per-device capacity n_dev*local_cap (worst case:
-    every row lands on one device) plus per-device received row counts.
+    Every device sends ``bucket_cap`` slots to every peer, so the out
+    leaves have per-device capacity ``n_dev * bucket_cap``; with them
+    come the per-device received row counts.  Without a ``bucket_cap``
+    a bucket has the sender's whole capacity (worst case: every row
+    lands on one device); ``exchange_placed`` counts the rows first and
+    passes the tier of the fullest bucket.
     """
-    key = (mesh, axis, tuple(names), aux_key)
+    key = (mesh, axis, tuple(names), aux_key, bucket_cap)
     if key in _STEP_CACHE:
         return _STEP_CACHE[key]
     n_dev = mesh.shape[axis]
@@ -320,7 +333,7 @@ def make_exchange_step(mesh: Mesh, axis: str, names, dtypes, aux_key):
         batch = DeviceBatch(names, cols, local_rows[0])
         part = batch.columns[-1].data.astype(jnp.int32)
         owner = part % np.int32(n_dev)
-        stacked, counts = bucketize(batch, owner, n_dev)
+        stacked, counts = bucketize(batch, owner, n_dev, bucket_cap)
         stacked, counts_recv = exchange(stacked, counts, axis)
         received = reassemble(names, stacked, counts_recv)
         return _cols_to_leaves(received.columns), jnp.reshape(
@@ -344,49 +357,157 @@ def split_shards(arr: jnp.ndarray, n_dev: int) -> List[jnp.ndarray]:
     return [arr[d * per:(d + 1) * per] for d in range(n_dev)]
 
 
-def exchange_batch(batch: DeviceBatch, targets: jnp.ndarray,
-                   min_bucket: int = 16
-                   ) -> Tuple[List[Optional[DeviceBatch]], Mesh]:
-    """Run the full ICI exchange for one global batch.
+def _with_part(batch: DeviceBatch, targets: jnp.ndarray) -> DeviceBatch:
+    part_col = DeviceColumn(dt.INT32, targets.astype(jnp.int32),
+                            batch.row_mask(), None)
+    return DeviceBatch(list(batch.names) + ["__part__"],
+                       list(batch.columns) + [part_col], batch.num_rows)
 
-    ``targets`` is a per-slot int32 target-partition vector (padding slots
-    ignored).  Returns one local DeviceBatch per mesh device — each batch
-    carries a trailing '__part__' column so the reader can sub-split the
-    device's rows into its owned partitions — plus the mesh used.
-    """
+
+def _peer_counts(aug: DeviceBatch, n_dev: int) -> jnp.ndarray:
+    """How many of a chip's rows go to each peer: int32[n_dev], computed
+    where the rows lie (kernel family ``exch_counts``)."""
+    from spark_rapids_tpu.exec import kernel_cache as kc
+
+    def impl(part, num_rows):
+        live = jnp.arange(part.shape[0]) < num_rows
+        owner = jnp.where(live, part % np.int32(n_dev), n_dev)
+        return jnp.zeros((n_dev,), jnp.int32).at[owner].add(
+            live.astype(jnp.int32), mode="drop")
+    fn = kc.get_kernel(("exch_counts", n_dev, aug.capacity), lambda: impl)
+    return fn(aug.columns[-1].data,
+              jnp.asarray(aug.num_rows, dtype=jnp.int32))
+
+
+def _like_on(template: DeviceBatch, cap: int, device) -> DeviceBatch:
+    """A batch of no rows with ``template``'s columns, on ``device``: a
+    chip that holds nothing still takes part in the collective."""
+    cols = []
+    for c in template.columns:
+        def zeros(a):
+            return None if a is None else jax.device_put(
+                np.zeros((cap,) + a.shape[1:], a.dtype), device)
+        cols.append(DeviceColumn(c.dtype, zeros(c.data), zeros(c.validity),
+                                 zeros(c.lengths), zeros(c.elem_validity)))
+    return DeviceBatch(template.names, cols, 0)
+
+
+def _same_shapes(augs: List[DeviceBatch]) -> List[DeviceBatch]:
+    """Every chip's batch at one capacity, one width a string column and
+    one set of buffers a column, so that the per-device arrays are the
+    shards of one global array.  Usually nothing to do."""
+    cap = max(b.capacity for b in augs)
+    augs = [with_capacity(b, cap) for b in augs]
+    n_cols = len(augs[0].columns)
+    widths = [max(b.columns[i].max_len for b in augs)
+              if augs[0].columns[i].dtype.has_lengths else 0
+              for i in range(n_cols)]
+    with_ev = [any(b.columns[i].elem_validity is not None for b in augs)
+               for i in range(n_cols)]
+    out = []
+    for b in augs:
+        cols = []
+        for c, w, ev in zip(b.columns, widths, with_ev):
+            if w and c.max_len < w:
+                pad = ((0, 0), (0, w - c.max_len))
+                c = DeviceColumn(
+                    c.dtype, jnp.pad(c.data, pad), c.validity, c.lengths,
+                    None if c.elem_validity is None
+                    else jnp.pad(c.elem_validity, pad))
+            if ev and c.elem_validity is None:
+                c = DeviceColumn(c.dtype, c.data, c.validity, c.lengths,
+                                 jnp.ones_like(c.data, dtype=jnp.bool_))
+            cols.append(c)
+        out.append(DeviceBatch(b.names, cols, b.num_rows))
+    return out
+
+
+def exchange_placed(batches: List[Optional[DeviceBatch]],
+                    targets: List[Optional[jnp.ndarray]],
+                    min_bucket: int = 16, count_wait=None
+                    ) -> Tuple[List[Optional[DeviceBatch]], dict]:
+    """The ICI exchange over rows that already lie on the mesh:
+    ``batches[d]`` is what mesh device ``d`` holds (committed there; None
+    where it holds nothing) and ``targets[d]`` its per-slot target
+    partitions.  Nothing passes through one chip: each chip counts its
+    rows a peer where they lie, the ``n_dev x n_dev`` counts are read in
+    one transfer (the scalar-prefetch idiom of the module docstring: the
+    true counts decide the static shape), the buckets get the tier of
+    the fullest one, the global arrays are assembled from the per-device
+    ones and one ``all_to_all`` step runs.  A receiver's batch is then
+    cut to the tier of the rows it received.
+
+    ``count_wait`` is a context manager factory around the one read.
+    Returns one local DeviceBatch per mesh device (None where a device
+    received nothing; each carries a trailing ``__part__`` column so the
+    reader can sub-split the device's rows into its owned partitions)
+    and what was counted: ``rows`` (sender x receiver), ``bucket_rows``,
+    ``capacities``."""
+    import contextlib
     from spark_rapids_tpu.columnar.batch import bucket_rows
 
     mesh = get_default_mesh()
     n_dev = mesh.shape["shuffle"]
-    total = int(batch.num_rows)
-    part_col = DeviceColumn(dt.INT32, targets.astype(jnp.int32),
-                            batch.row_mask(), None)
-    aug = DeviceBatch(list(batch.names) + ["__part__"],
-                      list(batch.columns) + [part_col], total)
-    local_cap = bucket_rows((total + n_dev - 1) // n_dev, min_bucket)
-    aug = with_capacity(aug, local_cap * n_dev)
-    leaves, counts = shard_batch(aug, mesh, "shuffle")
+    devices = list(mesh.devices.flat)
+    assert len(batches) == len(targets) == n_dev
+    template = next(_with_part(b, t) for b, t in zip(batches, targets)
+                    if b is not None)
+    augs = [_with_part(b, t) if b is not None
+            else _like_on(template, template.capacity, devices[d])
+            for d, (b, t) in enumerate(zip(batches, targets))]
+    per_chip = [_peer_counts(a, n_dev) for a in augs]
+    with (count_wait() if count_wait else contextlib.nullcontext()):
+        counts = np.stack([np.asarray(c) for c in
+                           jax.device_get(per_chip)])    # sender x receiver
+    bucket = bucket_rows(max(int(counts.max()), 1), min_bucket)
+    augs = _same_shapes(augs)
+    local_cap = augs[0].capacity
+    bucket = min(bucket, local_cap)
+    sharding = NamedSharding(mesh, P("shuffle"))
+
+    def glob(arrays):
+        shape = (n_dev * local_cap,) + arrays[0].shape[1:]
+        # a buffer made outside any kernel may be uncommitted
+        arrays = [a if a.devices() == {dev} else jax.device_put(a, dev)
+                  for a, dev in zip(arrays, devices)]
+        return jax.make_array_from_single_device_arrays(
+            shape, sharding, arrays)
+    leaves = tuple(
+        tuple(glob([leaf[k] for leaf in per_dev])
+              for k in range(len(per_dev[0])))
+        for per_dev in zip(*(_cols_to_leaves(a.columns) for a in augs)))
+    rows = jax.make_array_from_single_device_arrays(
+        (n_dev,), sharding,
+        [jax.device_put(np.asarray([counts[d].sum()], np.int32), devices[d])
+         for d in range(n_dev)])
+    first = augs[0]
     aux_key = tuple((c.dtype.name, c.data.shape[1:],
                      c.lengths is not None, c.elem_validity is not None)
-                    for c in aug.columns) + (local_cap,)
-    step = make_exchange_step(mesh, "shuffle", aug.names, aug.dtypes,
-                              aux_key)
-    out_leaves, out_rows = step(leaves, counts)
-    rows = np.asarray(out_rows)
+                    for c in first.columns) + (local_cap,)
+    step = make_exchange_step(mesh, "shuffle", first.names, first.dtypes,
+                              aux_key, bucket)
+    out_leaves, _ = step(leaves, rows)
+    received = counts.sum(axis=0)
     dev_batches: List[Optional[DeviceBatch]] = []
     for d in range(n_dev):
-        if int(rows[d]) == 0:
+        if int(received[d]) == 0:
             dev_batches.append(None)
             continue
         cols = []
-        for leaf, c in zip(out_leaves, aug.columns):
+        for leaf, c in zip(out_leaves, first.columns):
             parts = [split_shards(a, n_dev)[d] for a in leaf]
             lengths = parts[2] if c.lengths is not None else None
             ev = parts[-1] if c.elem_validity is not None else None
             cols.append(DeviceColumn(c.dtype, parts[0], parts[1],
                                      lengths, ev))
-        dev_batches.append(DeviceBatch(aug.names, cols, int(rows[d])))
-    return dev_batches, mesh
+        got = DeviceBatch(first.names, cols, int(received[d]))
+        dev_batches.append(with_capacity(
+            got, min(got.capacity,
+                     bucket_rows(int(received[d]), min_bucket))))
+    return dev_batches, {
+        "rows": counts, "bucket_rows": bucket,
+        "capacities": [0 if b is None else b.capacity
+                       for b in dev_batches]}
 
 
 def ring_broadcast_batch(batch: DeviceBatch) -> dict:
@@ -476,7 +597,7 @@ def broadcast_batch(batch: DeviceBatch) -> dict:
     fully-replicated ``jax.device_put`` lets XLA broadcast every column
     over ICI, then each device gets a zero-copy local view.
 
-    The mesh sibling of ``exchange_batch`` (all-to-all) — the
+    The mesh sibling of ``exchange_placed`` (all-to-all) — the
     ``GpuBroadcastExchangeExec`` analog (reference:
     GpuBroadcastExchangeExec.scala:238-398, which serializes the build
     side once and ships it to every executor).  Returns

@@ -41,3 +41,39 @@ def test_smoke_refuses_without_tpu(capsys):
         chip_smoke.main([])
     assert e.value.code not in (0, None)
     assert capsys.readouterr().out == ""    # no result line, no data
+
+
+def test_four_chip_smoke_phase_on_cpu(capsys, monkeypatch):
+    """``--chips 4``: q65 through the placed ICI path, here on a mesh of
+    four virtual devices at 16 scan batches of a few thousand rows."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    import chip_smoke
+    from spark_rapids_tpu.mem import device as devmgr
+    from spark_rapids_tpu.shuffle import ici
+    monkeypatch.setattr(ici, "_DEFAULT_MESH", Mesh(
+        np.array(jax.devices()[:4]), ("shuffle",)))
+    monkeypatch.setattr(jax, "devices", lambda *a: jax.local_devices()[:4])
+    monkeypatch.setitem(
+        chip_smoke.BASE_CONF,
+        "spark.rapids.tpu.sql.reader.batchSizeRows", 5000)
+    device = chip_smoke.device_info()
+    try:
+        chip_smoke.run(rows=64_000, seed=7, device=device, ici=True)
+    finally:
+        devmgr.initialize(2)
+    out = _lines(capsys)
+    assert out[-1] == {"ok": True, "device": device}
+    q65 = [o for o in out if o.get("phase") == "ici_q65"]
+    assert [o["execution"] for o in q65] == [1, 2]
+    for o in q65:
+        assert o["rows"] == 100 and o["kernel.dispatches"] > 0
+        assert o["scan.placed.chips"] >= 4
+        assert o["exchange.ici.exchanges"] == 3
+        assert o["exchange.ici.movedBatches"] == 0
+    vs = next(o for o in out if o.get("phase") == "ici_q65_vs_reference")
+    assert vs["rows_diff"] == vs["key_mismatch"] == 0
+    assert len(next(o for o in out if o.get("phase") == "ici_device_bytes")
+               ["peak_bytes_in_use"]) == 4

@@ -655,3 +655,163 @@ def test_planned_distributed_sort_then_limit():
     tpu, _ = _ici_collect(q)
     assert_tables_equal(cpu, tpu, ignore_order=False)
     assert tpu.column("k").to_pylist() == list(range(17))
+
+
+# ---------------------------------------------------------------------------
+# the exchange in place: rows that already lie on the mesh
+# ---------------------------------------------------------------------------
+
+def _mesh_of(n, monkeypatch):
+    mesh = Mesh(np.array(jax.devices()[:n]), ("shuffle",))
+    monkeypatch.setattr(ici, "_DEFAULT_MESH", mesh)
+    return list(mesh.devices.flat)
+
+
+def _placed_inputs(devices, rows, targets_of, strings=False, empty=()):
+    """One batch a device (None on the ``empty`` ones), committed there,
+    with its target partitions."""
+    import jax.numpy as jnp
+    batches, targets, sent = [], [], []
+    for d, dev in enumerate(devices):
+        if d in empty:
+            batches.append(None)
+            targets.append(None)
+            continue
+        k = np.arange(rows, dtype=np.int64) + 1_000_000 * d
+        cols = {"k": pa.array(k), "v": pa.array(k * 0.5)}
+        if strings:
+            cols["s"] = pa.array([f"row-{x}-" + "x" * (x % 7) for x in k])
+        b = jax.device_put(from_arrow(pa.table(cols), min_bucket=16), dev)
+        t = np.full(b.capacity, len(devices), np.int32)
+        t[:rows] = targets_of(d, np.arange(rows))
+        batches.append(b)
+        targets.append(jax.device_put(jnp.asarray(t), dev))
+        sent.append((k, t[:rows]))
+    return batches, targets, sent
+
+
+@pytest.mark.parametrize("case", ["uniform", "skewed", "one-chip-empty",
+                                  "strings", "all-to-one"])
+def test_exchange_placed_sizes_buckets_by_the_counted_rows(case,
+                                                           monkeypatch):
+    from spark_rapids_tpu.columnar.batch import bucket_rows, to_arrow
+    from spark_rapids_tpu.exec import placement
+    n_dev, rows = 4, 1000
+    devices = _mesh_of(n_dev, monkeypatch)
+    targets_of = {
+        "uniform": lambda d, i: i % n_dev,
+        # chip 0 receives 70% of every sender's rows
+        "skewed": lambda d, i: np.where(i % 10 < 7, 0, 1 + i % 3),
+        "one-chip-empty": lambda d, i: (i + d) % n_dev,
+        "strings": lambda d, i: (i * 7 + d) % n_dev,
+        "all-to-one": lambda d, i: np.full(len(i), 2),
+    }[case]
+    batches, targets, sent = _placed_inputs(
+        devices, rows, targets_of, strings=case == "strings",
+        empty=(3,) if case == "one-chip-empty" else ())
+    out, counted = ici.exchange_placed(batches, targets, 16)
+    counts = counted["rows"]
+    assert counts.shape == (n_dev, n_dev)
+    received = counts.sum(axis=0)
+    assert received.sum() == sum(len(k) for k, _ in sent)
+    # the buckets have the tier of the fullest one, not the sender's
+    # capacity ...
+    assert counted["bucket_rows"] == min(
+        bucket_rows(int(counts.max()), 16), 1024)
+    # ... and a receiver holds its rows at their own tier
+    for d, b in enumerate(out):
+        if not received[d]:
+            assert b is None
+            continue
+        assert int(b.num_rows) == received[d]
+        assert b.capacity == bucket_rows(int(received[d]), 16)
+        assert placement.device_of(b) == devices[d]
+        got = to_arrow(b)
+        assert set(got.column("__part__").to_pylist()) == {d}
+        want = np.sort(np.concatenate(
+            [k[t == d] for k, t in sent]))
+        assert np.array_equal(
+            np.sort(got.column("k").to_numpy()), want)
+        if case == "strings":
+            by_k = dict(zip(got.column("k").to_pylist(),
+                            got.column("s").to_pylist()))
+            assert all(s == f"row-{k}-" + "x" * (k % 7)
+                       for k, s in by_k.items())
+    if case == "skewed":
+        assert received[0] == 0.7 * n_dev * rows
+        assert out[0].capacity == 4096 and out[1].capacity == 1024
+    if case == "uniform":
+        # 250 rows a peer: 1024 slots a receiver where the sender's
+        # capacity would have made it 4096
+        assert counted["bucket_rows"] == 256
+        assert counted["capacities"] == [1024] * n_dev
+
+
+def test_bucketize_with_a_counted_bucket_capacity():
+    import jax.numpy as jnp
+    n = 500
+    b = from_arrow(pa.table({"k": pa.array(np.arange(n, dtype=np.int64))}),
+                   min_bucket=16)
+    target = jnp.asarray(np.arange(b.capacity) % 4, dtype=jnp.int32)
+    wide, counts = ici.bucketize(b, target, 4)
+    narrow, counts2 = ici.bucketize(b, target, 4, bucket_cap=128)
+    assert wide[0].data.shape == (4, b.capacity)
+    assert narrow[0].data.shape == (4, 128)
+    assert np.array_equal(np.asarray(counts), np.asarray(counts2))
+    for p in range(4):
+        c = int(counts[p])
+        assert np.array_equal(np.asarray(narrow[0].data[p, :c]),
+                              np.asarray(wide[0].data[p, :c]))
+        assert not np.asarray(narrow[0].validity[p, c:]).any()
+
+
+@pytest.mark.parametrize("n_parts", [2, 4, 7])
+def test_range_bounds_are_the_samples_weighted_quantiles(n_parts):
+    from spark_rapids_tpu.shuffle.exchange import _range_bounds
+    rng = np.random.default_rng(n_parts)
+    # two words a key; a chip of 3000 rows and one of 1000, 64 samples each
+    samples = [(3000, np.stack([np.zeros(64, np.uint64),
+                                np.sort(rng.integers(0, 1000, 64)
+                                        ).astype(np.uint64)])),
+               (1000, np.stack([np.zeros(64, np.uint64),
+                                np.sort(rng.integers(1000, 2000, 64)
+                                        ).astype(np.uint64)]))]
+    bounds = _range_bounds(samples, n_parts)
+    assert bounds.shape == (2, n_parts - 1) and bounds.dtype == np.uint64
+    keys = bounds[1].astype(np.int64)
+    assert (np.diff(keys) >= 0).all()
+    # three quarters of the rows lie under 1000: so do that share of
+    # the bounds
+    under = (keys < 1000).sum()
+    assert abs(under - 0.75 * (n_parts - 1)) <= 1
+
+
+def test_a_host_built_frame_still_exchanges_from_one_device(monkeypatch):
+    """Everything on one device of a mesh of several: the same exchange,
+    the other chips sending nothing; nothing moves before it."""
+    from spark_rapids_tpu import TpuSparkSession
+    from spark_rapids_tpu.obs import registry
+    _mesh_of(4, monkeypatch)
+    spark = TpuSparkSession({
+        "spark.rapids.tpu.shuffle.transport": "ici",
+        "spark.rapids.tpu.sql.agg.exchange.enabled": True,
+        "spark.rapids.tpu.sql.shuffle.partitions": 4})
+    try:
+        rng = np.random.default_rng(3)
+        t = pa.table({"k": pa.array(rng.integers(0, 50, 4000)),
+                      "v": pa.array(rng.integers(0, 9, 4000))})
+        view = registry.get_registry().view()
+        got = spark.create_dataframe(t).group_by("k").agg(
+            __import__("spark_rapids_tpu").functions.sum("v").alias("s")
+        ).collect().sort_by("k")
+        moved = view.delta()["counters"]
+        want = t.group_by("k").aggregate([("v", "sum")]).sort_by("k")
+        assert got.column("s").to_pylist() == \
+            want.column("v_sum").to_pylist()
+        assert moved["exchange.ici.exchanges"] >= 1
+        assert moved.get("exchange.ici.movedBatches", 0) == 0
+        assert moved["kernel.dispatches.exch_counts"] == \
+            4 * moved["exchange.ici.exchanges"]
+    finally:
+        from spark_rapids_tpu.mem import device as devmgr
+        devmgr.initialize(2)
