@@ -59,8 +59,9 @@ def bucket_rows(n: int, min_bucket: int = 16) -> int:
     """Smallest capacity tier >= n (>= min_bucket).
 
     Delegates to the shape-erased ABI's capacity ladder
-    (exec/kernel_abi.py): every 2^tierStride-th power-of-two rung under
-    the default ABI, the legacy every-pow2 ladder when the ABI is
+    (exec/kernel_abi.py): every 2^tierStride-th power-of-two rung
+    below 1,048,576 and every power of two from there up under the
+    default ABI, the legacy every-pow2 ladder when the ABI is
     disabled.  Batches BORN at tier capacities make the dispatch-time
     pad of kernel_abi.erase a no-op on the hot path."""
     from spark_rapids_tpu.exec import kernel_abi

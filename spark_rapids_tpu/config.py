@@ -575,11 +575,14 @@ KERNEL_ABI_ENABLED = conf(
 
 KERNEL_ABI_TIER_STRIDE = conf(
     "spark.rapids.tpu.kernel.abi.tierStride", 2,
-    "Row-capacity tier ladder stride: capacities quantize to every "
-    "2^stride-th power-of-two rung (stride 1 = the legacy every-pow2 "
-    "ladder; the default 2 gives tiers 16, 64, 256, 1024, ... — at "
-    "most 4x padding for at most half the distinct capacity programs "
-    "per family).", int)
+    "Row-capacity tier ladder stride: below 1,048,576 rows capacities "
+    "quantize to every 2^stride-th power-of-two rung (stride 1 = the "
+    "legacy every-pow2 ladder; the default 2 gives tiers 16, 64, 256, "
+    "1024, ..., 1048576 — at most 4x padding for at most half the "
+    "distinct capacity programs per family). From 1,048,576 up every "
+    "power of two is a tier at any stride (2097152, 4194304, ...): at "
+    "that scale a half-empty tier costs every program over it tenths "
+    "of a second a call, and one more executable its build once.", int)
 
 KERNEL_ABI_WIDTH_STRIDE = conf(
     "spark.rapids.tpu.kernel.abi.widthStride", 2,

@@ -28,7 +28,14 @@ into that identity that never change a kernel's semantics:
   3. **Shape spread.**  Row capacities and string/list widths bucket to
      every power of two; the ABI quantizes both ladders to every
      ``2**stride``-th rung (default stride 2: capacities 16, 64, 256,
-     1024, ... and widths 1, 4, 16, 64, ...).  Batches are BORN at tier
+     1024, ... and widths 1, 4, 16, 64, ...).  The capacity ladder is
+     coarse only where padding is cheap: from ``_DENSE_TIER_START``
+     (1,048,576 rows) up every power of two is a rung (1,048,576,
+     2,097,152, 4,194,304, 8,388,608, ...), because every sort pass,
+     gather and scan of a program runs over capacity, not live rows —
+     a reader batch of 1.8M rows born at 4,194,304 slots cost each
+     program above it tenths of a second a call, where one more
+     executable costs its build once.  Batches are BORN at tier
      capacities (``columnar.batch.bucket_rows`` delegates here), and
      ``pad_to_tier`` pads stragglers (hand-built batches, batches born
      under a different conf) host-side at dispatch — padding rows keep
@@ -67,6 +74,11 @@ from typing import Any, List, Optional, Tuple
 
 _enabled = True          # kernel.abi.enabled default
 _tier_stride = 2         # capacity ladder: every 2**stride-th pow2 rung
+# ... below this capacity; from it up every power of two is a rung.  The
+# last stride-2 rung at or under the reader's batch limit (1 << 21 rows,
+# sql.reader.batchSizeRows), and the scale from which a half-empty tier
+# costs tenths of a second a program
+_DENSE_TIER_START = 1 << 20
 _width_stride = 2        # string/list max_len ladder
 _bucket_hints = True     # re-bucket vbits at the ABI boundary
 
@@ -103,9 +115,10 @@ def is_enabled() -> bool:
 def tier_rows(n: int, min_bucket: int = 16) -> int:
     """Smallest capacity tier >= max(n, min_bucket): power-of-two
     rungs restricted to every ``tierStride``-th step of ONE canonical
-    ladder anchored at 1 (stride 2: 1, 4, 16, 64, 256, ...).  All
+    ladder anchored at 1 (stride 2: 1, 4, 16, 64, 256, ...) below
+    ``_DENSE_TIER_START``, every power of two from there up.  All
     tiers are powers of two, so the result is always a legacy-valid
-    capacity.
+    capacity, and never above what the stride alone would give.
 
     ``min_bucket`` is a FLOOR, not a ladder anchor: a caller-specific
     anchor (bucket_rows(n, 32)) would mint an offset ladder (32, 128,
@@ -124,7 +137,9 @@ def tier_rows(n: int, min_bucket: int = 16) -> int:
     lo = max(int(n), int(min_bucket), 1)
     step = 1 << _tier_stride
     while cap < lo:
-        cap *= step
+        nxt = cap * step
+        cap = nxt if nxt <= _DENSE_TIER_START \
+            else max(cap * 2, _DENSE_TIER_START)
     return cap
 
 

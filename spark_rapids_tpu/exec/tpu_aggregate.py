@@ -744,8 +744,9 @@ def _on_ladder(cap: int, nr, at):
     """Capacity ladder: run ``at(cap2)`` at the lowest of cap/4, cap/2
     and cap that holds the ``nr`` live rows — every sort pass, gather
     and scan scales with capacity, not live rows, and capacity tiers
-    stand 4x apart, so a batch just over a tier (1.5M rows at 4,194,304)
-    or behind a selective filter carries mostly padding.  ``at`` pads
+    stand 4x apart below 1,048,576 and 2x from there up, so a batch
+    just over a tier or behind a selective filter carries mostly
+    padding.  ``at`` pads
     its outputs back to ``cap``.  Host-known row counts pick the rung
     in Python; traced counts pick via one lax.switch (every branch
     compiles once; safe since exec/scans.py keeps 64-bit scans out of

@@ -26,6 +26,7 @@ from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
 from spark_rapids_tpu.exec.base import TpuExec, timed
 from spark_rapids_tpu.io import device_parquet as devpq
 from spark_rapids_tpu.mem.device import tpu_semaphore
+from spark_rapids_tpu.obs import registry as _obsreg
 from spark_rapids_tpu.plan.logical import FileScan, Schema
 
 
@@ -227,7 +228,6 @@ class TpuParquetScanExec(TpuExec):
         from spark_rapids_tpu.exec import placement
         chips = placement.mesh_devices(self.conf)
         if chips:
-            from spark_rapids_tpu.obs import registry as _obsreg
             _obsreg.get_registry().inc_many(
                 ("scan.placed.batches", len(groups)),
                 ("scan.placed.chips",
@@ -296,8 +296,13 @@ class TpuParquetScanExec(TpuExec):
                     # on the batch's chip
                     import jax
                     out = jax.device_put(out, prep.device)
-                self.metrics.num_output_rows += int(out.num_rows)
+                rows = int(out.num_rows)
+                self.metrics.num_output_rows += rows
                 self.metrics.add_batches()
+                # the fill of the capacity tier the batch was born at:
+                # every program above the scan runs over the slots
+                _obsreg.get_registry().inc_many(
+                    ("scan.batch.rows", rows), ("scan.batch.slots", cap))
                 return out
             finally:
                 for h in handles.values():

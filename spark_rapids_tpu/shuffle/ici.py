@@ -298,15 +298,19 @@ def get_default_mesh() -> Mesh:
 
 
 def with_capacity(batch: DeviceBatch, cap: int) -> DeviceBatch:
-    """Re-capacity a front-compacted batch (grow or shrink padding)."""
+    """Re-capacity a front-compacted batch (grow or shrink padding).
+    A shrink cuts padding off the end and is a prefix slice; only a
+    grow gathers."""
     if batch.capacity == cap:
         return batch
     assert int(batch.num_rows) <= cap
     from spark_rapids_tpu.exec import kernel_cache as kc
-    from spark_rapids_tpu.shuffle.exchange import slice_span
+    from spark_rapids_tpu.shuffle.exchange import prefix_span, slice_span
+    shrink = cap < batch.capacity     # the key holds both capacities
     fn = kc.get_kernel(
         ("exch_slice", cap, batch.schema_key()),
-        lambda: lambda b, o, c: slice_span(b, o, c, cap))
+        lambda: (lambda b, o, c: prefix_span(b, c, cap)) if shrink
+        else (lambda b, o, c: slice_span(b, o, c, cap)))
     return fn(batch, jnp.int32(0), jnp.asarray(batch.num_rows, jnp.int32))
 
 

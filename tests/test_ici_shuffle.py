@@ -815,3 +815,38 @@ def test_a_host_built_frame_still_exchanges_from_one_device(monkeypatch):
     finally:
         from spark_rapids_tpu.mem import device as devmgr
         devmgr.initialize(2)
+
+
+@pytest.mark.parametrize("rows,cap", [(5, 16), (16, 16), (17, 64),
+                                      (100, 128), (0, 16)])
+def test_with_capacity_shrinks_by_a_prefix_slice_to_the_gathers_batch(
+        rows, cap):
+    """A receiver cut to the tier of its rows (exchange_placed) is the
+    batch ``slice_span``'s gather gives, column for column, and its
+    program holds no gather."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.shuffle.exchange import prefix_span, slice_span
+    rng = np.random.default_rng(rows)
+    t = pa.table({
+        "k": pa.array(rng.integers(0, 9, rows).astype(np.int32),
+                      mask=rng.random(rows) < 0.2),
+        "v": pa.array(rng.random(rows)),
+        "s": pa.array([None if i % 5 == 0 else "x" * (i % 7)
+                       for i in range(rows)], type=pa.string()),
+        "b": pa.array(rng.random(rows) < 0.5),
+    })
+    big = ici.with_capacity(from_arrow(t), 256)        # a grow: gathers
+    assert big.capacity == 256 and int(big.num_rows) == rows
+    got = ici.with_capacity(big, cap)
+    want = slice_span(big, jnp.int32(0), jnp.int32(rows), cap)
+    assert got.capacity == cap and int(got.num_rows) == rows
+    for g, w in zip(jax.tree_util.tree_leaves(got.columns),
+                    jax.tree_util.tree_leaves(want.columns)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    text = jax.jit(lambda b, c: prefix_span(b, c, cap)).lower(
+        big, jnp.int32(rows)).as_text()
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.gather" in jax.jit(
+        lambda b, c: slice_span(b, jnp.int32(0), c, cap)).lower(
+            big, jnp.int32(rows)).as_text()

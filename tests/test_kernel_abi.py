@@ -75,6 +75,87 @@ def test_tier_ladders():
     assert kernel_abi.tier_strlen(5) == 8
 
 
+def _stride_ladder(n: int, stride: int, min_bucket: int = 16) -> int:
+    """The ladder as the stride alone gives it (no dense part)."""
+    cap, lo = 1, max(n, min_bucket, 1)
+    while cap < lo:
+        cap <<= stride
+    return cap
+
+
+_DENSE = 1 << 20
+# row counts around every rung of every stride up to 2**25, and the
+# cells' own (a reader batch of q65, q3 and Q1; q65's partial groups)
+_LADDER_PROBES = sorted(
+    {max(1, (1 << e) + d) for e in range(26) for d in (-1, 0, 1)} |
+    {1_800_061, 1_440_202, 1_500_304, 5_332_700, 3_000_000, 300_000})
+
+
+@pytest.mark.parametrize("stride", [1, 2, 3])
+def test_ladder_below_the_dense_start_is_the_strides(stride):
+    kernel_abi._tier_stride = stride
+    rungs = {kernel_abi.tier_rows(n) for n in _LADDER_PROBES}
+    strided = {1 << e for e in range(0, 20, stride) if (1 << e) >= 16}
+    # the least rung is min_bucket's own rung of the stride's ladder
+    assert {r for r in rungs if r < _DENSE} == \
+        {r for r in strided if r >= _stride_ladder(1, stride)}
+    top = max(strided)              # the last strided rung under 2**20
+    for n in _LADDER_PROBES:
+        if n <= top:
+            assert kernel_abi.tier_rows(n) == _stride_ladder(n, stride)
+    # whatever lies over it goes to the dense part's first rung
+    assert kernel_abi.tier_rows(top + 1) == _DENSE
+
+
+@pytest.mark.parametrize("stride", [1, 2, 3])
+@pytest.mark.parametrize("n,tier", [
+    (1_048_576, 1_048_576), (1_048_577, 2_097_152),
+    (1_800_061, 2_097_152), (2_097_152, 2_097_152),
+    (2_097_153, 4_194_304), (5_332_700, 8_388_608),
+    (16_777_217, 33_554_432)])
+def test_ladder_is_every_power_of_two_from_the_dense_start(stride, n,
+                                                           tier):
+    kernel_abi._tier_stride = stride
+    assert kernel_abi.tier_rows(n) == tier
+    assert kernel_abi.is_tier(tier)
+    assert kernel_abi.tier_rows(n, min_bucket=64) == tier
+
+
+@pytest.mark.parametrize("stride", [1, 2, 3])
+def test_dense_ladder_is_never_above_the_strides(stride):
+    kernel_abi._tier_stride = stride
+    for n in _LADDER_PROBES:
+        for floor in (1, 8, 16, 64):
+            t = kernel_abi.tier_rows(n, floor)
+            assert max(n, floor) <= t <= _stride_ladder(n, stride, floor)
+            assert t & (t - 1) == 0
+            assert kernel_abi.is_tier(t)
+
+
+@pytest.mark.parametrize("n,legacy", [
+    (300_000, 524_288), (1_048_577, 2_097_152), (1_800_061, 2_097_152),
+    (2_097_153, 4_194_304), (5_332_700, 8_388_608)])
+def test_disabled_abi_keeps_the_legacy_ladder(n, legacy):
+    kernel_abi._enabled = False
+    assert kernel_abi.tier_rows(n) == legacy
+    assert kernel_abi.tier_rows(n, min_bucket=64) == legacy
+
+
+def test_erase_leaves_a_half_rung_batch_unpadded():
+    """2,097,152 is a rung since the ladder is dense from 1,048,576: a
+    reader batch born there is dispatched as it is, buffers shared
+    (before, erase padded it to 4,194,304 at every dispatch)."""
+    cap, n = 2_097_152, 1_800_061
+    assert kernel_abi.is_tier(cap) and kernel_abi.tier_rows(n) == cap
+    valid = jnp.arange(cap) < n
+    b = DeviceBatch(["k"], [DeviceColumn(
+        dt.INT32, jnp.zeros(cap, dtype=jnp.int32), valid)], n)
+    eb = kernel_abi.erase(b)
+    assert eb.capacity == cap and eb.num_rows == n
+    assert eb.columns[0].data is b.columns[0].data
+    assert eb.columns[0].validity is b.columns[0].validity
+
+
 def test_bucket_vbits():
     assert kernel_abi.bucket_vbits(None) is None
     assert kernel_abi.bucket_vbits(8) == 16
