@@ -38,8 +38,9 @@ class TpuSparkSession:
     def __init__(self, conf: Optional[Dict[str, Any]] = None):
         self.conf = RapidsTpuConf(conf)
         from spark_rapids_tpu.exec import placement
+        mesh = placement.mesh_devices(self.conf)
         devmgr.initialize(self.conf.get(cfg.CONCURRENT_TPU_TASKS),
-                          chips=len(placement.mesh_devices(self.conf)))
+                          chips=len(mesh), device_ids=[d.id for d in mesh])
         # -- fleet shared cache plane (fleet/store.py): attach BEFORE
         # the compile cache and compile observatory configure, so the
         # shared compile-cache directory and corpus directory take
@@ -310,7 +311,8 @@ class TpuSparkSession:
             # task, the slots count a chip (exec/placement)
             from spark_rapids_tpu.exec.placement import drain_by_chip
             parts: List[List] = [[] for _ in its]
-            drain_by_chip(its, lambda p, b: parts[p].append(b))
+            drain_by_chip(its, lambda p, b: parts[p].append(b),
+                          stage="collect")
             return [x for part in parts for x in part]
         if len(its) <= 1 or n_tasks <= 1:
             out: List = []

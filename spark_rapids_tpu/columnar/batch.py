@@ -24,7 +24,6 @@ byte-tensor string kernels (SURVEY.md §7 hard part #3).
 
 from __future__ import annotations
 
-import contextlib
 from dataclasses import dataclass, replace
 from typing import List, Optional, Sequence, Tuple
 
@@ -468,12 +467,9 @@ def _download_batch(batch: DeviceBatch, packed: Optional[jnp.ndarray]
     arrays at full capacity."""
     if packed is None:
         packed = _dispatch_pack(batch)
-    for arr in packed.values():  # overlap the (few) transfers
-        try:
-            arr.copy_to_host_async()
-        except Exception:
-            pass
-    host = {k: np.asarray(v) for k, v in packed.items()}
+    # one read of the (few) buffers, their transfers overlapped
+    host = dict(zip(packed, read_host(list(packed.values()),
+                                      "collect.downloadWait")))
     pos = {k: 0 for k in host}
 
     def take(key: str, count: int):
@@ -564,8 +560,53 @@ def _run_compact(b: DeviceBatch, fn, t: int) -> DeviceBatch:
     return DeviceBatch(b.names, nb.columns, nb.num_rows)
 
 
+def _fetched(value) -> bool:
+    """Whether jax has already copied ``value`` to the host (it keeps
+    the copy, so reading it again moves nothing)."""
+    return getattr(value, "_npy_value", None) is not None
+
+
+def read_host(values, site: str):
+    """The host's blocking read of device values.  ``values`` is what
+    one site reads at once: one value, or a list of them; the host
+    values come back in the same form (numpy for a device array).
+
+    The engine's device-to-host reads all pass here, so each is counted
+    where it happens.  A call that copies counts once in
+    ``device.reads`` and once in ``device.reads.<site>`` and, with
+    tracing on, copies inside a span named ``site`` (cat
+    ``device.read``, args ``chips``: the device ids of the values, and
+    ``values``: how many were copied).  A value the host already knows
+    (a host number, or a device array jax has copied before) is no
+    read.  A site is named ``<layer>.<what>Wait``.  The copy is
+    ``jax.device_get``, an explicit transfer, so a run under
+    ``jax.transfer_guard_device_to_host("log")`` logs only the copies
+    that bypass this helper."""
+    one = not isinstance(values, (list, tuple))
+    vals = [values] if one else list(values)
+    at = [i for i, v in enumerate(vals)
+          if isinstance(v, jax.Array) and not _fetched(v)]
+    if at:
+        from spark_rapids_tpu.obs import registry as obsreg
+        from spark_rapids_tpu.obs import trace as obstrace
+        obsreg.get_registry().inc_many(("device.reads", 1),
+                                       (f"device.reads.{site}", 1))
+        dev = [vals[i] for i in at]
+        if obstrace.is_enabled():
+            chips = sorted({d.id for v in dev for d in v.devices()})
+            with obstrace.span(site, cat="device.read",
+                               args={"chips": chips, "values": len(dev)}):
+                got = jax.device_get(dev)
+        else:
+            got = jax.device_get(dev)
+        for i, g in zip(at, got):
+            vals[i] = g
+    vals = [np.asarray(v) if isinstance(v, jax.Array) else v for v in vals]
+    return vals[0] if one else vals
+
+
 def read_row_counts(batches: Sequence[DeviceBatch],
-                    wait_span: Optional[str] = None) -> List[int]:
+                    site: str) -> List[int]:
     """Row counts of ``batches`` on the host.  Counts that are still
     device scalars come back in ONE transfer (stacked, copied once),
     however many batches there are; where every count is host-known
@@ -573,9 +614,8 @@ def read_row_counts(batches: Sequence[DeviceBatch],
 
     The copy blocks until every program that feeds a count has run, so
     it belongs where the host has nothing left to enqueue (the terminal
-    collect, a pipeline breaker's last input).  ``wait_span`` names that
-    wait in the trace."""
-    from spark_rapids_tpu.obs import trace as obstrace
+    collect, a pipeline breaker's last input).  ``site`` names that
+    read (:func:`read_host`)."""
     counts = [b.num_rows for b in batches]
     traced = [i for i, n in enumerate(counts)
               if not isinstance(n, (int, np.integer))]
@@ -589,16 +629,13 @@ def read_row_counts(batches: Sequence[DeviceBatch],
         if len(devs) > 1:
             tgt = sorted(devs, key=lambda d: d.id)[0]
             scalars = [jax.device_put(s, tgt) for s in scalars]
-        with obstrace.span(wait_span, cat="query") if wait_span \
-                else contextlib.nullcontext():
-            got = np.asarray(jnp.stack(scalars))
+        got = read_host(jnp.stack(scalars), site)
         for i, n in zip(traced, got):
             counts[i] = n
     return [int(n) for n in counts]
 
 
-def _compact_for_download(batches: Sequence[DeviceBatch],
-                          wait_span: Optional[str] = None):
+def _compact_for_download(batches: Sequence[DeviceBatch]):
     """Re-bucket batches whose capacity vastly exceeds their row count
     (e.g. an aggregate output that inherited a multi-million-row concat
     capacity) so the terminal download moves rows, not padding.
@@ -611,9 +648,8 @@ def _compact_for_download(batches: Sequence[DeviceBatch],
 
     That read is the first point at which the host blocks on the
     device: everything dispatched so far has to finish before the
-    counts arrive.  ``wait_span`` names it in the trace (the terminal
-    collect passes ``collect.deviceWait``); it is a name round a wait
-    that is there anyway, never a sync of its own."""
+    counts arrive: the read ``collect.deviceWait`` (:func:`read_host`),
+    a name round a wait that is there anyway, never a sync of its own."""
     candidates = {}
     full_packed = []
     for b in batches:
@@ -633,7 +669,7 @@ def _compact_for_download(batches: Sequence[DeviceBatch],
         full_packed.append(_dispatch_pack(b))
     out, out_packed = [], []
     for b, fp, n in zip(batches, full_packed,
-                        read_row_counts(batches, wait_span)):
+                        read_row_counts(batches, "collect.deviceWait")):
         b.num_rows = n
         tier = _dl_tier(n, b.capacity)
         if tier is not None and id(b) in candidates and \
@@ -665,8 +701,7 @@ def to_arrow_all(batches: Sequence[DeviceBatch]) -> List[pa.Table]:
     query's work) and ``collect.download`` (the compacted batches'
     pack, the host copies and the Arrow build)."""
     from spark_rapids_tpu.obs import trace as obstrace
-    batches, packed = _compact_for_download(
-        batches, wait_span="collect.deviceWait")
+    batches, packed = _compact_for_download(batches)
     with obstrace.span("collect.download", cat="query"):
         return [to_arrow(b, p) for b, p in zip(batches, packed)]
 

@@ -116,7 +116,8 @@ class Metrics:
     def num_output_rows(self) -> int:
         with self._rows_lock:
             if self._rows_pending:
-                self._rows_host += sum(int(x)
+                from spark_rapids_tpu.columnar.batch import read_host
+                self._rows_host += sum(int(read_host(x, "metrics.rowsWait"))
                                        for x in self._rows_pending)
                 self._rows_pending.clear()
             return self._rows_host

@@ -18,11 +18,13 @@ one-device path.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Callable, List, Optional, Sequence
 
 from spark_rapids_tpu import config as cfg
 from spark_rapids_tpu.mem import device as devmgr
+from spark_rapids_tpu.obs import trace as obstrace
 from spark_rapids_tpu.sched import cancel as _cancel
 
 
@@ -46,14 +48,20 @@ def device_of(batch):
 
 
 def drain_by_chip(its: Sequence, sink: Callable,
-                  n_dev: Optional[int] = None) -> None:
+                  n_dev: Optional[int] = None, *, stage: str) -> None:
     """Drain partition iterators with the partitions of different chips
     side by side: partition ``p`` is a task of chip ``p % n_dev``, a
     chip runs at most ``concurrentTpuTasks`` of its tasks at a time and
     takes them in partition order.  ``sink(p, batch)`` is called for
     every batch on the task's thread.  ``n_dev`` defaults to the chips
     the session places partitions on; on one chip (or one partition) it
-    is a plain loop on the caller's thread."""
+    is a plain loop on the caller's thread.
+
+    The drain is a barrier: a chip whose tasks end first waits for its
+    peers.  With tracing on, each such chip gets a ``chip.peerWait``
+    span (cat ``query``, stamped with the chip, args ``stage``: what
+    the caller drains for) from its last task's end to the drain's
+    end; the slowest chip gets none."""
     n_dev = devmgr.chips() if n_dev is None else n_dev
     if n_dev <= 1 or len(its) <= 1:
         for p, it in enumerate(its):
@@ -64,21 +72,31 @@ def drain_by_chip(its: Sequence, sink: Callable,
               for c in range(n_dev)]
     tok = _cancel.current()
     errors: List[BaseException] = []
+    # when each chip's last task thread ended (a chip with no task: the
+    # drain's start)
+    t_start = time.perf_counter_ns()
+    ended = [t_start] * n_dev
+    lock = threading.Lock()
 
     def work(chip: int) -> None:
         # task threads inherit the query's CancelToken explicitly
         # (threads don't propagate thread-locals)
-        with _cancel.install(tok), devmgr.task_chip(chip):
-            while not errors:
-                try:
-                    p = queues[chip].popleft()
-                except IndexError:
-                    return
-                try:
-                    for b in its[p]:
-                        sink(p, b)
-                except BaseException as e:
-                    errors.append(e)
+        try:
+            with _cancel.install(tok), devmgr.task_chip(chip):
+                while not errors:
+                    try:
+                        p = queues[chip].popleft()
+                    except IndexError:
+                        return
+                    try:
+                        for b in its[p]:
+                            sink(p, b)
+                    except BaseException as e:
+                        errors.append(e)
+        finally:
+            t = time.perf_counter_ns()
+            with lock:
+                ended[chip] = max(ended[chip], t)
 
     threads = [threading.Thread(target=work, args=(c,),
                                 name=f"tpu-task-chip{c}-{k}")
@@ -90,3 +108,11 @@ def drain_by_chip(its: Sequence, sink: Callable,
         t.join()
     if errors:
         raise errors[0]
+    if obstrace.is_enabled():
+        t_end = time.perf_counter_ns()
+        last = max(ended)
+        for chip, t in enumerate(ended):
+            if t < last:
+                obstrace.record("chip.peerWait", t, t_end - t, cat="query",
+                                args={"stage": stage},
+                                chip=devmgr.chip_device_id(chip))

@@ -122,7 +122,7 @@ class TpuReusedSubplanExec(TpuExec):
             # partitions of different chips side by side (one loop here
             # on one chip)
             drain_by_chip(its, lambda p, b: parts[p].append(
-                register_or_hold(b)))
+                register_or_hold(b)), stage="reuse")
             assert len(parts) == self.partitions, \
                 (len(parts), self.partitions)
         except BaseException as e:

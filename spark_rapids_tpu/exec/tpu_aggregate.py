@@ -62,7 +62,8 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
-                                             bucket_rows, concat_batches)
+                                             bucket_rows, concat_batches,
+                                             read_host)
 from spark_rapids_tpu.exec.base import PhysicalPlan, TpuExec, timed
 from spark_rapids_tpu.exec import scans, sortkeys
 from spark_rapids_tpu.expr import eval_tpu, ir
@@ -1155,7 +1156,8 @@ class TpuHashAggregateExec(TpuExec):
                 if len(partials) > 1:
                     # the consumer is done with the batch, so the count
                     # is long computed: no wait, no dispatch
-                    reg.inc("agg.merge.groupsOut", int(out.num_rows))
+                    reg.inc("agg.merge.groupsOut", int(read_host(
+                        out.num_rows, "agg.mergeCountWait")))
             finally:
                 for p in partials:
                     p.close()
@@ -1213,10 +1215,7 @@ def _shrink_partials(partials: List, grouped: bool,
         if not grouped:
             counts = [1] * len(batches)
         else:
-            if any(not isinstance(b.num_rows, (int, np.integer))
-                   for b in batches):
-                reg.inc("agg.partials.read")
-            counts = read_row_counts(batches, wait_span="agg.countWait")
+            counts = read_row_counts(batches, "agg.countWait")
             first = len(batches) - n_updates
             for b, n in zip(batches[first:], counts[first:]):
                 if _ladder_engages(b.capacity):   # else: no choice built

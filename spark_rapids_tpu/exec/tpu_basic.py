@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
                                              bucket_rows, concat_batches,
-                                             from_arrow, to_arrow)
+                                             from_arrow, read_host,
+                                             to_arrow)
 from spark_rapids_tpu.exec.base import (CoalesceGoal, PhysicalPlan,
                                         RequireSingleBatch, TargetSize,
                                         TpuExec, timed)
@@ -347,7 +348,7 @@ class TpuGlobalLimitExec(TpuExec):
                 for b in it:
                     if remaining <= 0:
                         return
-                    rows = int(b.num_rows)
+                    rows = int(read_host(b.num_rows, "limit.rowsWait"))
                     take = min(remaining, rows)
                     remaining -= take
                     if take == rows:
@@ -383,7 +384,8 @@ class TpuCoalesceBatchesExec(TpuExec):
             pending: List[DeviceBatch] = []
             pending_bytes = 0
             for b in it:
-                if int(b.num_rows) == 0 and pending:
+                if not int(read_host(b.num_rows, "coalesce.rowsWait")) \
+                        and pending:
                     continue
                 pending.append(b)
                 pending_bytes += b.nbytes()

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
                                              _combined_hints, bucket_rows,
-                                             concat_batches)
+                                             concat_batches, read_host)
 from spark_rapids_tpu.exec import scans, sortkeys
 from spark_rapids_tpu.exec.base import (PhysicalPlan, REQUIRE_SINGLE_BATCH,
                                         TpuExec, timed)
@@ -60,7 +60,8 @@ def _gather(child: PhysicalPlan) -> Optional[DeviceBatch]:
     parts = [[] for _ in its]
     # partitions of different chips side by side (one loop here on one
     # chip); partition order is kept
-    drain_by_chip(its, lambda p, b: parts[p].append(register_or_hold(b)))
+    drain_by_chip(its, lambda p, b: parts[p].append(register_or_hold(b)),
+                  stage="join")
     handles = [h for part in parts for h in part]
     if not handles:
         return None
@@ -729,8 +730,6 @@ class _HashJoinBase(TpuExec):
         if self._direct_wide is None:
             return None
         from spark_rapids_tpu.exec import kernel_abi, kernel_cache as kc
-        from spark_rapids_tpu.obs import registry as obsreg
-        from spark_rapids_tpu.obs import trace as obstrace
         keys = self.left_keys if build_is_left else self.right_keys
         pos = tuple(build.names.index(k) for k in keys)
         if isinstance(build.num_rows, (int, np.integer)) and \
@@ -739,9 +738,7 @@ class _HashJoinBase(TpuExec):
         eb = kernel_abi.erase(build)
         fn = kc.get_kernel(("join_range", pos, _side_key(eb)),
                            lambda: lambda b: _range_kernel(b, pos))
-        with obstrace.span("join.rangeWait", cat="query"):
-            got = np.asarray(fn(eb))
-        obsreg.get_registry().inc("join.rangeReads")
+        got = read_host(fn(eb), "join.rangeWait")
         lo, hi = [int(v) for v in got[:, 0]], [int(v) for v in got[:, 1]]
         if any(l > h for l, h in zip(lo, hi)):
             lo, hi = [0] * len(pos), [0] * len(pos)   # nothing to match
@@ -765,7 +762,8 @@ class _HashJoinBase(TpuExec):
         with timed(self.metrics, "join.probeCount"):
             total, maxm = self._kernels[ckey](build, stream, kr.base,
                                               kr.extent)
-            total, maxm = int(total), int(maxm)
+            total, maxm = (int(v) for v in read_host([total, maxm],
+                                                     "join.countWait"))
         if total >= (1 << 31):
             raise MemoryError(
                 f"join output of {total} rows exceeds the single-batch "
@@ -1116,7 +1114,7 @@ class TpuBroadcastHashJoinExec(_BroadcastBuildMixin, _HashJoinBase):
             else:
                 self._build()
             for sb in sit:
-                if not int(sb.num_rows):
+                if not int(read_host(sb.num_rows, "join.streamRowsWait")):
                     continue
                 build = self._build_for(sb)
                 b = build if build is not None else \

@@ -15,7 +15,9 @@ reference runs one executor a GPU, each with its own semaphore and
 pool): a task thread says which chip it works for (``task_chip``, set
 by ``exec/placement.drain_by_chip`` and by a placed scan partition) and
 ``tpu_semaphore`` takes a slot of that chip's gate.  A thread that says
-nothing works for chip 0, so on one device nothing changes.
+nothing works for chip 0, so on one device nothing changes.  The tracer
+stamps a span with the jax device id of the chip its thread works for
+(:func:`span_chip`).
 """
 
 from __future__ import annotations
@@ -23,7 +25,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 from spark_rapids_tpu.obs import registry as obsreg
 from spark_rapids_tpu.obs import trace as obstrace
@@ -34,16 +36,22 @@ _LOCK = threading.Lock()
 _GATES: Dict[int, TaskGate] = {}       # chip index -> its slots
 _SLOTS = 2
 _CHIPS = 1
+_CHIP_IDS: List[int] = []              # jax device id of each chip, in mesh order
 _TLS = threading.local()               # .chip: the chip this thread works for
 
 
-def initialize(concurrent_tasks: int, chips: int = 1) -> None:
+def initialize(concurrent_tasks: int, chips: int = 1,
+               device_ids: Optional[Sequence[int]] = None) -> None:
     """``chips``: the mesh devices partitions are placed on (1 where
-    they are not, ``exec/placement.mesh_devices``)."""
+    they are not, ``exec/placement.mesh_devices``); ``device_ids``
+    their jax device ids in mesh order (default ``0 .. chips-1``)."""
     global _SLOTS, _CHIPS
     with _LOCK:
         _SLOTS = max(1, int(concurrent_tasks))
         _CHIPS = max(1, int(chips))
+        _CHIP_IDS[:] = [] if _CHIPS == 1 else \
+            [int(i) for i in (device_ids if device_ids is not None
+                              else range(_CHIPS))]
         _GATES.clear()
 
 
@@ -58,14 +66,27 @@ def chips() -> int:
 
 
 def current_chip() -> int:
-    return getattr(_TLS, "chip", 0)
+    return getattr(_TLS, "chip", None) or 0
+
+
+def chip_device_id(chip: int) -> Optional[int]:
+    """The jax device id of mesh device ``chip`` (the id that names its
+    trace plane, ``/device:TPU:<id>``); None on one chip."""
+    return _CHIP_IDS[chip] if 0 <= chip < len(_CHIP_IDS) else None
+
+
+def span_chip() -> Optional[int]:
+    """The jax device id of the chip this thread works for; None on a
+    thread that works for no single chip, and on one chip."""
+    chip = getattr(_TLS, "chip", None)
+    return None if chip is None else chip_device_id(chip)
 
 
 @contextlib.contextmanager
 def task_chip(chip: int):
     """This thread works for mesh device ``chip`` until the block ends:
     its ``tpu_semaphore`` acquisitions count against that chip."""
-    prev = current_chip()
+    prev = getattr(_TLS, "chip", None)
     _TLS.chip = int(chip)
     try:
         yield

@@ -19,13 +19,16 @@ event pairs a Perfetto / chrome://tracing load renders as a flame
 graph.
 
 **One tree a query.**  Every span carries an id minted at *entry*, the
-id of its ``parent`` and the ``query`` it works for.  The parent is the
-innermost span open on the thread at entry (a thread-local stack that
-``span()`` and ``exec/base``'s ``timed``/``timed_extra`` push and pop);
-the query comes from the thread's ``CancelToken``
-(``sched/cancel.current()``), so prefetch, task-pool and streamer
-threads label their spans with the query they work for.  A span with a
-query and no open parent hangs under that query's root
+id of its ``parent``, the ``query`` it works for and the ``chip`` its
+thread works for.  The parent is the innermost span open on the thread
+at entry (a thread-local stack that ``span()`` and ``exec/base``'s
+``timed``/``timed_extra`` push and pop); the query comes from the
+thread's ``CancelToken`` (``sched/cancel.current()``), so prefetch,
+task-pool and streamer threads label their spans with the query they
+work for; the chip is the jax device id ``mem/device.span_chip`` gives
+(None on one chip and on a thread that works for no single chip: serve,
+planner).  A span with a query and no open parent hangs under that
+query's root
 (:func:`root_id`): ``serve.request`` for a served query, ``query``
 otherwise.  :func:`query_spans` is a query's tree; ``mark()`` /
 ``spans_since()`` stay for callers that want a window of the ring
@@ -43,12 +46,13 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 DEFAULT_BUFFER_SPANS = 65536
 
-# one span record (indices 0-7 are the original eight; 8-10 appended):
+# one span record (indices 0-7 are the original eight; 8-11 appended):
 #   (seq, tid, name, cat, t0_ns, dur_ns, depth, args,
-#    span id, parent span id (0: none), query id (None: no query))
+#    span id, parent span id (0: none), query id (None: no query),
+#    chip: jax device id (None: no single chip))
 Span = Tuple[int, int, str, str, int, int, int, Optional[Dict[str, Any]],
-             int, int, Optional[int]]
-SID, PARENT, QUERY = 8, 9, 10
+             int, int, Optional[int], Optional[int]]
+SID, PARENT, QUERY, CHIP = 8, 9, 10, 11
 
 _enabled = False
 _ring: deque = deque(maxlen=DEFAULT_BUFFER_SPANS)
@@ -109,6 +113,18 @@ def mark() -> int:
 
 
 _current_token = None
+_span_chip = None
+
+
+def current_chip() -> Optional[int]:
+    """The jax device id of the chip this thread works for
+    (``mem/device.span_chip``; ``mem`` imports this module, hence the
+    late import)."""
+    global _span_chip
+    if _span_chip is None:
+        from spark_rapids_tpu.mem.device import span_chip
+        _span_chip = span_chip
+    return _span_chip()
 
 
 def current_query() -> Optional[int]:
@@ -195,13 +211,13 @@ def close_span(sid: int, name: str, t0_ns: int, dur_ns: int,
 def record(name: str, t0_ns: int, dur_ns: int, cat: str = "exec",
            args: Optional[Dict[str, Any]] = None,
            depth: Optional[int] = None, parent: Optional[int] = None,
-           query: Optional[int] = None, sid: Optional[int] = None
-           ) -> None:
+           query: Optional[int] = None, sid: Optional[int] = None,
+           chip: Optional[int] = None) -> None:
     """Record one completed span. No-op (one bool check) when disabled.
 
     ``parent`` defaults to the innermost span open on this thread, else
-    to the query's root; ``query`` to the thread's (see the module
-    docstring).  ``sid`` is given by who minted the id at entry
+    to the query's root; ``query`` and ``chip`` to the thread's (see the
+    module docstring).  ``sid`` is given by who minted the id at entry
     (:func:`open_span`, :func:`record_root`)."""
     if not _enabled:
         return
@@ -214,9 +230,11 @@ def record(name: str, t0_ns: int, dur_ns: int, cat: str = "exec",
         sid = next(_ids)
     if parent is None:
         parent = _parent_here(stack, query)
+    if chip is None:
+        chip = current_chip()
     _ring.append((next(_seq), threading.get_ident(), name, cat,
                   int(t0_ns), int(dur_ns), depth, args, sid, parent,
-                  query))
+                  query, chip))
 
 
 class _NoopSpan:
@@ -315,7 +333,8 @@ def record_foreign(spans: Sequence[Span], offset_ns: int,
             a.setdefault("lane", _tid_labels[lane])
             _ring.append((next(_seq), lane, name, cat,
                           int(t0) + int(offset_ns), int(dur),
-                          int(depth), a, sid, parent, query))
+                          int(depth), a, sid, parent, query,
+                          s[CHIP] if len(s) > CHIP else None))
             n += 1
     return n
 
@@ -345,11 +364,11 @@ def query_spans(query: int) -> List[Span]:
 def span_dicts(spans: Sequence[Span]) -> List[Dict[str, Any]]:
     """JSON-friendly rendering (the QueryProfile ``spans`` section)."""
     out = []
-    for seq, tid, name, cat, t0, dur, depth, args, sid, parent, query \
-            in spans:
+    for seq, tid, name, cat, t0, dur, depth, args, sid, parent, query, \
+            chip in spans:
         d = {"name": name, "cat": cat, "tid": tid, "ts_ns": t0,
              "dur_ns": dur, "depth": depth, "id": sid,
-             "parent": parent, "query": query}
+             "parent": parent, "query": query, "chip": chip}
         if args:
             d["args"] = args
         out.append(d)
@@ -393,7 +412,9 @@ def chrome_trace(spans: Optional[Sequence[Span]] = None
         def emit(ph: str, s: Span, ts_ns: int) -> None:
             ev = {"name": s[2], "cat": s[3], "ph": ph, "pid": 0,
                   "tid": tid, "ts": ts_ns / 1e3}
-            if ph == "B" and s[7]:
+            if ph == "B" and s[CHIP] is not None:
+                ev["args"] = {**(s[7] or {}), "chip": s[CHIP]}
+            elif ph == "B" and s[7]:
                 ev["args"] = s[7]
             events.append(ev)
 

@@ -45,7 +45,7 @@ from spark_rapids_tpu import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
                                              _bcast, bucket_rows,
                                              concat_batches, from_arrow,
-                                             to_arrow)
+                                             read_host, to_arrow)
 from spark_rapids_tpu.exec import sortkeys
 from spark_rapids_tpu.exec.base import (PhysicalPlan, TpuExec, timed,
                                         timed_extra)
@@ -1679,9 +1679,11 @@ class TpuShuffleExchangeExec(TpuExec):
             its = self.children[0].execute()
             parts: List[List[DeviceBatch]] = [[] for _ in its]
             placement.drain_by_chip(
-                its, lambda p, b: parts[p].append(b), n_dev)
+                its, lambda p, b: parts[p].append(b), n_dev,
+                stage="exchange")
             batches = [b for part in parts for b in part
-                       if int(b.num_rows)]
+                       if int(read_host(b.num_rows,
+                                        "exchange.mapRowsWait"))]
             if batches:
                 # the span carries what the exchange counted
                 with timed(self.metrics), \
@@ -1733,7 +1735,7 @@ class TpuShuffleExchangeExec(TpuExec):
                         key, lambda: extract)
                 with timed(self.metrics, "exchange.iciExtract"):
                     out = self._kernels[key](b, jnp.int32(pidx))
-            if int(out.num_rows) == 0:
+            if not int(read_host(out.num_rows, "exchange.partRowsWait")):
                 return
             out = DeviceBatch(out.names[:-1], out.columns[:-1],
                               out.num_rows)  # drop __part__
@@ -1750,13 +1752,14 @@ class TpuShuffleExchangeExec(TpuExec):
         ``info`` with what was counted (the ``exchange.ici`` span's
         arguments and the counters)."""
         from spark_rapids_tpu.exec import placement
-        from spark_rapids_tpu.obs import trace as obstrace
         from spark_rapids_tpu.shuffle import ici
         index = {d: i for i, d in enumerate(devices)}
         homes = [index.get(placement.device_of(b)) for b in batches]
-        info["rows_in"] = sum(int(b.num_rows) for b in batches)
+        info["rows_in"] = sum(
+            int(read_host(b.num_rows, "exchange.mapRowsWait"))
+            for b in batches)
         info["bytes_in"] = sum(
-            int(b.num_rows) * sum(
+            int(read_host(b.num_rows, "exchange.mapRowsWait")) * sum(
                 a.dtype.itemsize * int(np.prod(a.shape[1:]))
                 for c in b.columns
                 for a in (c.data, c.validity, c.lengths, c.elem_validity)
@@ -1770,9 +1773,7 @@ class TpuShuffleExchangeExec(TpuExec):
             per_chip[h].append(b)
         held = [concat_batches(bs) if bs else None for bs in per_chip]
         targets = self._placed_targets(held)
-        dev, counted = ici.exchange_placed(
-            held, targets, self.min_bucket,
-            count_wait=lambda: obstrace.span("exchange.countWait"))
+        dev, counted = ici.exchange_placed(held, targets, self.min_bucket)
         info["bucket_rows"] = counted["bucket_rows"]
         info["capacities"] = counted["capacities"]
         info["received"] = [int(n) for n in counted["rows"].sum(axis=0)]
@@ -1792,10 +1793,10 @@ class TpuShuffleExchangeExec(TpuExec):
             for g in held:
                 out.append(None if g is None
                            else self._compute_targets(g, seen))
-                seen += 0 if g is None else int(g.num_rows)
+                seen += 0 if g is None else int(
+                    read_host(g.num_rows, "exchange.heldRowsWait"))
             return out
         from spark_rapids_tpu.exec import kernel_cache as kc
-        from spark_rapids_tpu.obs import trace as obstrace
         n_parts = p.num_partitions
         orders = p.orders
 
@@ -1833,12 +1834,12 @@ class TpuShuffleExchangeExec(TpuExec):
                 ("exch_rkeys", p.cache_sig(), g.schema_key()),
                 lambda: keys_impl)(g)
             words.append(wm)
-            samples.append((int(g.num_rows), kc.get_kernel(
+            samples.append((int(read_host(
+                g.num_rows, "exchange.heldRowsWait")), kc.get_kernel(
                 ("exch_rsample", wm.shape), lambda: sample_impl)(
                     wm, jnp.asarray(g.num_rows, dtype=jnp.int32))))
-        with obstrace.span("exchange.countWait"):
-            drawn = jax.device_get([s for _, s in samples])
-        bounds = _range_bounds([(n, np.asarray(s)) for (n, _), s in
+        drawn = read_host([s for _, s in samples], "exchange.countWait")
+        bounds = _range_bounds([(n, s) for (n, _), s in
                                 zip(samples, drawn)], n_parts)
         return [None if g is None else kc.get_kernel(
             ("exch_rplace", n_parts, wm.shape), lambda: place_impl)(

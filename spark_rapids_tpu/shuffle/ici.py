@@ -34,7 +34,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_tpu import dtypes as dt
-from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+from spark_rapids_tpu.columnar.batch import (DeviceBatch, DeviceColumn,
+                                             read_host)
 from spark_rapids_tpu.exec.kernel_cache import jit_named
 from spark_rapids_tpu.exec.tpu_aggregate import (finalize_aggregate,
                                                  make_spec, merge_aggregate,
@@ -254,7 +255,7 @@ def shard_batch(batch: DeviceBatch, mesh: Mesh, axis: str
     cap = batch.capacity
     assert cap % n_dev == 0, f"capacity {cap} not divisible by {n_dev}"
     local_cap = cap // n_dev
-    total = int(batch.num_rows)
+    total = int(read_host(batch.num_rows, "exchange.shardRowsWait"))
     # per-shard true row counts for the contiguous layout
     counts = np.clip(total - np.arange(n_dev) * local_cap, 0, local_cap)
     counts = jnp.asarray(counts, dtype=jnp.int32)
@@ -428,7 +429,7 @@ def _same_shapes(augs: List[DeviceBatch]) -> List[DeviceBatch]:
 
 def exchange_placed(batches: List[Optional[DeviceBatch]],
                     targets: List[Optional[jnp.ndarray]],
-                    min_bucket: int = 16, count_wait=None
+                    min_bucket: int = 16
                     ) -> Tuple[List[Optional[DeviceBatch]], dict]:
     """The ICI exchange over rows that already lie on the mesh:
     ``batches[d]`` is what mesh device ``d`` holds (committed there; None
@@ -441,13 +442,12 @@ def exchange_placed(batches: List[Optional[DeviceBatch]],
     ones and one ``all_to_all`` step runs.  A receiver's batch is then
     cut to the tier of the rows it received.
 
-    ``count_wait`` is a context manager factory around the one read.
-    Returns one local DeviceBatch per mesh device (None where a device
+    The one read is ``exchange.countWait``.  Returns one local
+    DeviceBatch per mesh device (None where a device
     received nothing; each carries a trailing ``__part__`` column so the
     reader can sub-split the device's rows into its owned partitions)
     and what was counted: ``rows`` (sender x receiver), ``bucket_rows``,
     ``capacities``."""
-    import contextlib
     from spark_rapids_tpu.columnar.batch import bucket_rows
 
     mesh = get_default_mesh()
@@ -460,9 +460,8 @@ def exchange_placed(batches: List[Optional[DeviceBatch]],
             else _like_on(template, template.capacity, devices[d])
             for d, (b, t) in enumerate(zip(batches, targets))]
     per_chip = [_peer_counts(a, n_dev) for a in augs]
-    with (count_wait() if count_wait else contextlib.nullcontext()):
-        counts = np.stack([np.asarray(c) for c in
-                           jax.device_get(per_chip)])    # sender x receiver
+    counts = np.stack(read_host(per_chip,
+                                "exchange.countWait"))   # sender x receiver
     bucket = bucket_rows(max(int(counts.max()), 1), min_bucket)
     augs = _same_shapes(augs)
     local_cap = augs[0].capacity
@@ -532,7 +531,7 @@ def ring_broadcast_batch(batch: DeviceBatch) -> dict:
     n_dev = mesh.shape["shuffle"]
     if n_dev == 1:
         return broadcast_batch(batch)
-    total = int(batch.num_rows)
+    total = int(read_host(batch.num_rows, "exchange.shardRowsWait"))
     local_cap = bucket_rows(max((total + n_dev - 1) // n_dev, 1), 16)
     aug = with_capacity(batch, local_cap * n_dev)
     leaves, counts = shard_batch(aug, mesh, "shuffle")
@@ -574,7 +573,7 @@ def ring_broadcast_batch(batch: DeviceBatch) -> dict:
         local_step, mesh=mesh, in_specs=(P("shuffle"), P("shuffle")),
         out_specs=(P(), P()), check_vma=False), "ici_join")
     out_leaves, out_rows = step(leaves, counts)
-    n_out = int(np.asarray(out_rows)[0])
+    n_out = int(read_host(out_rows, "exchange.ringRowsWait")[0])
 
     out = {}
     for d in mesh.devices.flat:

@@ -477,7 +477,7 @@ def test_counters_ride_the_partials_one_read(low_gate, what, dense, srt):
     out = q(TpuSparkSession(_CONF)).collect()
     assert _moved(before, "agg.update.dense") == dense
     assert _moved(before, "agg.update.sorted") == srt
-    assert _moved(before, "agg.partials.read") == \
+    assert _moved(before, "device.reads.agg.countWait") == \
         (0 if what.startswith("global") else 1)
     if not what.startswith("first"):
         assert_tables_equal(cpu, out, ignore_order=True,
@@ -489,7 +489,7 @@ def test_below_the_gate_neither_counter_moves():
     before = _counters()
     out = _q(t)(TpuSparkSession(_CONF)).collect()
     assert out.num_rows == 4
-    assert _moved(before, "agg.partials.read") == 1
+    assert _moved(before, "device.reads.agg.countWait") == 1
     assert _moved(before, "agg.update.dense") == 0
     assert _moved(before, "agg.update.sorted") == 0
 

@@ -15,7 +15,7 @@ from spark_rapids_tpu.obs import registry as obsreg
 from tests.parity import assert_tables_equal, with_cpu_session
 
 _CONF = {"spark.rapids.tpu.sql.variableFloatAgg.enabled": True}
-_MOVED = ("agg.partials.read", "agg.partials.shrunk",
+_MOVED = ("device.reads.agg.countWait", "agg.partials.shrunk",
           "agg.partials.rowsCut", "kernel.cache.misses",
           "kernel.cache.compiles", "kernel.dispatches.agg_shrink")
 
@@ -103,7 +103,7 @@ def test_few_groups_cut_every_partial_to_its_tier(monkeypatch):
     assert seen.concat_in == [[16] * 4], seen.concat_in
     assert seen.concat_out == [bucket_rows(4 * 16)] == [64]
     assert seen.emitted == [64]
-    assert moved["agg.partials.read"] == 1
+    assert moved["device.reads.agg.countWait"] == 1
     assert moved["agg.partials.shrunk"] == 4
     assert moved["agg.partials.rowsCut"] == 4 * (4096 - 16)
 
@@ -120,7 +120,7 @@ def test_groups_that_fill_their_tier_are_left_alone(monkeypatch):
     _check(q, got)
     assert seen.concat_in == [[1024] * 4], seen.concat_in
     assert seen.concat_out == [4096]
-    assert moved["agg.partials.read"] == 1
+    assert moved["device.reads.agg.countWait"] == 1
     assert moved["agg.partials.shrunk"] == 0
     assert moved["agg.partials.rowsCut"] == 0
     assert moved["kernel.dispatches.agg_shrink"] == 0
@@ -135,7 +135,7 @@ def test_single_partial_is_cut_too(monkeypatch):
     _check(q, got)
     assert seen.concat_in == []
     assert seen.emitted == [16]
-    assert (moved["agg.partials.read"], moved["agg.partials.shrunk"]) \
+    assert (moved["device.reads.agg.countWait"], moved["agg.partials.shrunk"]) \
         == (1, 1)
 
 
@@ -154,12 +154,12 @@ def test_edges_answer_like_the_cpu(monkeypatch, case):
     if case == "global":
         # one row a partial by construction: cut without a read
         assert seen.concat_in == [[16] * 4]
-        assert moved["agg.partials.read"] == 0
+        assert moved["device.reads.agg.countWait"] == 0
         assert moved["agg.partials.shrunk"] == 4
     if case in ("null_keys", "filter_keeps_none"):
         assert seen.concat_in == [[16] * 4]
         assert seen.emitted == [64]
-        assert moved["agg.partials.read"] == 1
+        assert moved["device.reads.agg.countWait"] == 1
     if case == "filter_keeps_none":
         assert got.num_rows == 0
 
@@ -171,12 +171,12 @@ def test_one_count_read_an_aggregate(parts):
     q = _grouped(_table(parts * 300, 5), parts)
     got, moved = _run(q)
     _check(q, got)
-    assert moved["agg.partials.read"] == 1
+    assert moved["device.reads.agg.countWait"] == 1
     assert moved["agg.partials.shrunk"] == parts
     q2 = _grouped(_table(parts * 300, 11, seed=6), parts)
     got2, moved2 = _run(q2)
     _check(q2, got2)
-    assert moved2["agg.partials.read"] == 1
+    assert moved2["device.reads.agg.countWait"] == 1
     assert moved2["kernel.cache.misses"] == 0, moved2
     assert moved2["kernel.cache.compiles"] == 0, moved2
 
@@ -190,7 +190,7 @@ def test_per_partition_reads_once_a_partition():
     got, moved = _run(q, conf)
     _check(q, got)
     assert got.num_rows == 40
-    assert moved["agg.partials.read"] == 3
+    assert moved["device.reads.agg.countWait"] == 3
 
 
 def test_retained_state_merges_with_a_cut_delta(monkeypatch):
@@ -224,7 +224,7 @@ def test_retained_state_merges_with_a_cut_delta(monkeypatch):
     whole = _grouped(pa.concat_tables([old, new]), 2)
     _check(whole, got)
     assert seen.concat_in == [[16, 16, 16]], seen.concat_in
-    assert moved.get("agg.partials.read") == 1
+    assert moved.get("device.reads.agg.countWait") == 1
     assert moved.get("agg.partials.shrunk") == 2
     assert second.table.num_rows == 7
 

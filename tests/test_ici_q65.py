@@ -272,6 +272,39 @@ def test_a_sort_over_placed_batches_is_a_total_order(four_chips, root):
     assert moved.get("exchange.ici.movedBatches", 0) == 0
 
 
+def test_every_host_copy_on_four_chips_is_a_named_read(four_chips):
+    """The four-chip cell's statement copies nothing to the host outside
+    ``columnar/batch.read_host``; each of its three exchanges reads the
+    chips' per-peer counts once, and the sort's also its key samples.
+    With tracing on, the spans of the chips' task threads carry their
+    chip, and each barrier's waits are ``chip.peerWait`` spans."""
+    from spark_rapids_tpu.obs import trace
+    from tests.host_copies import copies_outside_read_host
+    trace.configure(True, 65536)
+    trace.clear()
+    try:
+        view = _counters()
+        with copies_outside_read_host() as outside:
+            four_chips.sql(_sql(CONFIG).decode()).collect()
+        moved = view.delta()["counters"]
+        spans = four_chips.last_query_profile().spans
+    finally:
+        trace.configure(False)
+        trace.clear()
+    assert outside == [], outside[0]
+    assert moved["device.reads"] == sum(
+        v for k, v in moved.items() if k.startswith("device.reads."))
+    assert moved["device.reads.exchange.countWait"] == 4
+    ids = {d.id for d in jax.devices()[:CHIPS]}
+    assert {sp["chip"] for sp in spans} - {None} <= ids
+    assert any(sp["chip"] is not None and sp["cat"] == "device.read"
+               for sp in spans)
+    for sp in spans:
+        if sp["name"] == "chip.peerWait":
+            assert sp["chip"] in ids and sp["args"]["stage"] in (
+                "exchange", "reuse", "join", "collect")
+
+
 def test_task_slots_count_a_chip():
     """Two chips' gates are two gates: a held slot of chip 0 does not
     keep chip 1's task waiting, and a thread that names no chip works
@@ -360,12 +393,12 @@ def test_drain_by_chip_keeps_every_batch_of_every_partition(n_dev, slots):
                     running[chip] -= 1
         got = [[] for _ in range(64)]
         drain_by_chip([part(p) for p in range(64)],
-                      lambda p, b: got[p].append(b))
+                      lambda p, b: got[p].append(b), stage="test")
         assert got == [[(p, k) for k in range(50)] for p in range(64)]
         assert max(worst) <= (slots if n_dev > 1 else 1)
         with pytest.raises(ValueError, match="partition 5"):
             drain_by_chip([part(p, fail=p == 5) for p in range(16)],
-                          lambda p, b: None)
+                          lambda p, b: None, stage="test")
     finally:
         sys.setswitchinterval(before)
         devmgr.initialize(2)

@@ -176,7 +176,7 @@ def test_join_path_is_chosen_by_key_range(session, how, case, first_key,
     other = "sortMerge" if path == "direct" else "direct"
     assert moved.get(f"join.path.{path}", 0) == 1
     assert moved.get(f"join.path.{other}", 0) == 0
-    assert moved.get("join.rangeReads", 0) == 1
+    assert moved.get("device.reads.join.rangeWait", 0) == 1
     if case == "from-1":
         assert moved.get("kernel.cache.compiles", 0) == 0
         assert moved.get("kernel.cache.persistentHits", 0) == 0

@@ -18,9 +18,13 @@ import pyarrow.parquet as papq
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from spark_rapids_tpu import TpuSparkSession
+from spark_rapids_tpu.columnar.batch import read_host
 from spark_rapids_tpu.exec.base import Metrics, timed, timed_extra
+from spark_rapids_tpu.exec.placement import drain_by_chip
+from spark_rapids_tpu.mem import device as devmgr
 from spark_rapids_tpu.obs import trace
 from spark_rapids_tpu.sched import cancel
 from spark_rapids_tpu.serve.client import ServeClient
@@ -194,6 +198,11 @@ def test_disabled_tracer_pushes_and_allocates_nothing():
             with timed_extra(m, "op.extra"):
                 with trace.span("inner"):
                     trace.record("y", 0, 1)
+        # a read and a barrier of several chips record nothing either
+        with devmgr.task_chip(1):
+            assert int(read_host(jnp.int32(3) + 1, "test.offWait")) == 4
+        drain_by_chip([iter((p,)) for p in range(4)], lambda p, b: None,
+                      2, stage="test")
     done = threading.Event()
     stacks = []
 
@@ -201,8 +210,12 @@ def test_disabled_tracer_pushes_and_allocates_nothing():
         body()
         stacks.append(getattr(trace._tls, "stack", None))
         done.set()
-    threading.Thread(target=on_thread).start()
-    assert done.wait(10)
+    devmgr.initialize(2, chips=2)
+    try:
+        threading.Thread(target=on_thread).start()
+        assert done.wait(10)
+    finally:
+        devmgr.initialize(2)
     assert stacks == [None]              # no stack was ever made
     assert trace.spans_since(mark) == [] and not trace._roots
     assert m.total_time_ns > 0 and m.extra["op.extra"] > 0
@@ -254,6 +267,69 @@ def test_foreign_spans_join_the_callers_tree():
     assert by_name["map.inner"][trace.PARENT] == \
         by_name["map.work"][trace.SID]
     assert len({s[trace.SID] for s in by_name.values()}) == 4
+
+
+def test_a_span_carries_the_chip_its_thread_works_for():
+    """On a mesh of several chips a span recorded under ``task_chip(c)``
+    carries mesh device ``c``'s jax id and one recorded outside carries
+    None; on one chip every span carries None.  The profile's dicts and
+    the Chrome export carry it, and foreign spans keep theirs."""
+    trace.configure(True, 4096)
+    trace.clear()
+    devmgr.initialize(2, chips=4, device_ids=[4, 5, 6, 7])
+    try:
+        with devmgr.task_chip(2):
+            trace.record("record.chip2", 0, 1)
+            with trace.span("span.chip2"):
+                pass
+        trace.record("outside", 0, 1)
+        with cancel.install(cancel.CancelToken(5)):
+            trace.record_foreign([
+                (1, 9, "foreign.chip5", "exec", 0, 1, 0, None, 3, 0, None,
+                 5),
+                (2, 9, "foreign.old", "exec", 0, 1, 0, None, 4, 0, None),
+            ], 0, "executor-0")
+    finally:
+        devmgr.initialize(2)
+    with devmgr.task_chip(2):
+        trace.record("one.chip", 0, 1)
+    chip = {s[2]: s[trace.CHIP] for s in trace.snapshot()}
+    assert chip == {"record.chip2": 6, "span.chip2": 6, "outside": None,
+                    "foreign.chip5": 5, "foreign.old": None,
+                    "one.chip": None}
+    dicts = {d["name"]: d for d in trace.span_dicts(trace.snapshot())}
+    assert dicts["span.chip2"]["chip"] == 6
+    assert dicts["outside"]["chip"] is None
+    begins = {e["name"]: e for e in trace.chrome_trace()["traceEvents"]
+              if e["ph"] == "B"}
+    assert begins["span.chip2"]["args"] == {"chip": 6}
+    assert "args" not in begins["outside"]
+
+
+def test_a_chip_that_waits_for_its_peers_says_so():
+    """``drain_by_chip`` is a barrier: with one chip's tasks held back
+    by a known delay, every other chip records one ``chip.peerWait`` of
+    about that delay, stamped with its chip, and the slow chip none."""
+    delay = 0.4
+    trace.configure(True, 4096)
+    trace.clear()
+    devmgr.initialize(2, chips=4, device_ids=[10, 11, 12, 13])
+
+    def part(p):
+        if p % 4 == 1:
+            time.sleep(delay)
+        yield p
+    try:
+        with cancel.install(cancel.CancelToken(77)):
+            drain_by_chip([part(p) for p in range(8)], lambda p, b: None,
+                          stage="exchange")
+    finally:
+        devmgr.initialize(2)
+    waits = [s for s in trace.query_spans(77) if s[2] == "chip.peerWait"]
+    assert sorted(s[trace.CHIP] for s in waits) == [10, 12, 13]
+    for s in waits:
+        assert s[3] == "query" and s[7] == {"stage": "exchange"}
+        assert 0.5 * delay < s[5] / 1e9 < delay + 1.0
 
 
 def test_ici_exchange_step_carries_its_scope_on_four_devices():
