@@ -16,9 +16,11 @@ The flagship composite op is the distributed hash aggregate:
 
 which is exactly the reference's partial-agg / shuffle / final-agg stage
 pair (aggregate.scala + GpuShuffleExchangeExec) fused into one SPMD step
-XLA can schedule end-to-end.  Static shapes: each device sends exactly
-``capacity`` candidate slots per peer; true counts travel as a tiny int
-vector alongside (the scalar-prefetch idiom).
+XLA can schedule end-to-end.  Static shapes: each device sends one bucket
+of slots per peer, the sender's capacity where nothing was counted and
+the power of two of the fullest count where the host has read the
+per-peer counts first (``exchange_placed``); true counts travel as a
+tiny int vector alongside (the scalar-prefetch idiom).
 """
 
 from __future__ import annotations
@@ -70,24 +72,29 @@ def bucketize(batch: DeviceBatch, target: jnp.ndarray, n_parts: int,
     a bucket has (the batch's capacity where none is given: any count
     fits); the caller that passes a smaller one has counted the rows and
     knows that no bucket holds more.
+
+    A counting placement, no sort: a row's slot is its target's bucket
+    base plus its rank among the earlier rows of that target (an
+    exclusive prefix sum of one mask a target), so a bucket keeps the
+    batch's row order.  One int32 scatter of the row index builds the
+    gather that fills the buckets.
     """
     cap = batch.capacity
     bcap = cap if bucket_cap is None else int(bucket_cap)
     exists = batch.row_mask()
-    t = jnp.where(exists, target, n_parts)  # park padding out of range
-    counts = jnp.zeros((n_parts,), dtype=jnp.int32).at[t].add(
-        exists.astype(jnp.int32), mode="drop")
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
-    order = jnp.argsort(t, stable=True)  # groups rows by target, padding last
-    sorted_t = jnp.take(t, order)
-    rank = jnp.arange(cap, dtype=jnp.int32) - jnp.take(
-        offsets, jnp.clip(sorted_t, 0, n_parts - 1))
-    flat_pos = jnp.where(sorted_t < n_parts,
-                         sorted_t * bcap + jnp.clip(rank, 0, bcap - 1),
+    t = jnp.where(exists, target, n_parts)  # padding matches no target
+    rank = jnp.zeros((cap,), dtype=jnp.int32)
+    counts = []
+    for k in range(n_parts):
+        hit = t == k
+        seen = jnp.cumsum(hit.astype(jnp.int32))
+        rank = jnp.where(hit, seen - 1, rank)
+        counts.append(seen[-1])
+    counts = jnp.stack(counts)
+    flat_pos = jnp.where(t < n_parts, t * bcap + rank,
                          n_parts * bcap)  # padding -> dropped
     gather_idx = jnp.zeros((n_parts * bcap,), dtype=jnp.int32).at[
-        flat_pos].set(order.astype(jnp.int32), mode="drop")
+        flat_pos].set(jnp.arange(cap, dtype=jnp.int32), mode="drop")
     slot = jnp.arange(n_parts * bcap) % bcap
     valid = slot < jnp.repeat(counts, bcap)
     out_cols = []
@@ -125,33 +132,42 @@ def exchange(stacked_cols: List[DeviceColumn], counts: jnp.ndarray,
 
 
 def reassemble(names: Sequence[str], stacked_cols: List[DeviceColumn],
-               counts_recv: jnp.ndarray) -> DeviceBatch:
-    """Flatten received blocks and compact valid rows to the front."""
+               counts_recv: jnp.ndarray,
+               out_cap: Optional[int] = None) -> DeviceBatch:
+    """The received blocks' rows, block after block, at the front of a
+    batch of ``out_cap`` slots (the blocks' total where none is given;
+    the caller that passes one knows that no chip receives more rows).
+
+    Block ``b``'s rows are its first ``counts_recv[b]`` slots and belong
+    at the sum of the earlier counts, so each block is copied there
+    whole, in block order, each over the unused tail of the one before;
+    the slots past the rows are then cleared.  Copies of contiguous
+    runs: no scatter and no gather."""
     n_parts = counts_recv.shape[0]
     cap = stacked_cols[0].validity.shape[1]
-    slot = jnp.arange(n_parts * cap) % cap
-    valid = slot < jnp.repeat(counts_recv, cap)
-    flat_cols = []
-    for c in stacked_cols:
-        data = c.data.reshape((n_parts * cap,) + c.data.shape[2:])
-        validity = c.validity.reshape((n_parts * cap,))
-        lengths = c.lengths.reshape((n_parts * cap,)) \
-            if c.lengths is not None else None
-        ev = c.elem_validity.reshape((n_parts * cap,) +
-                                     c.elem_validity.shape[2:]) \
-            if c.elem_validity is not None else None
-        flat_cols.append(DeviceColumn(c.dtype, data, validity, lengths, ev))
-    # rows arrive block-strided; compact the `valid` rows to the front so
-    # the result satisfies the DeviceBatch row_mask contract (scatter by
-    # cumsum rank — no sort; XLA sort compiles are minutes-scale)
-    tcap = n_parts * cap
-    count = jnp.sum(valid.astype(jnp.int32))
-    dest = jnp.where(valid, jnp.cumsum(valid.astype(jnp.int32)) - 1,
-                     tcap)
-    from spark_rapids_tpu.columnar.batch import compact_arrays
-    cols = [DeviceColumn(c.dtype, *compact_arrays(
-        valid, dest, c.data, c.validity, c.lengths, c.elem_validity))
-        for c in flat_cols]
+    ocap = n_parts * cap if out_cap is None else int(out_cap)
+    counts_recv = counts_recv.astype(jnp.int32)
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              jnp.cumsum(counts_recv)])
+    count = starts[-1]
+    # every copy lands inside the buffer: start[b] + cap <= n_parts * cap
+    room = max(ocap, n_parts * cap)
+    valid = jnp.arange(ocap) < count
+
+    def runs(a, fill):
+        out = jnp.full((room,) + a.shape[2:], fill, a.dtype)
+        for b in range(n_parts):
+            out = lax.dynamic_update_slice_in_dim(out, a[b], starts[b],
+                                                  axis=0)
+        out = out[:ocap]
+        mask = valid.reshape(valid.shape + (1,) * (out.ndim - 1))
+        return jnp.where(mask, out, jnp.asarray(fill, a.dtype))
+
+    cols = [DeviceColumn(
+        c.dtype, runs(c.data, 0), runs(c.validity, False),
+        None if c.lengths is None else runs(c.lengths, 0),
+        None if c.elem_validity is None else runs(c.elem_validity, False))
+        for c in stacked_cols]
     return DeviceBatch(names, cols, count)
 
 
@@ -316,19 +332,22 @@ def with_capacity(batch: DeviceBatch, cap: int) -> DeviceBatch:
 
 
 def make_exchange_step(mesh: Mesh, axis: str, names, dtypes, aux_key,
-                       bucket_cap: Optional[int] = None):
+                       bucket_cap: Optional[int] = None,
+                       recv_cap: Optional[int] = None):
     """Jitted shard_map step routing rows to the device owning their
     target partition.  The batch's LAST column is the int32 target
     partition id; device d owns partitions {p : p % n_dev == d}.
 
-    Every device sends ``bucket_cap`` slots to every peer, so the out
-    leaves have per-device capacity ``n_dev * bucket_cap``; with them
-    come the per-device received row counts.  Without a ``bucket_cap``
-    a bucket has the sender's whole capacity (worst case: every row
-    lands on one device); ``exchange_placed`` counts the rows first and
-    passes the tier of the fullest bucket.
+    Every device sends ``bucket_cap`` slots to every peer and compacts
+    what it received into ``recv_cap`` slots a device (``n_dev *
+    bucket_cap`` where none is given); with the out leaves come the
+    per-device received row counts.  Without a ``bucket_cap`` a bucket
+    has the sender's whole capacity (worst case: every row lands on one
+    device); ``exchange_placed`` counts the rows first and passes the
+    power of two of the fullest bucket and the tier of the most rows a
+    device receives.
     """
-    key = (mesh, axis, tuple(names), aux_key, bucket_cap)
+    key = (mesh, axis, tuple(names), aux_key, bucket_cap, recv_cap)
     if key in _STEP_CACHE:
         return _STEP_CACHE[key]
     n_dev = mesh.shape[axis]
@@ -340,7 +359,7 @@ def make_exchange_step(mesh: Mesh, axis: str, names, dtypes, aux_key,
         owner = part % np.int32(n_dev)
         stacked, counts = bucketize(batch, owner, n_dev, bucket_cap)
         stacked, counts_recv = exchange(stacked, counts, axis)
-        received = reassemble(names, stacked, counts_recv)
+        received = reassemble(names, stacked, counts_recv, recv_cap)
         return _cols_to_leaves(received.columns), jnp.reshape(
             jnp.asarray(received.num_rows, dtype=jnp.int32), (1,))
 
@@ -437,10 +456,13 @@ def exchange_placed(batches: List[Optional[DeviceBatch]],
     partitions.  Nothing passes through one chip: each chip counts its
     rows a peer where they lie, the ``n_dev x n_dev`` counts are read in
     one transfer (the scalar-prefetch idiom of the module docstring: the
-    true counts decide the static shape), the buckets get the tier of
-    the fullest one, the global arrays are assembled from the per-device
-    ones and one ``all_to_all`` step runs.  A receiver's batch is then
-    cut to the tier of the rows it received.
+    true counts decide the static shape), the buckets get the power of
+    two of the fullest one, the global arrays are assembled from the
+    per-device ones and one ``all_to_all`` step runs; it compacts what
+    each device received at the tier of the most rows any receives.  A
+    smaller receiver's batch is then cut to the tier of its own rows.
+    Each exchange adds its send slots, ``n_dev * n_dev * bucket``, to
+    the counter ``exchange.ici.sendSlots``.
 
     The one read is ``exchange.countWait``.  Returns one local
     DeviceBatch per mesh device (None where a device
@@ -449,6 +471,7 @@ def exchange_placed(batches: List[Optional[DeviceBatch]],
     and what was counted: ``rows`` (sender x receiver), ``bucket_rows``,
     ``capacities``."""
     from spark_rapids_tpu.columnar.batch import bucket_rows
+    from spark_rapids_tpu.obs import registry as obsreg
 
     mesh = get_default_mesh()
     n_dev = mesh.shape["shuffle"]
@@ -462,10 +485,14 @@ def exchange_placed(batches: List[Optional[DeviceBatch]],
     per_chip = [_peer_counts(a, n_dev) for a in augs]
     counts = np.stack(read_host(per_chip,
                                 "exchange.countWait"))   # sender x receiver
-    bucket = bucket_rows(max(int(counts.max()), 1), min_bucket)
+    bucket = 1 << (max(int(counts.max()), min_bucket) - 1).bit_length()
+    received = counts.sum(axis=0)
+    recv_cap = bucket_rows(max(int(received.max()), 1), min_bucket)
     augs = _same_shapes(augs)
     local_cap = augs[0].capacity
     bucket = min(bucket, local_cap)
+    obsreg.get_registry().inc("exchange.ici.sendSlots",
+                              n_dev * n_dev * bucket)
     sharding = NamedSharding(mesh, P("shuffle"))
 
     def glob(arrays):
@@ -488,9 +515,8 @@ def exchange_placed(batches: List[Optional[DeviceBatch]],
                      c.lengths is not None, c.elem_validity is not None)
                     for c in first.columns) + (local_cap,)
     step = make_exchange_step(mesh, "shuffle", first.names, first.dtypes,
-                              aux_key, bucket)
+                              aux_key, bucket, recv_cap)
     out_leaves, _ = step(leaves, rows)
-    received = counts.sum(axis=0)
     dev_batches: List[Optional[DeviceBatch]] = []
     for d in range(n_dev):
         if int(received[d]) == 0:

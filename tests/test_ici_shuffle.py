@@ -691,11 +691,12 @@ def _placed_inputs(devices, rows, targets_of, strings=False, empty=()):
 
 
 @pytest.mark.parametrize("case", ["uniform", "skewed", "one-chip-empty",
-                                  "strings", "all-to-one"])
+                                  "strings", "all-to-one", "between-rungs"])
 def test_exchange_placed_sizes_buckets_by_the_counted_rows(case,
                                                            monkeypatch):
     from spark_rapids_tpu.columnar.batch import bucket_rows, to_arrow
     from spark_rapids_tpu.exec import placement
+    from spark_rapids_tpu.obs import registry
     n_dev, rows = 4, 1000
     devices = _mesh_of(n_dev, monkeypatch)
     targets_of = {
@@ -705,19 +706,26 @@ def test_exchange_placed_sizes_buckets_by_the_counted_rows(case,
         "one-chip-empty": lambda d, i: (i + d) % n_dev,
         "strings": lambda d, i: (i * 7 + d) % n_dev,
         "all-to-one": lambda d, i: np.full(len(i), 2),
+        # 300 rows a peer at most: between the ladder's 256 and 1024
+        "between-rungs": lambda d, i: np.minimum(i // 300, n_dev - 1),
     }[case]
     batches, targets, sent = _placed_inputs(
         devices, rows, targets_of, strings=case == "strings",
         empty=(3,) if case == "one-chip-empty" else ())
+    view = registry.get_registry().view()
     out, counted = ici.exchange_placed(batches, targets, 16)
     counts = counted["rows"]
     assert counts.shape == (n_dev, n_dev)
     received = counts.sum(axis=0)
     assert received.sum() == sum(len(k) for k, _ in sent)
-    # the buckets have the tier of the fullest one, not the sender's
-    # capacity ...
+    # the buckets have the power of two of the fullest one, not the
+    # sender's capacity nor the ladder's rung above it ...
+    fullest = int(counts.max())
     assert counted["bucket_rows"] == min(
-        bucket_rows(int(counts.max()), 16), 1024)
+        1 << (max(fullest, 16) - 1).bit_length(), 1024)
+    assert fullest <= counted["bucket_rows"] < 2 * max(fullest, 16)
+    assert view.delta()["counters"]["exchange.ici.sendSlots"] == \
+        n_dev * n_dev * counted["bucket_rows"]
     # ... and a receiver holds its rows at their own tier
     for d, b in enumerate(out):
         if not received[d]:
@@ -728,10 +736,10 @@ def test_exchange_placed_sizes_buckets_by_the_counted_rows(case,
         assert placement.device_of(b) == devices[d]
         got = to_arrow(b)
         assert set(got.column("__part__").to_pylist()) == {d}
-        want = np.sort(np.concatenate(
-            [k[t == d] for k, t in sent]))
-        assert np.array_equal(
-            np.sort(got.column("k").to_numpy()), want)
+        # a stable partition: sender by sender, each in its own order
+        want = np.concatenate([k[t == d] for k, t in sent])
+        assert np.array_equal(got.column("k").to_numpy(), want)
+        assert np.array_equal(got.column("v").to_numpy(), want * 0.5)
         if case == "strings":
             by_k = dict(zip(got.column("k").to_pylist(),
                             got.column("s").to_pylist()))
@@ -745,6 +753,11 @@ def test_exchange_placed_sizes_buckets_by_the_counted_rows(case,
         # capacity would have made it 4096
         assert counted["bucket_rows"] == 256
         assert counted["capacities"] == [1024] * n_dev
+    if case == "between-rungs":
+        # 300 rows a peer: 512 slots a bucket, where the ladder's rung
+        # above 300 is 1024
+        assert fullest == 300 and counted["bucket_rows"] == 512
+        assert counted["capacities"] == [4096, 4096, 4096, 1024]
 
 
 def test_bucketize_with_a_counted_bucket_capacity():
@@ -763,6 +776,127 @@ def test_bucketize_with_a_counted_bucket_capacity():
         assert np.array_equal(np.asarray(narrow[0].data[p, :c]),
                               np.asarray(wide[0].data[p, :c]))
         assert not np.asarray(narrow[0].validity[p, c:]).any()
+
+
+def test_exchange_step_holds_no_sort():
+    """The step places rows by a prefix sum a target and compacts by
+    copies of runs: its lowered text holds no sort and no scatter but
+    the one of the row index."""
+    mesh = Mesh(np.array(jax.devices()[:4]), ("shuffle",))
+    batch = from_arrow(pa.table({
+        "k": pa.array(np.arange(64) % 4, pa.int32()),
+        "s": pa.array([f"x{i}" for i in range(64)]),
+        "v": pa.array(np.arange(64, dtype=np.float64))}))
+    aug = ici.with_capacity(batch, 64)
+    leaves, counts = ici.shard_batch(aug, mesh, "shuffle")
+    step = ici.make_exchange_step(mesh, "shuffle", aug.names, aug.dtypes,
+                                  ("test_no_sort", 16), 16, 64)
+    text = step.lower(leaves, counts).as_text()
+    assert "module @jit_ici_exchange " in text
+    assert "stablehlo.sort" not in text
+    assert text.count('"stablehlo.scatter"') == 1
+
+
+@pytest.mark.parametrize("counts,out_cap", [
+    ((5, 0, 16, 3), 64), ((0, 0, 0, 0), 16), ((16, 16, 16, 16), 64),
+    ((0, 7, 0, 0), 16), ((2, 9, 0, 1), 32)],
+    ids=["an-empty-block", "nothing", "full", "one-block", "small-out"])
+def test_reassemble_puts_the_blocks_rows_in_front_unchanged(counts,
+                                                            out_cap):
+    """Nulls, strings and empty blocks come through the receiver's
+    compaction as the stable compaction of each block's first
+    ``counts[b]`` slots gives them, slots past the rows cleared."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.batch import DeviceColumn
+    n, bcap, width = 4, 16, 5
+    rng = np.random.default_rng(sum(counts) + out_cap)
+    data = rng.integers(-99, 99, (n, bcap)).astype(np.int64)
+    validity = rng.random((n, bcap)) < 0.8
+    sdata = rng.integers(1, 255, (n, bcap, width)).astype(np.uint8)
+    slen = rng.integers(0, width + 1, (n, bcap)).astype(np.int32)
+    svalid = rng.random((n, bcap)) < 0.7
+    stacked = [DeviceColumn(dt.INT64, jnp.asarray(data),
+                            jnp.asarray(validity)),
+               DeviceColumn(dt.STRING, jnp.asarray(sdata),
+                            jnp.asarray(svalid), jnp.asarray(slen))]
+    got = jax.jit(lambda cols, c: ici.reassemble(["k", "s"], cols, c,
+                                                 out_cap))(
+        stacked, jnp.asarray(counts, jnp.int32))
+    total = sum(counts)
+    assert int(got.num_rows) == total and got.capacity == out_cap
+    live = np.concatenate([np.arange(b * bcap, b * bcap + c)
+                           for b, c in enumerate(counts)]).astype(int)
+
+    def want(a, fill=0):
+        flat = a.reshape((n * bcap,) + a.shape[2:])
+        out = np.full((out_cap,) + a.shape[2:], fill, a.dtype)
+        out[:total] = flat[live]
+        return out
+    k, s = got.columns
+    np.testing.assert_array_equal(np.asarray(k.data), want(data))
+    np.testing.assert_array_equal(np.asarray(k.validity),
+                                  want(validity, False))
+    np.testing.assert_array_equal(np.asarray(s.data), want(sdata))
+    np.testing.assert_array_equal(np.asarray(s.lengths), want(slen))
+    np.testing.assert_array_equal(np.asarray(s.validity),
+                                  want(svalid, False))
+
+
+def test_exchange_placed_keeps_nulls_and_strings_with_an_empty_chip(
+        monkeypatch):
+    """Null keys, null strings and a chip that holds nothing: every
+    receiver holds its senders' rows in their order, nulls where they
+    were."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.batch import to_arrow
+    n_dev = 4
+    devices = _mesh_of(n_dev, monkeypatch)
+    batches, targets, sent = [], [], []
+    for d, dev in enumerate(devices):
+        if d == 1:
+            batches.append(None)
+            targets.append(None)
+            continue
+        rows = 100 + 37 * d
+        k = np.arange(rows) + 1000 * d
+        t = pa.table({
+            "k": pa.array(k, mask=k % 5 == 0),
+            "s": pa.array([None if x % 3 == 0 else "s" * (x % 9) + str(x)
+                           for x in k])})
+        b = jax.device_put(from_arrow(t, min_bucket=16), dev)
+        tg = np.zeros(b.capacity, np.int32)
+        tg[:rows] = (k * 7) % n_dev
+        batches.append(b)
+        targets.append(jax.device_put(jnp.asarray(tg), dev))
+        sent.append((t, tg[:rows]))
+    out, counted = ici.exchange_placed(batches, targets, 16)
+    for d, b in enumerate(out):
+        want = pa.concat_tables([t.filter(pa.array(tg == d))
+                                 for t, tg in sent])
+        got = to_arrow(b)
+        assert got.column("k").to_pylist() == want.column("k").to_pylist()
+        assert got.column("s").to_pylist() == want.column("s").to_pylist()
+
+
+def test_exchange_fill_pct_is_rows_over_send_slots_inside_the_window():
+    """The benchmark's reader (benchmark/metrics/exchange_fill_pct.py):
+    100 x rows in / send slots of the window's counters; nothing where
+    the program has neither counter or the window exchanged nothing."""
+    import importlib.util
+    import os
+    from spark_rapids_tpu.obs import registry
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics",
+        "exchange_fill_pct.py")
+    spec = importlib.util.spec_from_file_location("exchange_fill_pct", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    registry.get_registry().inc("exchange.ici.sendSlots", 0)
+    assert mod.read({"counters": {}}) is None
+    assert mod.read({"counters": {
+        "exchange.ici.rowsIn": 5_500_000,
+        "exchange.ici.sendSlots": 16 * 524_288}}) == \
+        pytest.approx(65.565, abs=1e-3)
 
 
 @pytest.mark.parametrize("n_parts", [2, 4, 7])
